@@ -19,10 +19,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 NEG_INF = -1e30
 

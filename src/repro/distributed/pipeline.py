@@ -20,10 +20,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def bubble_fraction(n_micro: int, n_stages: int) -> float:
@@ -68,13 +65,8 @@ def gpipe_forward(mesh: Mesh, axis: str, block_fn):
             return (buf_next, outs), None
 
         # initial carries must be marked pod-varying for shard_map's vma check
-        # (newer jax only; older shard_map has no vma tracking — no-op there)
-        if hasattr(jax.lax, "pcast"):
-            buf0 = jax.lax.pcast(jnp.zeros_like(x_mb[0]), (axis,), to="varying")
-            outs0 = jax.lax.pcast(jnp.zeros_like(x_mb), (axis,), to="varying")
-        else:
-            buf0 = jnp.zeros_like(x_mb[0])
-            outs0 = jnp.zeros_like(x_mb)
+        buf0 = jax.lax.pcast(jnp.zeros_like(x_mb[0]), (axis,), to="varying")
+        outs0 = jax.lax.pcast(jnp.zeros_like(x_mb), (axis,), to="varying")
         (_, outs), _ = jax.lax.scan(tick, (buf0, outs0),
                                     jnp.arange(T, dtype=jnp.int32))
         # outputs live on the last stage only (zeros elsewhere); replicate

@@ -14,16 +14,16 @@
                      side state
   topk_retrieval/    T3: int8 proxy-similarity scoring (the CAM analogue)
 
-Each directory: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit wrapper,
-interpret-mode switch), ref.py (pure-jnp oracle).
+Each directory: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit wrapper
+through ``platform_call``), ref.py (pure-jnp oracle).
 
 Paged decode entry points (serving/paged_cache.py arenas)
 ---------------------------------------------------------
 The ``paged_*`` kernels take ``(pages, block_table, lengths)`` directly: the
 block table is a scalar-prefetch operand, so each grid step's BlockSpec index
-map resolves ``block_table[b, ib]`` and DMAs that PHYSICAL page from the
-arena into VMEM — the contiguous logical view the jnp gather path
-materializes never exists. Masking convention (shared with
+map resolves ``block_table[b, ib]`` and DMAs that PHYSICAL page, every kv
+head of it, from the arena into VMEM — the contiguous logical view the jnp
+gather path materializes never exists. Masking convention (shared with
 serving/paged_cache.py): block-table entry 0 is the reserved null page whose
 contents are garbage by design; every position >= lengths[b] — all slots of
 an unmapped/null page and the tail of a partial last page — is masked to
@@ -47,26 +47,32 @@ adds one extra grid step that attends the chunk's RAW roped K/V causally
 (earlier pages are dequantized in VMEM, reading exactly what decode reads).
 ``chunk_attend_paged`` in serving/paged_cache.py is the dispatch.
 
-INTERPRET
----------
-Kernels TARGET TPU v5e (128-aligned MXU tiles, VMEM-resident accumulators)
-and are VALIDATED with interpret=True on CPU. ``INTERPRET`` is the
-package-wide default every ops.py wrapper applies when its ``interpret``
-argument is None; per-call overrides win. It defaults to True (this
-container is CPU-only) and can be forced either way with the
-``REPRO_INTERPRET`` env var (1/0, true/false, yes/no, on/off —
-anything else raises); flip it off on real TPUs. Interpret
-mode checks semantics, not speed — benchmark latency bars only apply
-compiled (see benchmarks/bench_serving.py).
+Interpret mode
+--------------
+Kernels TARGET TPU v5e (VMEM-resident accumulators, page blocks whose last
+two dims are whole array dims so Mosaic's (8, 128) tiling accepts them) and
+are VALIDATED in interpret mode on CPU. The mode is not an option: every
+``ops.py`` wrapper goes through ``platform_call``, which stages both
+variants and lets lowering pick the one for the platform the arrays live
+on — interpreted on the CPU backend, compiled Mosaic on a TPU. A per-call
+``interpret=`` argument still wins (tests pass it). Nothing here touches a
+backend at import time. Interpret mode checks semantics, not speed.
 """
-import os
+import functools
 
-_interpret_env = os.environ.get("REPRO_INTERPRET", "1").strip().lower()
-if _interpret_env in ("1", "true", "yes", "on"):
-    INTERPRET = True
-elif _interpret_env in ("0", "false", "no", "off"):
-    INTERPRET = False
-else:
-    raise ValueError(
-        f"REPRO_INTERPRET={_interpret_env!r}: expected 1/0, true/false, "
-        "yes/no, or on/off")
+import jax
+
+
+def platform_call(fwd, *args, interpret: bool | None = None, **static):
+    """Call a kernel forward ``fwd(*args, interpret=..., **static)``.
+
+    ``interpret=None`` defers the choice to lowering
+    (``jax.lax.platform_dependent``): the CPU lowering runs the kernel in
+    the Pallas interpreter, every other platform compiles it. ``args`` are
+    arrays (or pytrees of arrays); hashable options go in ``static``."""
+    if interpret is not None:
+        return fwd(*args, interpret=interpret, **static)
+    return jax.lax.platform_dependent(
+        *args,
+        cpu=functools.partial(fwd, interpret=True, **static),
+        default=functools.partial(fwd, interpret=False, **static))

@@ -23,16 +23,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.flash_attn.kernel import online_update
+
 NEG_INF = -1e30
 
 
-def _dequant(codes, lv_oh, scale_ref, zero_ref):
-    """codes: (bn, D) i8 (stored = code - 128); lv_oh: (bn, L) f32."""
-    s = jax.lax.dot_general(lv_oh, scale_ref[0, :, 0, :],
-                            (((1,), (0,)), ((), ())),
+def _dequant(codes, lv_oh, scale_tab, zero_tab):
+    """codes: (bn, D) i8 (stored = code - 128); lv_oh: (bn, L) f32;
+    scale_tab/zero_tab: (L, D) f32."""
+    s = jax.lax.dot_general(lv_oh, scale_tab, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (bn, D)
-    z = jax.lax.dot_general(lv_oh, zero_ref[0, :, 0, :],
-                            (((1,), (0,)), ((), ())),
+    z = jax.lax.dot_general(lv_oh, zero_tab, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     c = codes.astype(jnp.float32) + 128.0
     return jnp.where(c == 0.0, 0.0, (c - 1.0) * s + z)
@@ -60,7 +61,7 @@ def _kernel(len_ref, q_ref, ck_ref, cv_ref, sk_ref, zk_ref, sv_ref, zv_ref,
     lvk_oh = onehot(lvk_ref[0, :, 0])
     lvv_oh = onehot(lvv_ref[0, :, 0])
 
-    k_hat = _dequant(ck, lvk_oh, sk_ref, zk_ref)     # (bn, Dh) f32
+    k_hat = _dequant(ck, lvk_oh, sk_ref[0, :, 0, :], zk_ref[0, :, 0, :])
     s = jax.lax.dot_general(q.astype(jnp.float32), k_hat,
                             (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale  # (G, bn)
@@ -73,7 +74,7 @@ def _kernel(len_ref, q_ref, ck_ref, cv_ref, sk_ref, zk_ref, sv_ref, zv_ref,
     p = jnp.exp(s - m_new)
     l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=1, keepdims=True)
     m_sc[...] = m_new
-    v_hat = _dequant(cv, lvv_oh, sv_ref, zv_ref)     # (bn, Dv) f32
+    v_hat = _dequant(cv, lvv_oh, sv_ref[0, :, 0, :], zv_ref[0, :, 0, :])
     acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
         p, v_hat, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -82,15 +83,30 @@ def _kernel(len_ref, q_ref, ck_ref, cv_ref, sk_ref, zk_ref, sv_ref, zv_ref,
         o_ref[0, 0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(o_ref.dtype)
 
 
+def _page_dequant(codes_ref, lv_ref, s_ref, z_ref, h, num_levels):
+    """Dequantize kv head ``h`` of the page in VMEM: codes (page, KV, D)
+    i8, levels (page, KV) i32, per-slot scale/zero (L, KV, D) f32. Tiles
+    are rounded to bf16 like the jnp gather path
+    (cpq_chunked_decode_attention) so paged-kernel decode stays token-exact
+    vs it under greedy sampling."""
+    lv = lv_ref[0, :, h:h + 1]                           # (page, 1)
+    lv_oh = (lv == jax.lax.broadcasted_iota(
+        jnp.int32, (lv.shape[0], num_levels), 1)).astype(jnp.float32)
+    return _dequant(codes_ref[0, :, h, :], lv_oh, s_ref[0, :, h, :],
+                    z_ref[0, :, h, :]).astype(jnp.bfloat16).astype(jnp.float32)
+
+
 def _paged_kernel(bt_ref, len_ref, q_ref, ck_ref, cv_ref, sk_ref, zk_ref,
                   sv_ref, zv_ref, lvk_ref, lvv_ref, o_ref, m_sc, l_sc, acc_sc,
-                  *, scale: float, page_size: int, nb: int, num_levels: int):
-    """Paged T2 step: code/level tiles ARE physical page bt[b, ib] (resolved
-    by the BlockSpec index maps from the scalar-prefetched block table);
-    per-slot HQE scale/zero stay slot-indexed by b. Dequantization happens in
-    VMEM on the page — HBM moved only the compressed bytes of mapped pages."""
+                  *, scale: float, page_size: int, nb: int, num_levels: int,
+                  kv_heads: int):
+    """Paged T2 step: code/level tiles ARE physical page bt[b, ib] with all
+    kv heads (resolved by the BlockSpec index maps from the scalar-prefetched
+    block table); per-slot HQE scale/zero stay slot-indexed by b.
+    Dequantization happens in VMEM on the page — HBM moved only the
+    compressed bytes of mapped pages."""
     b = pl.program_id(0)
-    ib = pl.program_id(2)
+    ib = pl.program_id(1)
 
     @pl.when(ib == 0)
     def _init():
@@ -101,56 +117,37 @@ def _paged_kernel(bt_ref, len_ref, q_ref, ck_ref, cv_ref, sk_ref, zk_ref,
     # unmapped (null) pages sit wholly past the row's length: skip
     @pl.when(ib * page_size < len_ref[b])
     def _compute():
-        q = q_ref[0, 0]                                  # (G, Dh)
-        ck = ck_ref[0, :, 0, :]                          # (page, Dh) i8
-        cv = cv_ref[0, :, 0, :]                          # (page, Dv) i8
-
-        def onehot(lv):                                  # (page,) -> (page, L)
-            return (lv[:, None] == jax.lax.broadcasted_iota(
-                jnp.int32, (lv.shape[0], num_levels), 1)).astype(jnp.float32)
-
-        def dequant(codes, lv_oh, s_ref, z_ref):
-            # round dequantized tiles to bf16 like the jnp gather path
-            # (cpq_chunked_decode_attention) so paged-kernel decode stays
-            # token-exact vs it under greedy sampling
-            return _dequant(codes, lv_oh, s_ref, z_ref).astype(
-                jnp.bfloat16).astype(jnp.float32)
-
-        k_hat = dequant(ck, onehot(lvk_ref[0, :, 0]), sk_ref, zk_ref)
-        s = jax.lax.dot_general(q.astype(jnp.float32), k_hat,
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        pos = ib * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < len_ref[b], s, NEG_INF)      # partial last page
-
-        m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_sc[...] = m_new
-        v_hat = dequant(cv, onehot(lvv_ref[0, :, 0]), sv_ref, zv_ref)
-        acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
-            p, v_hat, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        for h in range(kv_heads):                        # static: per kv head
+            q = q_ref[0, h].astype(jnp.float32)          # (G, Dh)
+            k_hat = _page_dequant(ck_ref, lvk_ref, sk_ref, zk_ref, h,
+                                  num_levels)
+            s = jax.lax.dot_general(q, k_hat, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            pos = ib * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(pos < len_ref[b], s, NEG_INF)  # partial last page
+            v_hat = _page_dequant(cv_ref, lvv_ref, sv_ref, zv_ref, h,
+                                  num_levels)
+            online_update(s, v_hat, m_sc, l_sc, acc_sc, h)
 
     @pl.when(ib == nb - 1)
     def _finish():
-        o_ref[0, 0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(o_ref.dtype)
 
 
 def _paged_prefill_kernel(bt_ref, lens_ref, q_ref, ck_ref, cv_ref, sk_ref,
                           zk_ref, sv_ref, zv_ref, lvk_ref, lvv_ref, kraw_ref,
                           vraw_ref, o_ref, m_sc, l_sc, acc_sc, *, scale: float,
                           page_size: int, nb: int, num_levels: int, group: int,
-                          chunk: int):
-    """One (kv, ib) step of the Q-chunk>1 paged T2 prefill sweep for the slot
-    being admitted. Grid steps ib < nb dequantize the slot's EARLIER code
-    pages (positions < offset — cross-chunk keys read exactly what decode
-    will read); the extra final step ib == nb attends the chunk's RAW roped
-    K/V tile causally, so a single-chunk admission reproduces the one-shot
-    prefill's raw-attention numerics bit-for-bit. lens = (offset, valid)."""
-    ib = pl.program_id(1)
+                          kv_heads: int):
+    """One ib step of the Q-chunk>1 paged T2 prefill sweep for the slot
+    being admitted, all kv heads of a page at once. Grid steps ib < nb
+    dequantize the slot's EARLIER code pages (positions < offset —
+    cross-chunk keys read exactly what decode will read); the extra final
+    step ib == nb attends the chunk's RAW roped K/V tile causally, so a
+    single-chunk admission reproduces the one-shot prefill's raw-attention
+    numerics bit-for-bit. lens = (offset, valid)."""
+    ib = pl.program_id(0)
 
     @pl.when(ib == 0)
     def _init():
@@ -158,55 +155,38 @@ def _paged_prefill_kernel(bt_ref, lens_ref, q_ref, ck_ref, cv_ref, sk_ref,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    def online(s, v_tile):
-        m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_sc[...] = m_new
-        acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
-            p, v_tile, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
     # earlier-chunk pages: dequantize in VMEM, positions >= offset are dead
     # (the current chunk's keys are served raw by the final grid step)
     @pl.when((ib < nb) & (ib * page_size < lens_ref[0]))
     def _pages():
-        q = q_ref[0, 0].astype(jnp.float32)              # (C*G, Dh)
-        ck = ck_ref[0, :, 0, :]                          # (page, Dh) i8
-        cv = cv_ref[0, :, 0, :]                          # (page, Dv) i8
-
-        def onehot(lv):
-            return (lv[:, None] == jax.lax.broadcasted_iota(
-                jnp.int32, (lv.shape[0], num_levels), 1)).astype(jnp.float32)
-
-        def dequant(codes, lv_oh, s_ref, z_ref):
-            # bf16 rounding matches the jnp gather path (see _paged_kernel)
-            return _dequant(codes, lv_oh, s_ref, z_ref).astype(
-                jnp.bfloat16).astype(jnp.float32)
-
-        k_hat = dequant(ck, onehot(lvk_ref[0, :, 0]), sk_ref, zk_ref)
-        s = jax.lax.dot_general(q, k_hat, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        pos = ib * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < lens_ref[0], s, NEG_INF)     # earlier tokens only
-        online(s, dequant(cv, onehot(lvv_ref[0, :, 0]), sv_ref, zv_ref))
+        for h in range(kv_heads):                        # static: per kv head
+            q = q_ref[0, h].astype(jnp.float32)          # (C*G, Dh)
+            k_hat = _page_dequant(ck_ref, lvk_ref, sk_ref, zk_ref, h,
+                                  num_levels)
+            s = jax.lax.dot_general(q, k_hat, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            pos = ib * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(pos < lens_ref[0], s, NEG_INF)  # earlier tokens only
+            v_hat = _page_dequant(cv_ref, lvv_ref, sv_ref, zv_ref, h,
+                                  num_levels)
+            online_update(s, v_hat, m_sc, l_sc, acc_sc, h)
 
     # final step: the chunk's raw roped K/V, causal within the chunk
     @pl.when(ib == nb)
     def _raw_tail():
-        q = q_ref[0, 0].astype(jnp.float32)              # (C*G, Dh)
-        k = kraw_ref[:, 0, :].astype(jnp.float32)        # (C, Dh)
-        v = vraw_ref[:, 0, :].astype(jnp.float32)        # (C, Dv)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        qtok = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-        ok = (col < lens_ref[1]) & (col <= qtok)         # valid & causal
-        s = jnp.where(ok, s, NEG_INF)
-        online(s, v)
-        o_ref[0, 0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(
+        for h in range(kv_heads):
+            q = q_ref[0, h].astype(jnp.float32)          # (C*G, Dh)
+            k = kraw_ref[:, h, :].astype(jnp.float32)    # (C, Dh)
+            v = vraw_ref[:, h, :].astype(jnp.float32)    # (C, Dv)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            qtok = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+            ok = (col < lens_ref[1]) & (col <= qtok)     # valid & causal
+            s = jnp.where(ok, s, NEG_INF)
+            online_update(s, v, m_sc, l_sc, acc_sc, h)
+        o_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(
             o_ref.dtype)
 
 
@@ -237,7 +217,7 @@ def paged_cpq_prefill_fwd(q, codes_k, codes_v, scale_k, zero_k, scale_v,
 
     kern = functools.partial(_paged_prefill_kernel, scale=scale,
                              page_size=page, nb=nb, num_levels=L, group=G,
-                             chunk=C)
+                             kv_heads=KV)
     # page index maps clamp ib to nb-1 so the extra raw-tail grid step keeps
     # well-formed (dummy) page operands
     pg = lambda ib, bt: bt[jnp.minimum(ib, nb - 1)]  # noqa: E731
@@ -245,30 +225,30 @@ def paged_cpq_prefill_fwd(q, codes_k, codes_v, scale_k, zero_k, scale_v,
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # block_row, (offset, valid)
-            grid=(KV, nb + 1),      # block-table sweep + raw-chunk tail
+            grid=(nb + 1,),         # block-table sweep + raw-chunk tail
             in_specs=[
-                pl.BlockSpec((1, 1, CG, Dh), lambda kv, ib, bt, ln: (0, kv, 0, 0)),
-                pl.BlockSpec((1, page, 1, Dh),
-                             lambda kv, ib, bt, ln: (pg(ib, bt), 0, kv, 0)),
-                pl.BlockSpec((1, page, 1, Dv),
-                             lambda kv, ib, bt, ln: (pg(ib, bt), 0, kv, 0)),
-                pl.BlockSpec((1, L, 1, Dh), lambda kv, ib, bt, ln: (0, 0, kv, 0)),
-                pl.BlockSpec((1, L, 1, Dh), lambda kv, ib, bt, ln: (0, 0, kv, 0)),
-                pl.BlockSpec((1, L, 1, Dv), lambda kv, ib, bt, ln: (0, 0, kv, 0)),
-                pl.BlockSpec((1, L, 1, Dv), lambda kv, ib, bt, ln: (0, 0, kv, 0)),
-                pl.BlockSpec((1, page, 1),
-                             lambda kv, ib, bt, ln: (pg(ib, bt), 0, kv)),
-                pl.BlockSpec((1, page, 1),
-                             lambda kv, ib, bt, ln: (pg(ib, bt), 0, kv)),
-                pl.BlockSpec((C, 1, Dh), lambda kv, ib, bt, ln: (0, kv, 0)),
-                pl.BlockSpec((C, 1, Dv), lambda kv, ib, bt, ln: (0, kv, 0)),
+                pl.BlockSpec((1, KV, CG, Dh), lambda ib, bt, ln: (0, 0, 0, 0)),
+                pl.BlockSpec((1, page, KV, Dh),
+                             lambda ib, bt, ln: (pg(ib, bt), 0, 0, 0)),
+                pl.BlockSpec((1, page, KV, Dv),
+                             lambda ib, bt, ln: (pg(ib, bt), 0, 0, 0)),
+                pl.BlockSpec((1, L, KV, Dh), lambda ib, bt, ln: (0, 0, 0, 0)),
+                pl.BlockSpec((1, L, KV, Dh), lambda ib, bt, ln: (0, 0, 0, 0)),
+                pl.BlockSpec((1, L, KV, Dv), lambda ib, bt, ln: (0, 0, 0, 0)),
+                pl.BlockSpec((1, L, KV, Dv), lambda ib, bt, ln: (0, 0, 0, 0)),
+                pl.BlockSpec((1, page, KV),
+                             lambda ib, bt, ln: (pg(ib, bt), 0, 0)),
+                pl.BlockSpec((1, page, KV),
+                             lambda ib, bt, ln: (pg(ib, bt), 0, 0)),
+                pl.BlockSpec((C, KV, Dh), lambda ib, bt, ln: (0, 0, 0)),
+                pl.BlockSpec((C, KV, Dv), lambda ib, bt, ln: (0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, CG, Dv),
-                                   lambda kv, ib, bt, ln: (0, kv, 0, 0)),
+            out_specs=pl.BlockSpec((1, KV, CG, Dv),
+                                   lambda ib, bt, ln: (0, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((CG, 1), jnp.float32),
-                pltpu.VMEM((CG, 1), jnp.float32),
-                pltpu.VMEM((CG, Dv), jnp.float32),
+                pltpu.VMEM((KV, CG, 1), jnp.float32),
+                pltpu.VMEM((KV, CG, 1), jnp.float32),
+                pltpu.VMEM((KV, CG, Dv), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((1, KV, CG, Dv), jnp.float32),
@@ -299,33 +279,33 @@ def paged_cpq_decode_fwd(q, codes_k, codes_v, scale_k, zero_k, scale_v, zero_v,
     nb = block_table.shape[1]
 
     kern = functools.partial(_paged_kernel, scale=scale, page_size=page,
-                             nb=nb, num_levels=L)
+                             nb=nb, num_levels=L, kv_heads=KV)
     return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # block_table, lengths
-            grid=(B, KV, nb),
+            grid=(B, nb),
             in_specs=[
-                pl.BlockSpec((1, 1, G, Dh), lambda b, kv, ib, bt, ln: (b, kv, 0, 0)),
-                pl.BlockSpec((1, page, 1, Dh),
-                             lambda b, kv, ib, bt, ln: (bt[b, ib], 0, kv, 0)),
-                pl.BlockSpec((1, page, 1, Dv),
-                             lambda b, kv, ib, bt, ln: (bt[b, ib], 0, kv, 0)),
-                pl.BlockSpec((1, L, 1, Dh), lambda b, kv, ib, bt, ln: (b, 0, kv, 0)),
-                pl.BlockSpec((1, L, 1, Dh), lambda b, kv, ib, bt, ln: (b, 0, kv, 0)),
-                pl.BlockSpec((1, L, 1, Dv), lambda b, kv, ib, bt, ln: (b, 0, kv, 0)),
-                pl.BlockSpec((1, L, 1, Dv), lambda b, kv, ib, bt, ln: (b, 0, kv, 0)),
-                pl.BlockSpec((1, page, 1),
-                             lambda b, kv, ib, bt, ln: (bt[b, ib], 0, kv)),
-                pl.BlockSpec((1, page, 1),
-                             lambda b, kv, ib, bt, ln: (bt[b, ib], 0, kv)),
+                pl.BlockSpec((1, KV, G, Dh), lambda b, ib, bt, ln: (b, 0, 0, 0)),
+                pl.BlockSpec((1, page, KV, Dh),
+                             lambda b, ib, bt, ln: (bt[b, ib], 0, 0, 0)),
+                pl.BlockSpec((1, page, KV, Dv),
+                             lambda b, ib, bt, ln: (bt[b, ib], 0, 0, 0)),
+                pl.BlockSpec((1, L, KV, Dh), lambda b, ib, bt, ln: (b, 0, 0, 0)),
+                pl.BlockSpec((1, L, KV, Dh), lambda b, ib, bt, ln: (b, 0, 0, 0)),
+                pl.BlockSpec((1, L, KV, Dv), lambda b, ib, bt, ln: (b, 0, 0, 0)),
+                pl.BlockSpec((1, L, KV, Dv), lambda b, ib, bt, ln: (b, 0, 0, 0)),
+                pl.BlockSpec((1, page, KV),
+                             lambda b, ib, bt, ln: (bt[b, ib], 0, 0)),
+                pl.BlockSpec((1, page, KV),
+                             lambda b, ib, bt, ln: (bt[b, ib], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, G, Dv),
-                                   lambda b, kv, ib, bt, ln: (b, kv, 0, 0)),
+            out_specs=pl.BlockSpec((1, KV, G, Dv),
+                                   lambda b, ib, bt, ln: (b, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, Dv), jnp.float32),
+                pltpu.VMEM((KV, G, 1), jnp.float32),
+                pltpu.VMEM((KV, G, 1), jnp.float32),
+                pltpu.VMEM((KV, G, Dv), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, Dv), jnp.float32),

@@ -17,14 +17,12 @@ from repro.kernels.cpq_dequant_attn.kernel import (cpq_decode_fwd,
 def cpq_decode_tpu(q, cache: CPQKVCache, scale: float, block_n: int = 512,
                    interpret: bool | None = None):
     """q: (B, 1, H, Dh) roped query; cache: CPQKVCache. -> (B, 1, H, Dv)."""
-    if interpret is None:
-        interpret = K.INTERPRET
     B, _, H, Dh = q.shape
     KV = cache.k.codes.shape[2]
     g = H // KV
     qg = q[:, 0].reshape(B, KV, g, Dh)
-    out = cpq_decode_fwd(
-        qg, cache.k.codes, cache.v.codes,
+    out = K.platform_call(
+        cpq_decode_fwd, qg, cache.k.codes, cache.v.codes,
         cache.k.scale, cache.k.zero, cache.v.scale, cache.v.zero,
         cache.k.level, cache.v.level, cache.length, scale=scale,
         block_n=block_n, interpret=interpret)
@@ -40,18 +38,16 @@ def paged_cpq_prefill_tpu(q, kt, vt, k_raw, v_raw, slot, block_row, offset,
     kt/vt: PagedCPQTensor arenas; k_raw/v_raw: (1, C, KV, Dh|Dv);
     slot/offset/valid: () int32; block_row: (max_blocks,) int32.
     -> (1, C, H, Dv); rows past ``valid`` are jit-padding garbage."""
-    if interpret is None:
-        interpret = K.INTERPRET
     _, C, H, Dh = q.shape
     KV = kt.codes.shape[2]
     g = H // KV
     # (1, KV, C*G, Dh), token-major rows within each kv head
     qg = q[0].reshape(C, KV, g, Dh).transpose(1, 0, 2, 3).reshape(1, KV, C * g, Dh)
     sl = lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0)  # noqa: E731
-    out = paged_cpq_prefill_fwd(
-        qg, kt.codes, vt.codes, sl(kt.scale), sl(kt.zero), sl(vt.scale),
-        sl(vt.zero), kt.level, vt.level, k_raw[0], v_raw[0], block_row,
-        offset, valid, scale=scale, interpret=interpret)
+    out = K.platform_call(
+        paged_cpq_prefill_fwd, qg, kt.codes, vt.codes, sl(kt.scale),
+        sl(kt.zero), sl(vt.scale), sl(vt.zero), kt.level, vt.level, k_raw[0],
+        v_raw[0], block_row, offset, valid, scale=scale, interpret=interpret)
     Dv = out.shape[-1]
     return (out.reshape(KV, C, g, Dv).transpose(1, 0, 2, 3)
             .reshape(1, C, H, Dv).astype(q.dtype))
@@ -65,14 +61,12 @@ def paged_cpq_decode_tpu(q, kt, vt, block_table, lengths, scale: float,
     Dh) roped query; kt/vt: PagedCPQTensor (code/level pages + per-slot HQE
     scale/zero); block_table: (B, max_blocks) int32 (0 = null page);
     lengths: (B,) int32. -> (B, 1, H, Dv)."""
-    if interpret is None:
-        interpret = K.INTERPRET
     B, _, H, Dh = q.shape
     KV = kt.codes.shape[2]
     g = H // KV
     qg = q[:, 0].reshape(B, KV, g, Dh)
-    out = paged_cpq_decode_fwd(
-        qg, kt.codes, vt.codes, kt.scale, kt.zero, vt.scale, vt.zero,
-        kt.level, vt.level, block_table, lengths, scale=scale,
-        interpret=interpret)
+    out = K.platform_call(
+        paged_cpq_decode_fwd, qg, kt.codes, vt.codes, kt.scale, kt.zero,
+        vt.scale, vt.zero, kt.level, vt.level, block_table, lengths,
+        scale=scale, interpret=interpret)
     return out.reshape(B, 1, H, -1).astype(q.dtype)

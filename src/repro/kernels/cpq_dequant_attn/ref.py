@@ -52,6 +52,46 @@ def paged_cpq_decode_ref(q, codes_k, codes_v, scale_k, zero_k, scale_v, zero_v,
     return jnp.where((lengths > 0)[:, None, None, None], o, 0.0)
 
 
+def paged_cpq_prefill_ref(q, codes_k, codes_v, scale_k, zero_k, scale_v,
+                          zero_v, level_k, level_v, k_raw, v_raw, block_row,
+                          offset, valid, scale):
+    """Oracle for the chunked paged T2 prefill kernel, straight from the
+    paged layout: the slot's earlier code pages (positions < offset,
+    dequantized with this slot's HQE state and rounded to bf16) plus the
+    chunk's raw keys/values (positions offset + i, i < valid), causal.
+    q: (1, KV, C*G, Dh) token-major rows (row r = chunk token r // G);
+    codes_*/level_*: (P, page, KV, D*) / (P, page, KV) pools; scale_/zero_*:
+    (1, L, KV, D*); k_raw/v_raw: (C, KV, Dh|Dv); block_row: (max_blocks,).
+    -> (1, KV, C*G, Dv) f32; rows past ``valid`` are padding."""
+    _, KV, CG, _ = q.shape
+    C = k_raw.shape[0]
+    G = CG // C
+    N = block_row.shape[0] * codes_k.shape[1]
+    L = scale_k.shape[1]
+
+    def logical(codes, level, sc, zr):
+        c = jnp.take(codes, block_row, axis=0).reshape(1, N, KV, -1)
+        lv = jnp.clip(jnp.take(level, block_row, axis=0).reshape(1, N, KV),
+                      0, L - 1)
+        return _dequant_full(c, sc, zr, lv)[0].astype(
+            jnp.bfloat16).astype(jnp.float32)                   # (N, KV, D)
+
+    k_all = jnp.concatenate([logical(codes_k, level_k, scale_k, zero_k),
+                             k_raw.astype(jnp.float32)])
+    v_all = jnp.concatenate([logical(codes_v, level_v, scale_v, zero_v),
+                             v_raw.astype(jnp.float32)])
+    kpos = jnp.concatenate([jnp.arange(N, dtype=jnp.int32),
+                            offset + jnp.arange(C, dtype=jnp.int32)])
+    live = jnp.concatenate([jnp.arange(N) < offset, jnp.arange(C) < valid])
+    qpos = offset + jnp.arange(CG, dtype=jnp.int32) // G
+    ok = live[None, :] & (kpos[None, :] <= qpos[:, None])       # (CG, N + C)
+    s = jnp.einsum("kqd,nkd->kqn", q[0].astype(jnp.float32), k_all) * scale
+    s = jnp.where(ok[None], s, NEG_INF)
+    w = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    l = jnp.sum(w, axis=-1, keepdims=True)
+    return (jnp.einsum("kqn,nkd->kqd", w, v_all) / jnp.maximum(l, 1e-30))[None]
+
+
 def cpq_decode_ref(q, codes_k, codes_v, scale_k, zero_k, scale_v, zero_v,
                    level_k, level_v, length, scale):
     """q: (B, KV, G, Dh) -> (B, KV, G, Dv) f32."""

@@ -184,6 +184,15 @@ def _paged_prefill_kernel(bt_ref, lens_ref, r_ref, qr_ref, x_ref, kr_ref, p_ref,
         p_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(p_ref.dtype)
 
 
+def _vmem_limit(rows: int, dm: int, itemsize: int) -> int:
+    """Scoped-VMEM budget of the chunk prefill kernel: f32 accumulator,
+    double-buffered query and output blocks, and the lane-padded (rows, 1)
+    softmax statistics, with 8 MiB of headroom for page tiles and temporaries
+    (never below Mosaic's 16 MiB default)."""
+    need = rows * (4 * dm + 4 * dm * itemsize + 2 * 4 * 128)
+    return max(16 << 20, need + (8 << 20))
+
+
 def paged_decomposed_prefill_fwd(r: jax.Array, q_rope: jax.Array,
                                  x_pages: jax.Array, kr_pages: jax.Array,
                                  block_row: jax.Array, offset: jax.Array,
@@ -236,6 +245,11 @@ def paged_decomposed_prefill_fwd(r: jax.Array, q_rope: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((1, H * C, Dm), x_pages.dtype),
+        # every query row of the chunk stays resident: the f32 accumulator
+        # plus double-buffered query/output blocks outgrow the default scoped
+        # VMEM (16 MiB) from H*C = 2048 rows at Dm = 1024
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(H * C, Dm, x_pages.dtype.itemsize)),
         interpret=interpret,
     )(block_row.astype(jnp.int32), lens, r2, qr2, x_pages, kr_pages)
     return out.reshape(H, C, Dm).transpose(1, 0, 2)

@@ -24,8 +24,6 @@ def decomposed_decode_tpu(q_nope, q_rope, x_cache, k_rope, w_k_nope, w_v,
     """q_nope: (B,1,H,Dn); q_rope: (B,1,H,Rr); x_cache: (B,N,Dm);
     k_rope: (B,N,1,Rr) shared across heads (MLA layout) or Rr == 0;
     w_k_nope: (Dm, KV, Dn); w_v: (Dm, KV, Dv). Returns (B, 1, H, Dv)."""
-    if interpret is None:
-        interpret = K.INTERPRET
     B, _, H, Dn = q_nope.shape
     Dm = x_cache.shape[-1]
     KV, Dv = w_v.shape[1], w_v.shape[2]
@@ -40,9 +38,9 @@ def decomposed_decode_tpu(q_nope, q_rope, x_cache, k_rope, w_k_nope, w_v,
     qr = q_rope[:, 0] if q_rope is not None and q_rope.shape[-1] > 0 \
         else jnp.zeros((B, H, 0), x_cache.dtype)
 
-    p = decomposed_decode_fwd(r.astype(x_cache.dtype), qr.astype(x_cache.dtype),
-                              x_cache, kr, length, scale=scale,
-                              block_n=block_n, interpret=interpret)
+    p = K.platform_call(decomposed_decode_fwd, r.astype(x_cache.dtype),
+                        qr.astype(x_cache.dtype), x_cache, kr, length,
+                        scale=scale, block_n=block_n, interpret=interpret)
 
     # out = P W_V  (second tiny dense MatMul)
     pg = p.reshape(B, KV, g, Dm)
@@ -61,8 +59,6 @@ def paged_decomposed_prefill_tpu(q_nope, q_rope, x_pages, kr_pages,
     block_row: (max_blocks,) int32 (0 = null page); offset/valid: () int32;
     w_k_nope: (Dm, KV, Dn); w_v: (Dm, KV, Dv). -> (1, C, H, Dv); rows past
     ``valid`` are jit-padding garbage."""
-    if interpret is None:
-        interpret = K.INTERPRET
     _, C, H, Dn = q_nope.shape
     Dm = x_pages.shape[-1]
     KV, Dv = w_v.shape[1], w_v.shape[2]
@@ -75,9 +71,10 @@ def paged_decomposed_prefill_tpu(q_nope, q_rope, x_pages, kr_pages,
     qr = q_rope[0] if q_rope is not None and q_rope.shape[-1] > 0 \
         else jnp.zeros((C, H, 0), x_pages.dtype)
 
-    p = paged_decomposed_prefill_fwd(
-        r.astype(x_pages.dtype), qr.astype(x_pages.dtype), x_pages, kr_pages,
-        block_row, offset, valid, scale=scale, interpret=interpret)
+    p = K.platform_call(
+        paged_decomposed_prefill_fwd, r.astype(x_pages.dtype),
+        qr.astype(x_pages.dtype), x_pages, kr_pages, block_row, offset, valid,
+        scale=scale, interpret=interpret)
 
     # out = P W_V  (second tiny dense MatMul)
     pg = p.reshape(C, KV, g, Dm)
@@ -94,8 +91,6 @@ def paged_decomposed_decode_tpu(q_nope, q_rope, x_pages, kr_pages,
     KV_r == 1 (MLA shared rope) or per-kv-head; w_k_nope: (Dm, KV, Dn);
     w_v: (Dm, KV, Dv); block_table: (B, max_blocks) int32 (0 = null page);
     lengths: (B,) int32. Returns (B, 1, H, Dv)."""
-    if interpret is None:
-        interpret = K.INTERPRET
     B, _, H, Dn = q_nope.shape
     Dm = x_pages.shape[-1]
     KV, Dv = w_v.shape[1], w_v.shape[2]
@@ -108,9 +103,10 @@ def paged_decomposed_decode_tpu(q_nope, q_rope, x_pages, kr_pages,
     qr = q_rope[:, 0] if q_rope is not None and q_rope.shape[-1] > 0 \
         else jnp.zeros((B, H, 0), x_pages.dtype)
 
-    p = paged_decomposed_decode_fwd(
-        r.astype(x_pages.dtype), qr.astype(x_pages.dtype), x_pages, kr_pages,
-        block_table, lengths, scale=scale, interpret=interpret)
+    p = K.platform_call(
+        paged_decomposed_decode_fwd, r.astype(x_pages.dtype),
+        qr.astype(x_pages.dtype), x_pages, kr_pages, block_table, lengths,
+        scale=scale, interpret=interpret)
 
     # out = P W_V  (second tiny dense MatMul)
     pg = p.reshape(B, KV, g, Dm)

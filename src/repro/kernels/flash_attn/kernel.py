@@ -71,18 +71,33 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
             acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(o_ref.dtype)
 
 
+def online_update(s, v, m_sc, l_sc, acc_sc, h):
+    """One online-softmax step for kv head ``h``: fold scores ``s`` (rows,
+    n) and values ``v`` (n, Dv) into the f32 scratch state of that head."""
+    m_prev = m_sc[h]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    corr = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_sc[h] = l_sc[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+    m_sc[h] = m_new
+    acc_sc[h] = acc_sc[h] * corr + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
 def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                          m_sc, l_sc, acc_sc, *, scale: float, page_size: int,
-                         nb: int):
-    """One (b, kv, ib) step: the K/V tile IS physical page bt[b, ib] — the
-    BlockSpec index map resolved the block table before the body ran, so the
-    page was DMA'd straight from the arena into VMEM (no logical view).
+                         nb: int, kv_heads: int):
+    """One (b, ib) step: the K/V tile IS physical page bt[b, ib] with every
+    kv head of the page (block (1, page, KV, Dh): the last two block dims
+    span whole array dims, which Mosaic's (8, 128) tiling accepts at any
+    head count) — the BlockSpec index map resolved the block table before
+    the body ran, so the page was DMA'd straight from the arena into VMEM.
 
-    One sweep serves both attention matmuls per page (scores AND weighted-V
-    accumulate while the page sits in VMEM); softmax state is carried online
-    in f32 scratch across the block-table sweep."""
+    One sweep serves both attention matmuls of every head per page (scores
+    AND weighted-V accumulate while the page sits in VMEM); softmax state is
+    carried online in f32 scratch across the block-table sweep."""
     b = pl.program_id(0)
-    ib = pl.program_id(2)
+    ib = pl.program_id(1)
 
     @pl.when(ib == 0)
     def _init():
@@ -94,42 +109,36 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     # contents by convention) — skip them entirely: no MXU work
     @pl.when(ib * page_size < len_ref[b])
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)        # (G, Dh)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (page, Dh)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)  # (page, Dv)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # (G, page)
-        # null-page / partial-last-page masking: position vs per-row length
-        pos = ib * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < len_ref[b], s, NEG_INF)
-
-        m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_sc[...] = m_new
-        acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        for h in range(kv_heads):                      # static: per kv head
+            q = q_ref[0, h].astype(jnp.float32)        # (G, Dh)
+            k = k_ref[0, :, h, :].astype(jnp.float32)  # (page, Dh)
+            v = v_ref[0, :, h, :].astype(jnp.float32)  # (page, Dv)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale      # (G, page)
+            # null-page / partial-last-page masking: position vs row length
+            pos = ib * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(pos < len_ref[b], s, NEG_INF)
+            online_update(s, v, m_sc, l_sc, acc_sc, h)
 
     @pl.when(ib == nb - 1)
     def _finish():
-        o_ref[0, 0] = (
+        o_ref[0] = (
             acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(o_ref.dtype)
 
 
 def _paged_prefill_kernel(bt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
                           m_sc, l_sc, acc_sc, *, scale: float, page_size: int,
-                          nb: int, group: int):
-    """One (kv, ib) step of the Q-chunk>1 paged prefill sweep: queries are the
+                          nb: int, group: int, kv_heads: int):
+    """One ib step of the Q-chunk>1 paged prefill sweep: queries are the
     admission chunk's C tokens (flattened (C*G) rows per kv head), the K/V
-    tile IS physical page bt[ib] of the slot being admitted. lens holds
-    (offset, total): ``offset`` tokens preceded this chunk, ``total`` =
-    offset + valid masks the chunk's jit padding. Causal masking is per query
-    ROW: row r is chunk token r // G at absolute position offset + r // G."""
-    ib = pl.program_id(1)
+    tile IS physical page bt[ib] of the slot being admitted, all kv heads at
+    once. lens holds (offset, total): ``offset`` tokens preceded this chunk,
+    ``total`` = offset + valid masks the chunk's jit padding. Causal masking
+    is per query ROW: row r is chunk token r // G at absolute position
+    offset + r // G."""
+    ib = pl.program_id(0)
 
     @pl.when(ib == 0)
     def _init():
@@ -140,30 +149,23 @@ def _paged_prefill_kernel(bt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     # pages wholly past the row's post-chunk length are unmapped: skip
     @pl.when(ib * page_size < lens_ref[1])
     def _compute():
-        q = q_ref[0].astype(jnp.float32)           # (C*G, Dh)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (page, Dh)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)  # (page, Dv)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale          # (C*G, page)
-        pos = ib * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        qtok = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-        ok = (pos < lens_ref[1]) & (pos <= lens_ref[0] + qtok)   # valid & causal
-        s = jnp.where(ok, s, NEG_INF)
-
-        m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_sc[...] = m_new
-        acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        for h in range(kv_heads):                      # static: per kv head
+            q = q_ref[h].astype(jnp.float32)           # (C*G, Dh)
+            k = k_ref[0, :, h, :].astype(jnp.float32)  # (page, Dh)
+            v = v_ref[0, :, h, :].astype(jnp.float32)  # (page, Dv)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale      # (C*G, page)
+            pos = ib * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            qtok = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+            ok = (pos < lens_ref[1]) & (pos <= lens_ref[0] + qtok)  # causal
+            s = jnp.where(ok, s, NEG_INF)
+            online_update(s, v, m_sc, l_sc, acc_sc, h)
 
     @pl.when(ib == nb - 1)
     def _finish():
-        o_ref[0] = (
+        o_ref[...] = (
             acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(o_ref.dtype)
 
 
@@ -193,25 +195,24 @@ def paged_flash_prefill_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array
     lens = jnp.stack([offset, offset + valid]).astype(jnp.int32)
 
     kern = functools.partial(_paged_prefill_kernel, scale=scale,
-                             page_size=page, nb=nb, group=g)
+                             page_size=page, nb=nb, group=g, kv_heads=KV)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # block_row, (offset, total)
-            grid=(KV, nb),          # innermost axis sweeps block-table entries
+            grid=(nb,),             # sweeps the slot's block-table entries
             in_specs=[
-                pl.BlockSpec((1, C * g, Dh), lambda kv, ib, bt, ln: (kv, 0, 0)),
-                pl.BlockSpec((1, page, 1, Dh),
-                             lambda kv, ib, bt, ln: (bt[ib], 0, kv, 0)),
-                pl.BlockSpec((1, page, 1, Dv),
-                             lambda kv, ib, bt, ln: (bt[ib], 0, kv, 0)),
+                pl.BlockSpec((KV, C * g, Dh), lambda ib, bt, ln: (0, 0, 0)),
+                pl.BlockSpec((1, page, KV, Dh),
+                             lambda ib, bt, ln: (bt[ib], 0, 0, 0)),
+                pl.BlockSpec((1, page, KV, Dv),
+                             lambda ib, bt, ln: (bt[ib], 0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, C * g, Dv),
-                                   lambda kv, ib, bt, ln: (kv, 0, 0)),
+            out_specs=pl.BlockSpec((KV, C * g, Dv), lambda ib, bt, ln: (0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((C * g, 1), jnp.float32),
-                pltpu.VMEM((C * g, 1), jnp.float32),
-                pltpu.VMEM((C * g, Dv), jnp.float32),
+                pltpu.VMEM((KV, C * g, 1), jnp.float32),
+                pltpu.VMEM((KV, C * g, 1), jnp.float32),
+                pltpu.VMEM((KV, C * g, Dv), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((KV, C * g, Dv), q.dtype),
@@ -245,25 +246,25 @@ def paged_flash_decode_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     qg = q[:, 0].reshape(B, KV, g, Dh)
 
     kern = functools.partial(_paged_decode_kernel, scale=scale,
-                             page_size=page, nb=nb)
+                             page_size=page, nb=nb, kv_heads=KV)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # block_table, lengths
-            grid=(B, KV, nb),       # innermost axis sweeps block-table entries
+            grid=(B, nb),           # innermost axis sweeps block-table entries
             in_specs=[
-                pl.BlockSpec((1, 1, g, Dh), lambda b, kv, ib, bt, ln: (b, kv, 0, 0)),
-                pl.BlockSpec((1, page, 1, Dh),
-                             lambda b, kv, ib, bt, ln: (bt[b, ib], 0, kv, 0)),
-                pl.BlockSpec((1, page, 1, Dv),
-                             lambda b, kv, ib, bt, ln: (bt[b, ib], 0, kv, 0)),
+                pl.BlockSpec((1, KV, g, Dh), lambda b, ib, bt, ln: (b, 0, 0, 0)),
+                pl.BlockSpec((1, page, KV, Dh),
+                             lambda b, ib, bt, ln: (bt[b, ib], 0, 0, 0)),
+                pl.BlockSpec((1, page, KV, Dv),
+                             lambda b, ib, bt, ln: (bt[b, ib], 0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, g, Dv),
-                                   lambda b, kv, ib, bt, ln: (b, kv, 0, 0)),
+            out_specs=pl.BlockSpec((1, KV, g, Dv),
+                                   lambda b, ib, bt, ln: (b, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, Dv), jnp.float32),
+                pltpu.VMEM((KV, g, 1), jnp.float32),
+                pltpu.VMEM((KV, g, 1), jnp.float32),
+                pltpu.VMEM((KV, g, Dv), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, g, Dv), q.dtype),

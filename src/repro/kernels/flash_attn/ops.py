@@ -16,11 +16,9 @@ from repro.kernels.flash_attn.kernel import (flash_attention_fwd,
 def flash_attention_tpu(q, k, v, scale: float, causal: bool = True,
                         block_q: int = 512, block_k: int = 512,
                         interpret: bool | None = None):
-    if interpret is None:
-        interpret = K.INTERPRET
-    return flash_attention_fwd(q, k, v, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k,
-                               interpret=interpret)
+    return K.platform_call(flash_attention_fwd, q, k, v, scale=scale,
+                           causal=causal, block_q=block_q, block_k=block_k,
+                           interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -31,10 +29,9 @@ def paged_flash_prefill_tpu(q, k_pages, v_pages, block_row, offset, valid,
     (the chunk's K/V already live in those pages). q: (1, C, H, Dh);
     block_row: (max_blocks,) int32 (0 = null page); offset/valid: () int32.
     -> (1, C, H, Dv); rows past ``valid`` are jit-padding garbage."""
-    if interpret is None:
-        interpret = K.INTERPRET
-    return paged_flash_prefill_fwd(q, k_pages, v_pages, block_row, offset,
-                                   valid, scale=scale, interpret=interpret)
+    return K.platform_call(paged_flash_prefill_fwd, q, k_pages, v_pages,
+                           block_row, offset, valid, scale=scale,
+                           interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -44,7 +41,6 @@ def paged_flash_decode_tpu(q, k_pages, v_pages, block_table, lengths,
     table — no contiguous logical view. q: (B, 1, H, Dh); block_table:
     (B, max_blocks) int32 (0 = null page); lengths: (B,) int32 valid tokens
     per row. -> (B, 1, H, Dv)."""
-    if interpret is None:
-        interpret = K.INTERPRET
-    return paged_flash_decode_fwd(q, k_pages, v_pages, block_table, lengths,
-                                  scale=scale, interpret=interpret)
+    return K.platform_call(paged_flash_decode_fwd, q, k_pages, v_pages,
+                           block_table, lengths, scale=scale,
+                           interpret=interpret)

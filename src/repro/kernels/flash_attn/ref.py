@@ -36,6 +36,33 @@ def paged_flash_decode_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     return o.reshape(B, 1, H, -1).astype(q.dtype)
 
 
+def paged_flash_prefill_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+                            block_row: jax.Array, offset: jax.Array,
+                            valid: jax.Array, scale: float) -> jax.Array:
+    """Oracle for the chunked paged prefill kernel, straight from the paged
+    layout: q: (1, C, H, Dh) queries at positions offset + i attend the
+    slot's positions < offset + valid causally through block_row
+    (max_blocks,). -> (1, C, H, Dv); rows past ``valid`` are padding."""
+    _, C, H, Dh = q.shape
+    page, KV = k_pages.shape[1], k_pages.shape[2]
+    N = block_row.shape[0] * page
+    g = H // KV
+    kl = jnp.take(k_pages, block_row, axis=0).reshape(N, KV, Dh)
+    vl = jnp.take(v_pages, block_row, axis=0).reshape(N, KV, v_pages.shape[-1])
+    qg = q[0].reshape(C, KV, g, Dh)
+    s = jnp.einsum("ckgd,nkd->ckgn", qg.astype(jnp.float32),
+                   kl.astype(jnp.float32)) * scale
+    pos = jnp.arange(N, dtype=jnp.int32)
+    qpos = offset + jnp.arange(C, dtype=jnp.int32)
+    live = (pos[None, :] < offset + valid) & (pos[None, :] <= qpos[:, None])
+    s = jnp.where(live[:, None, None, :], s, NEG_INF)
+    w = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    l = jnp.sum(w, axis=-1, keepdims=True)
+    o = jnp.einsum("ckgn,nkd->ckgd", w, vl.astype(jnp.float32))
+    o = o / jnp.maximum(l, 1e-30)
+    return o.reshape(1, C, H, -1).astype(q.dtype)
+
+
 def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, scale: float,
                         causal: bool = True) -> jax.Array:
     """q: (B, T, H, D); k/v: (B, S, KV, D) -> (B, T, H, Dv). Exact SDA."""

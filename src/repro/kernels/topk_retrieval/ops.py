@@ -20,16 +20,14 @@ def proxy_scores_tpu(q, proxy_scale, proxy_zero, codes, length,
     """q: (B, H, Dp) pre-scaled query (incl. attention scale);
     proxy_scale/zero: (B, KV, Dp); codes: (B, N, KV, Dp) i8.
     Returns (B, H, N) f32."""
-    if interpret is None:
-        interpret = K.INTERPRET
     B, H, Dp = q.shape
     KV = codes.shape[2]
     g = H // KV
     qf = q.astype(jnp.float32).reshape(B, KV, g, Dp)
     qs = qf * proxy_scale[:, :, None, :]
     qz = jnp.einsum("bkgd,bkd->bkg", qf, proxy_zero)[..., None]
-    s = proxy_scores_fwd(qs, qz, codes, length, block_n=block_n,
-                         interpret=interpret)
+    s = K.platform_call(proxy_scores_fwd, qs, qz, codes, length,
+                        block_n=block_n, interpret=interpret)
     return s.reshape(B, H, codes.shape[1])
 
 

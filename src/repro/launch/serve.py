@@ -245,9 +245,10 @@ def main(argv=None):
         eng = ServeEngine(cfg, params, max_len=args.prompt + args.new)
     gen = GenerationConfig(max_new_tokens=args.new, temperature=args.temperature,
                            seed=args.seed)
-    t0 = time.time()
+    t0 = time.perf_counter()
     out, stats = eng.generate(batch, gen)
-    dt = time.time() - t0
+    out = jax.block_until_ready(out)
+    dt = time.perf_counter() - t0
 
     from repro.core import kv_cache as kvc
     from repro.models.attention_layer import decoupled_rope_dims
@@ -280,9 +281,12 @@ def main(argv=None):
         print(f"[serve] router aggregate: "
               f"{stats['tokens_per_step']:.2f} tok/step over "
               f"{stats['decode_steps_max']} lockstep ticks ({rows})")
-    print(f"[serve] arch={cfg.name} mode={mode}")
+    dev = jax.devices()[0]
+    print(f"[serve] arch={cfg.name} mode={mode} device={dev.platform} "
+          f"kind={dev.device_kind!r} count={len(jax.devices())}")
     print(f"[serve] generated {out.shape} in {dt:.2f}s "
-          f"({out.size / max(dt, 1e-9):.1f} tok/s batch-aggregate)")
+          f"({out.size / max(dt, 1e-9):.1f} tok/s batch-aggregate, "
+          "compile included)")
     print(f"[serve] decode cache traffic: {bpt:.1f} B/token/layer "
           f"({cfg.num_layers * bpt / 1024:.1f} KiB/token end-to-end)")
     print(f"[serve] sample row: {out[0][:16].tolist()}")

@@ -110,6 +110,13 @@ def sample_token_rows(logits: jax.Array, temps: jax.Array, top_ks: jax.Array,
     return jnp.where(temps <= 0.0, greedy, sampled)
 
 
+def greedy_token_rows(logits: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(B, V) logits -> ((B,) int32 argmax, (B,) bool all-finite): the
+    greedy draw and the logits health check in one device call."""
+    return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+            jnp.isfinite(logits).all(axis=-1))
+
+
 # --------------------------------------------------------------- static engine
 
 
@@ -210,6 +217,7 @@ class _ServeState:
         self.step = 0                 # model-invocation tick clock
         self.decode_steps = self.live_steps = self.prefill_chunks = 0
         self.prefill_tokens = self.generated = 0
+        self.nonfinite_rows = 0       # sampled rows whose logits had NaN/Inf
         self.traffic = self.prefill_write_bytes = self.interconnect = 0.0
         self.util_peak = self.util_sum = 0.0
         self.util_n = 0
@@ -334,6 +342,7 @@ class ContinuousServeEngine:
         self._n_cache_layers = sum(1 for m, _ in cfg.layer_kinds if m in ("attn", "mla"))
         self.policy = policy          # object/str override of serving.policy
         self._sample_rows = jax.jit(sample_token_rows)
+        self._greedy_rows = jax.jit(greedy_token_rows)
         self._st: Optional[_ServeState] = None
 
     def make_policy(self):
@@ -474,29 +483,35 @@ class ContinuousServeEngine:
         the same jitted per-row sampler, at stream index ``num_generated``
         (0 on fresh admission; the replay index after preemption, so
         recompute re-draws identical keys). Greedy requests short-circuit
-        to the plain argmax (the legacy ops, at the legacy cost)."""
+        to the plain argmax. Either way the logits' finite check feeds the
+        ``nonfinite_logit_rows`` stat."""
         sp = req.sampling
-        if sp.temperature <= 0.0:
-            return int(np.asarray(jnp.argmax(logits, axis=-1))[0])
-        args = (jnp.full((1,), sp.temperature, jnp.float32),
-                jnp.full((1,), sp.top_k, jnp.int32),
-                jnp.full((1,), sp.top_p, jnp.float32),
-                jnp.full((1,), sp.seed & 0x7fffffff, jnp.int32),
-                jnp.full((1,), req.num_generated, jnp.int32))
-        out = self._sample_rows(logits, *self._place_replicated(args))
+        out, finite = self._greedy_rows(logits)
+        self._st.nonfinite_rows += int(not np.asarray(finite)[0])
+        if sp.temperature > 0.0:
+            args = (jnp.full((1,), sp.temperature, jnp.float32),
+                    jnp.full((1,), sp.top_k, jnp.int32),
+                    jnp.full((1,), sp.top_p, jnp.float32),
+                    jnp.full((1,), sp.seed & 0x7fffffff, jnp.int32),
+                    jnp.full((1,), req.num_generated, jnp.int32))
+            out = self._sample_rows(logits, *self._place_replicated(args))
         return int(np.asarray(out)[0])
 
-    def _sample_active(self, st: _ServeState, logits: jax.Array) -> np.ndarray:
-        """One jitted per-row sampling call over the decode batch. Row r's
-        stream index is its request's ``num_generated`` (the index of the
-        token being drawn); inactive rows sample garbage that the caller
-        masks out, exactly as their logits always were. An all-greedy batch
-        (the default, and every legacy suite) skips the sampler entirely for
-        the single argmax the old engine ran — ``temps`` is host state, so
-        the check costs nothing and the jitted sort/softmax/categorical
-        machinery never enters the greedy hot path."""
+    def _sample_active(self, st: _ServeState, logits: jax.Array
+                       ) -> tuple[np.ndarray, np.ndarray]:
+        """One jitted per-row sampling call over the decode batch, plus the
+        per-row all-finite flags of the logits. Row r's stream index is its
+        request's ``num_generated`` (the index of the token being drawn);
+        inactive rows sample garbage that the caller masks out, exactly as
+        their logits always were. An all-greedy batch (the default, and
+        every legacy suite) skips the sampler entirely for the single argmax
+        the old engine ran — ``temps`` is host state, so the check costs
+        nothing and the jitted sort/softmax/categorical machinery never
+        enters the greedy hot path."""
+        greedy, finite = self._greedy_rows(logits)
+        finite = np.asarray(finite)
         if (st.temp <= 0.0).all():
-            return np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
+            return np.asarray(greedy), finite
         sched = st.sched
         idx = np.array([r.num_generated if (r := sched.slots[s]) is not None
                         else 0 for s in range(self.serving.num_slots)],
@@ -504,8 +519,8 @@ class ContinuousServeEngine:
         args = (jnp.asarray(st.temp), jnp.asarray(st.top_k),
                 jnp.asarray(st.top_p), jnp.asarray(st.seed),
                 jnp.asarray(idx))
-        return np.asarray(self._sample_rows(logits,
-                                            *self._place_replicated(args)))
+        return np.asarray(self._sample_rows(
+            logits, *self._place_replicated(args))), finite
 
     def _row_state(self, sched: Scheduler, active=None) -> pgc.RowState:
         return pgc.RowState(
@@ -1240,7 +1255,8 @@ class ContinuousServeEngine:
         logits, st.caches = self._decode(self.params,
                                          jnp.asarray(st.last_tok[:, None]),
                                          rows, st.caches)
-        toks = self._sample_active(st, logits)
+        toks, finite = self._sample_active(st, logits)
+        st.nonfinite_rows += int((active & ~finite).sum())
         st.decode_steps += 1
         st.live_steps += int(active.sum())
         tier_arr = sched.tiers
@@ -1294,6 +1310,7 @@ class ContinuousServeEngine:
             "prefill_chunks": st.prefill_chunks,
             "prefill_tokens": st.prefill_tokens,
             "generated_tokens": st.generated,
+            "nonfinite_logit_rows": st.nonfinite_rows,
             "tokens_per_step": st.generated / max(st.decode_steps, 1),
             "slot_utilization": st.live_steps / max(st.decode_steps * B, 1),
             "arena_utilization_mean": st.util_sum / max(st.util_n, 1),

@@ -54,24 +54,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-# replication checking is off: out_specs mix head-sharded attention outputs
-# with replicated cache side state that the checker cannot always prove
-# replicated. The kwarg was renamed check_rep -> check_vma across jax
-# versions; pick whichever this jax exposes.
-import inspect as _inspect
-
-_CHECK_KW = ("check_vma" if "check_vma"
-             in _inspect.signature(_shard_map_impl).parameters else "check_rep")
-
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_CHECK_KW: False})
+    # replication checking is off: out_specs mix head-sharded attention
+    # outputs with replicated cache side state that the checker cannot
+    # always prove replicated
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 MODEL_AXIS = "model"
@@ -148,6 +137,17 @@ def _gather_latent(x_local: jax.Array) -> jax.Array:
                               tiled=True)
 
 
+def _gather_heads(mesh, out: jax.Array) -> jax.Array:
+    """Concatenate the per-device head outputs on every device. Left
+    head-sharded, the output projection would contract over sharded heads:
+    GSPMD then all-reduces bf16 partial sums, which rounds differently from
+    the single-device engine and flips greedy near-ties. Replicated, the
+    projection runs exactly as on one device."""
+    from jax.sharding import NamedSharding
+
+    return jax.lax.with_sharding_constraint(out, NamedSharding(mesh, P()))
+
+
 def _split(kw: dict, mesh):
     """(present-operands dict, fitted specs dict) — None operands stay out of
     the shard_map argument tree and are reinstated in the body."""
@@ -162,7 +162,7 @@ def decode_attend_sharded(
 ):
     """shard_map wrapper of ``decode_attend_paged``: per-device sweep of the
     local head shard; only per-head outputs are concatenated. Returns
-    (out (B,1,H,Dv) head-sharded, new_cache) with cache specs preserved."""
+    (out (B,1,H,Dv) replicated, new_cache) with cache specs preserved."""
     from repro.serving import paged_cache as pgc
 
     mesh = rt.mesh
@@ -201,11 +201,12 @@ def decode_attend_sharded(
             return out, cache
         return pgc.decode_attend_paged(rt_local, cache, rows, scale=scale, **a)
 
-    return _shard_map(
+    out, cache = _shard_map(
         body, mesh,
         in_specs=(cspecs, rspecs, pspecs),
         out_specs=(P(None, None, MODEL_AXIS, None), cspecs),
     )(cache, rows, present)
+    return _gather_heads(mesh, out), cache
 
 
 def chunk_attend_sharded(
@@ -214,7 +215,7 @@ def chunk_attend_sharded(
 ):
     """shard_map wrapper of ``chunk_attend_paged`` (chunked paged prefill):
     the chunk's payload lands in each device's local arena shard and its C
-    queries attend per head shard. Returns (out (1,C,H,Dv) head-sharded,
+    queries attend per head shard. Returns (out (1,C,H,Dv) replicated,
     new_cache).
 
     C is whatever the caller compiled — prompt chunks (``prefill_chunk``)
@@ -268,11 +269,12 @@ def chunk_attend_sharded(
             rt_local, cache, tier=tier, first=first, slot=slot,
             block_row=block_row, offset=offset, valid=valid, scale=scale, **a)
 
-    return _shard_map(
+    out, cache = _shard_map(
         body, mesh,
         in_specs=(cspecs, sspecs, pspecs),
         out_specs=(P(None, None, MODEL_AXIS, None), cspecs),
     )(cache, scalars, present)
+    return _gather_heads(mesh, out), cache
 
 
 def validate_serve_mesh(cfg, rt, tiered: bool = False) -> int:

@@ -75,7 +75,8 @@ def test_hlo_analysis_collectives(run8):
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.distributed.hlo_analysis import analyze
-mesh = jax.make_mesh((8,), ('d',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('d',))
 def h(x, w):
     def body(c, _): return c @ w, None
     y, _ = jax.lax.scan(body, x, None, length=3)
@@ -97,7 +98,8 @@ def test_flash_decoding_and_ring(run8):
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.collectives import flash_decoding_attention, ring_decomposed_scores
 from repro.core.attention import dense_attention
-mesh = jax.make_mesh((8,), ('s',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('s',))
 key = jax.random.PRNGKey(0)
 B,H,KV,Dh,N = 2,8,4,32,128
 ks = jax.random.split(key,4)
@@ -119,7 +121,8 @@ def test_gpipe(run8):
     out = run8("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.pipeline import gpipe_forward
-mesh = jax.make_mesh((4,), ('pod',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ('pod',))
 key = jax.random.PRNGKey(0)
 w = jax.random.normal(key, (8, 16, 16)) / 4.0
 x = jax.random.normal(key, (6, 2, 16))

@@ -276,3 +276,126 @@ def test_paged_kernels_greedy_exact_vs_gather_f32():
     np.testing.assert_allclose(np.asarray(out_k)[live], np.asarray(out_g)[live],
                                atol=2e-5)
     _argmax_where_resolvable(np.asarray(out_k)[live], np.asarray(out_g)[live])
+
+
+# ------------------------------------------- chunked prefill vs ref.py oracles
+
+
+def _prefill_layout(rng, page, nb, offset, valid):
+    """One slot's block row: the pages holding positions < offset + valid
+    mapped in PERMUTED physical order, the rest left on the poisoned null
+    page. Returns (num_pages, block_row)."""
+    used = -(-(offset + valid) // page)
+    num_pages = 1 + nb + 2
+    row = np.zeros((nb,), np.int32)
+    row[:used] = rng.permutation(np.arange(1, num_pages))[:used]
+    return num_pages, row
+
+
+@pytest.mark.parametrize("seed,page,nb,C,KV,g,offset,valid", [
+    (0, 4, 6, 8, 2, 2, 0, 8),
+    (1, 4, 6, 8, 2, 1, 8, 5),    # continuation chunk, jit padding
+    (2, 2, 8, 4, 4, 1, 6, 4),
+])
+def test_paged_flash_prefill_vs_ref(seed, page, nb, C, KV, g, offset, valid):
+    from repro.kernels.flash_attn.kernel import paged_flash_prefill_fwd
+    from repro.kernels.flash_attn.ref import paged_flash_prefill_ref
+
+    rng = np.random.default_rng(seed)
+    num_pages, row = _prefill_layout(rng, page, nb, offset, valid)
+    kp = rng.normal(size=(num_pages, page, KV, 16)).astype(np.float32)
+    vp = rng.normal(size=(num_pages, page, KV, 16)).astype(np.float32)
+    kp[0] = vp[0] = 1e3                               # poison the null page
+    q = rng.normal(size=(1, C, KV * g, 16)).astype(np.float32)
+    args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(row),
+            jnp.asarray(offset, jnp.int32), jnp.asarray(valid, jnp.int32))
+    out = paged_flash_prefill_fwd(*args, scale=0.25, interpret=True)
+    ref = paged_flash_prefill_ref(*args, 0.25)
+    np.testing.assert_allclose(np.asarray(out)[0, :valid],
+                               np.asarray(ref)[0, :valid], atol=2e-5)
+
+
+@pytest.mark.parametrize("seed,page,nb,C,KV,g,offset,valid", [
+    (0, 4, 6, 8, 2, 2, 0, 8),    # first chunk: raw tail only
+    (1, 4, 6, 8, 2, 1, 12, 3),
+])
+def test_paged_cpq_prefill_vs_ref(seed, page, nb, C, KV, g, offset, valid):
+    from repro.kernels.cpq_dequant_attn.kernel import paged_cpq_prefill_fwd
+    from repro.kernels.cpq_dequant_attn.ref import paged_cpq_prefill_ref
+
+    rng = np.random.default_rng(seed)
+    num_pages, row = _prefill_layout(rng, page, nb, offset, valid)
+    Dh, L = 16, 3
+    codes = lambda: jnp.asarray(rng.integers(  # noqa: E731
+        -128, 128, size=(num_pages, page, KV, Dh)).astype(np.int8))
+    levels = lambda: jnp.asarray(rng.integers(  # noqa: E731
+        0, L, size=(num_pages, page, KV)).astype(np.int32))
+    side = lambda: jnp.asarray(  # noqa: E731
+        np.abs(rng.normal(size=(1, L, KV, Dh))).astype(np.float32) + 0.05)
+    args = (jnp.asarray(rng.normal(size=(1, KV, C * g, Dh)).astype(np.float32)),
+            codes(), codes(), side(), side(), side(), side(), levels(),
+            levels(),
+            jnp.asarray(rng.normal(size=(C, KV, Dh)).astype(np.float32)),
+            jnp.asarray(rng.normal(size=(C, KV, Dh)).astype(np.float32)),
+            jnp.asarray(row), jnp.asarray(offset, jnp.int32),
+            jnp.asarray(valid, jnp.int32))
+    out = paged_cpq_prefill_fwd(*args, scale=0.2, interpret=True)
+    ref = paged_cpq_prefill_ref(*args, 0.2)
+    rows = np.arange(C * g) // g < valid
+    np.testing.assert_allclose(np.asarray(out)[0][:, rows],
+                               np.asarray(ref)[0][:, rows], atol=3e-5)
+
+
+@pytest.mark.parametrize("seed,page,nb,C,H,kv_r,Rr,offset,valid", [
+    (0, 4, 6, 8, 4, 1, 8, 0, 8),     # MLA layout: shared rope head
+    (1, 4, 6, 8, 4, 4, 8, 8, 6),     # per-kv-head rope (decoupled T1)
+    (2, 2, 8, 4, 2, 1, 0, 4, 4),     # no rope
+])
+def test_paged_decomposed_prefill_vs_ref(seed, page, nb, C, H, kv_r, Rr,
+                                         offset, valid):
+    from repro.kernels.decomposed_attn.kernel import paged_decomposed_prefill_fwd
+    from repro.kernels.decomposed_attn.ref import paged_decomposed_prefill_ref
+
+    rng = np.random.default_rng(seed)
+    num_pages, row = _prefill_layout(rng, page, nb, offset, valid)
+    Dm = 16
+    xp = rng.normal(size=(num_pages, page, Dm)).astype(np.float32)
+    krp = rng.normal(size=(num_pages, page, kv_r, Rr)).astype(np.float32)
+    xp[0] = 1e3                                       # poison the null page
+    args = (jnp.asarray(rng.normal(size=(C, H, Dm)).astype(np.float32)),
+            jnp.asarray(rng.normal(size=(C, H, Rr)).astype(np.float32)),
+            jnp.asarray(xp), jnp.asarray(krp), jnp.asarray(row),
+            jnp.asarray(offset, jnp.int32), jnp.asarray(valid, jnp.int32))
+    out = paged_decomposed_prefill_fwd(*args, scale=0.2, interpret=True)
+    ref = paged_decomposed_prefill_ref(*args, 0.2)
+    np.testing.assert_allclose(np.asarray(out)[:valid],
+                               np.asarray(ref)[:valid], atol=2e-4)
+
+
+# ------------------------------------------------- interpret mode by platform
+
+
+def test_interpret_mode_follows_the_platform(monkeypatch):
+    """A kernel call on CPU arrays interprets (the compiled Mosaic kernel
+    cannot run on the CPU backend), whatever the environment says: the
+    package has no interpret switch left to read."""
+    import importlib
+
+    import repro.kernels as K
+    from repro.kernels.flash_attn.ops import paged_flash_decode_tpu
+
+    for var in ("REPRO_INTERPRET", "INTERPRET"):
+        monkeypatch.setenv(var, "0")
+    importlib.reload(K)
+    assert not hasattr(K, "INTERPRET")
+    rng = np.random.default_rng(0)
+    num_pages, lengths, bt = _pool_layout(rng, 2, 3, 4)
+    kp = jnp.asarray(rng.normal(size=(num_pages, 4, 2, 8)).astype(np.float32))
+    q = jnp.asarray(rng.normal(size=(2, 1, 4, 8)).astype(np.float32))
+    args = (q, kp, kp, jnp.asarray(bt), jnp.asarray(lengths))
+    assert q.devices().pop().platform == "cpu"
+    hlo = paged_flash_decode_tpu.lower(*args, scale=0.3).compile().as_text()
+    assert "tpu_custom_call" not in hlo
+    out = paged_flash_decode_tpu(*args, scale=0.3)
+    ref = paged_flash_decode_tpu(*args, scale=0.3, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))

@@ -73,10 +73,11 @@ def test_compressed_psum_error_feedback(run8):
     out = run8("""
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.optim.compression import compressed_psum
-mesh = jax.make_mesh((8,), ('pod',))
+mesh = make_mesh((8,), ('pod',))
 key = jax.random.PRNGKey(0)
 x = jax.random.normal(key, (8, 128))  # row i = device i's gradient
 true_mean = jnp.mean(x, 0)
@@ -143,8 +144,9 @@ def test_checkpoint_elastic_reshard(run8):
 import jax, jax.numpy as jnp, numpy as np, tempfile
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import CheckpointManager
-m1 = jax.make_mesh((2, 4), ('a', 'b'))
-m2 = jax.make_mesh((8,), ('c',))
+from repro.launch.mesh import make_mesh
+m1 = make_mesh((2, 4), ('a', 'b'))
+m2 = make_mesh((8,), ('c',))
 x = jnp.arange(64.0).reshape(8, 8)
 xs = jax.device_put(x, NamedSharding(m1, P('a', 'b')))
 with tempfile.TemporaryDirectory() as d:

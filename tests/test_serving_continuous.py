@@ -343,3 +343,21 @@ def test_throughput_vs_static_acceptance():
     assert ratio >= 1.5, (st, ct)
     assert ct["arena_utilization"] > st["arena_utilization"]
     assert ct["latency_mean"] < st["latency_mean"]
+
+
+def test_nonfinite_logit_rows_are_counted(model):
+    """The engine counts sampled rows whose logits hold NaN/Inf (first
+    tokens and decode rows alike): zero on a healthy model, every emitted
+    token once the final norm is poisoned."""
+    cfg, params = model
+    serving = ServingCfg(num_slots=2, page_size=4, num_pages=17,
+                         max_blocks_per_slot=8, prefill_chunk=4)
+    reqs = lambda: _reqs(cfg, [5, 7], max_new=3)  # noqa: E731
+    eng = ContinuousServeEngine(cfg, params, serving=serving)
+    _, stats = eng.serve(reqs(), GenerationConfig(max_new_tokens=3))
+    assert stats["nonfinite_logit_rows"] == 0
+    bad = dict(params, final_norm=jax.tree.map(
+        lambda a: jnp.full_like(a, jnp.nan), params["final_norm"]))
+    eng = ContinuousServeEngine(cfg, bad, serving=serving)
+    _, stats = eng.serve(reqs(), GenerationConfig(max_new_tokens=3))
+    assert stats["nonfinite_logit_rows"] == stats["generated_tokens"] == 6

@@ -252,6 +252,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.serving import paged_cache as pgc
 
 def scenario(seed, num_pages, n_slots, mp):
@@ -259,7 +260,7 @@ def scenario(seed, num_pages, n_slots, mp):
     replicated arena and a model-sharded one: logical contents (the
     gathered per-slot views) must match exactly for any mesh shape.\"\"\"
     page, kv, dh, max_blocks = 2, 8, 4, 4
-    mesh = jax.make_mesh((1, mp), ("data", "model"))
+    mesh = make_mesh((1, mp), ("data", "model"))
     sh = NamedSharding(mesh, P(None, None, "model", None))
     rng = np.random.default_rng(seed)
     ref = jnp.zeros((num_pages, page, kv, dh), jnp.float32)
