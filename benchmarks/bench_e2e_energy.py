@@ -56,8 +56,8 @@ class TrafficCfg:
     # measured by ContinuousServeEngine's ``interconnect_bytes`` stat)
     interconnect_bytes_per_token_layer: float = 0.0
     # idle-vs-active serving utilization: the mean fraction of batch slots
-    # that emit a useful token per decode step, measured from the engine's
-    # per-tick ``trace_active_rows`` series (bench_serving). Below 1.0 a
+    # that emit a useful token per decode step, the engine's
+    # ``slot_utilization`` stat (bench_serving). Below 1.0 a
     # useful token pays (a) the 1/u amplification of the per-step weight
     # stream (idle rows ride the same step) and (b) the idle share of the
     # board's static power. 1.0 (the default) reproduces the pre-trace
@@ -97,8 +97,8 @@ def decode_token_cost(dev: Device, n_params: float, L: int, cfg: TrafficCfg):
 
 def measured_paged_utilization(n_requests: int = 10, rate: float = 1.0):
     """Run the REAL continuous engine on the bench_serving mixed-length
-    Poisson trace (smoke model) and reduce its per-tick utilization traces
-    to the means the analytical model charges: (slot_util, arena_util,
+    Poisson trace (smoke model) and read the utilization means the
+    analytical model charges from its stats: (slot_util, arena_util,
     ticks). Falls back to recorded smoke-run constants when the engine
     cannot run (e.g. no jax in a stripped environment)."""
     try:
@@ -115,9 +115,8 @@ def measured_paged_utilization(n_requests: int = 10, rate: float = 1.0):
         max_len = max(len(w.prompt) + w.target for w in work)
         r = run_continuous(cfg, params, work,
                            equal_arena_serving(4, max_len, page_size=8))
-        trace = r["trace_active_rows"]
-        return (float(trace.mean()) / 4.0,
-                float(r["trace_arena_util"].mean()), int(len(trace)))
+        return (float(r["slot_utilization"]), float(r["arena_utilization"]),
+                int(r["decode_steps"]))
     except Exception:  # pragma: no cover - jax-less fallback
         return 0.72, 0.55, 0
 

@@ -252,6 +252,7 @@ def paged_cpq_prefill_fwd(q, codes_k, codes_v, scale_k, zero_k, scale_v,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((1, KV, CG, Dv), jnp.float32),
+        name="paged_cpq_prefill",
         interpret=interpret,
     )(block_row.astype(jnp.int32), lens,
       q, codes_k, codes_v, scale_k, zero_k, scale_v, zero_v,
@@ -309,6 +310,7 @@ def paged_cpq_decode_fwd(q, codes_k, codes_v, scale_k, zero_k, scale_v, zero_v,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, Dv), jnp.float32),
+        name="paged_cpq_decode",
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
       q, codes_k, codes_v, scale_k, zero_k, scale_v, zero_v,
@@ -360,6 +362,7 @@ def cpq_decode_fwd(q, codes_k, codes_v, scale_k, zero_k, scale_v, zero_v,
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, Dv), jnp.float32),
         ],
+        name="cpq_decode",
         interpret=interpret,
     )(length.reshape(1).astype(jnp.int32), q, codes_k, codes_v,
       scale_k, zero_k, scale_v, zero_v,

@@ -250,6 +250,7 @@ def paged_decomposed_prefill_fwd(r: jax.Array, q_rope: jax.Array,
         # VMEM (16 MiB) from H*C = 2048 rows at Dm = 1024
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(H * C, Dm, x_pages.dtype.itemsize)),
+        name="paged_t1_prefill",
         interpret=interpret,
     )(block_row.astype(jnp.int32), lens, r2, qr2, x_pages, kr_pages)
     return out.reshape(H, C, Dm).transpose(1, 0, 2)
@@ -304,6 +305,7 @@ def paged_decomposed_decode_fwd(r: jax.Array, q_rope: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, Dm), x_pages.dtype),
+        name="paged_t1_decode",
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
       r, q_rope, x_pages, kr_pages)
@@ -345,6 +347,7 @@ def decomposed_decode_fwd(r: jax.Array, q_rope: jax.Array, x: jax.Array,
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, Dm), jnp.float32),
         ],
+        name="t1_decode",
         interpret=interpret,
     )(length.reshape(1).astype(jnp.int32),
       r,

@@ -216,6 +216,7 @@ def paged_flash_prefill_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((KV, C * g, Dv), q.dtype),
+        name="paged_flash_prefill",
         interpret=interpret,
     )(block_row.astype(jnp.int32), lens, qg, k_pages, v_pages)
     return out.reshape(KV, C, g, Dv).transpose(1, 0, 2, 3).reshape(1, C, H, Dv)
@@ -268,6 +269,7 @@ def paged_flash_decode_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, g, Dv), q.dtype),
+        name="paged_flash_decode",
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
       qg, k_pages, v_pages)
@@ -312,6 +314,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
     return out[:, :T]

@@ -61,6 +61,7 @@ def proxy_scores_fwd(qs, qz, codes, length, *, block_n: int = 1024,
         ],
         out_specs=pl.BlockSpec((1, 1, G, bn), lambda b, kv, ib: (b, kv, 0, ib)),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, N + pad), jnp.float32),
+        name="proxy_scores",
         interpret=interpret,
     )(length.reshape(1).astype(jnp.int32), qs, qz, codes)
     return out[..., :N]

@@ -222,25 +222,29 @@ def attn_prefill_chunk(cfg: ModelConfig, rt: AttentionRuntime, tier: int,
     absolute ``positions``; ``tier``/``first`` are host-static."""
     from repro.serving import paged_cache as pgc
 
-    q, k, v = _project_qkv(cfg, p, x)
+    decomposed = rt.mode in ("decomposed", "decomposed_cpq")
     r = decoupled_rope_dims(cfg)
+    with jax.named_scope("attn.qkv"):
+        q, k, v = _project_qkv(cfg, p, x)
+        q, k = _rope_qk(cfg, q, k, positions, positions,
+                        dims=r if decomposed else None)
 
-    if rt.mode in ("decomposed", "decomposed_cpq"):
-        q, k = _rope_qk(cfg, q, k, positions, positions, dims=r)
-        wk_nope, wv, _ = _wk_wv_heads(cfg, p)
-        out, cache = pgc.chunk_attend_paged(
-            rt, cache, tier=tier, first=first, slot=slot, block_row=block_row,
-            offset=offset, valid=valid, q=q, k_c=k, v_c=v, x_c=x,
-            k_rope_c=k[..., :r], q_nope=q[..., r:], q_rope=q[..., :r],
-            w_k_nope=wk_nope, w_v=wv, scale=_scale(cfg))
-    else:
-        q, k = _rope_qk(cfg, q, k, positions, positions)
-        out, cache = pgc.chunk_attend_paged(
-            rt, cache, tier=tier, first=first, slot=slot, block_row=block_row,
-            offset=offset, valid=valid, q=q, k_c=k, v_c=v, x_c=None,
-            k_rope_c=None, q_nope=None, q_rope=None, w_k_nope=None, w_v=None,
-            scale=_scale(cfg))
-    return _out(cfg, p, out), cache
+    with jax.named_scope("attn.kernel"):   # its cache writes: kv_write
+        if decomposed:
+            wk_nope, wv, _ = _wk_wv_heads(cfg, p)
+            out, cache = pgc.chunk_attend_paged(
+                rt, cache, tier=tier, first=first, slot=slot,
+                block_row=block_row, offset=offset, valid=valid, q=q, k_c=k,
+                v_c=v, x_c=x, k_rope_c=k[..., :r], q_nope=q[..., r:],
+                q_rope=q[..., :r], w_k_nope=wk_nope, w_v=wv, scale=_scale(cfg))
+        else:
+            out, cache = pgc.chunk_attend_paged(
+                rt, cache, tier=tier, first=first, slot=slot,
+                block_row=block_row, offset=offset, valid=valid, q=q, k_c=k,
+                v_c=v, x_c=None, k_rope_c=None, q_nope=None, q_rope=None,
+                w_k_nope=None, w_v=None, scale=_scale(cfg))
+    with jax.named_scope("attn.out"):
+        return _out(cfg, p, out), cache
 
 
 def init_paged_attn_cache(cfg: ModelConfig, rt: AttentionRuntime, serving,
@@ -288,22 +292,27 @@ def attn_decode_rows(cfg: ModelConfig, rt: AttentionRuntime, p, x_t: jax.Array,
     rows.lengths)."""
     from repro.serving import paged_cache as pgc
 
-    q, k, v = _project_qkv(cfg, p, x_t)
+    decomposed = rt.mode in ("decomposed", "decomposed_cpq")
     r = decoupled_rope_dims(cfg)
+    with jax.named_scope("attn.qkv"):
+        q, k, v = _project_qkv(cfg, p, x_t)
+        q, k = _rope_qk_rows(cfg, q, k, rows.lengths,
+                             dims=r if decomposed else None)
 
-    if rt.mode in ("decomposed", "decomposed_cpq"):
-        q, k = _rope_qk_rows(cfg, q, k, rows.lengths, dims=r)
-        wk_nope, wv, _ = _wk_wv_heads(cfg, p)
-        out, cache = pgc.decode_attend_paged(
-            rt, cache, rows, q=q, k_t=k, v_t=v, x_t=x_t, k_rope_t=k[..., :r],
-            q_nope=q[..., r:], q_rope=q[..., :r], w_k_nope=wk_nope, w_v=wv,
-            scale=_scale(cfg))
-    else:
-        q, k = _rope_qk_rows(cfg, q, k, rows.lengths)
-        out, cache = pgc.decode_attend_paged(
-            rt, cache, rows, q=q, k_t=k, v_t=v, x_t=None, k_rope_t=None,
-            q_nope=None, q_rope=None, w_k_nope=None, w_v=None, scale=_scale(cfg))
-    return _out(cfg, p, out), cache
+    with jax.named_scope("attn.kernel"):   # its cache writes: kv_write
+        if decomposed:
+            wk_nope, wv, _ = _wk_wv_heads(cfg, p)
+            out, cache = pgc.decode_attend_paged(
+                rt, cache, rows, q=q, k_t=k, v_t=v, x_t=x_t,
+                k_rope_t=k[..., :r], q_nope=q[..., r:], q_rope=q[..., :r],
+                w_k_nope=wk_nope, w_v=wv, scale=_scale(cfg))
+        else:
+            out, cache = pgc.decode_attend_paged(
+                rt, cache, rows, q=q, k_t=k, v_t=v, x_t=None, k_rope_t=None,
+                q_nope=None, q_rope=None, w_k_nope=None, w_v=None,
+                scale=_scale(cfg))
+    with jax.named_scope("attn.out"):
+        return _out(cfg, p, out), cache
 
 
 # cross-attention serving: K/V are static per request (computed at prefill),

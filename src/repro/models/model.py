@@ -237,30 +237,39 @@ def decode_step_rows(cfg: ModelConfig, rt: AttentionRuntime, params,
     and X/MLA attention tiers read their arenas through the fused paged
     Pallas kernels (pages DMA'd via the block table, no logical view);
     otherwise caches are gathered through the block table in jnp.
-    tokens: (B, 1) int32. Returns (logits (B, V), caches)."""
-    x = embed_inputs(cfg, params["embed"], {"tokens": tokens}, rows.lengths[:, None])
+    tokens: (B, 1) int32. Returns (logits (B, V), caches).
 
-    new_prefix = []
-    for kind, p, c in zip(cfg.prefix_pattern, params["prefix"], caches["prefix"]):
-        x, c2 = tfm.layer_decode_rows(cfg, rt, kind, p, x, rows, c)
-        new_prefix.append(c2)
+    Profiler scopes (metadata only): ``embed``; ``layers`` around the layer
+    stack, whose own ops are the scan's moves of the stacked arena and
+    weights; per layer ``norm``, ``attn.qkv``, ``kv_write``,
+    ``attn.kernel``, ``attn.out``, ``mlp``; then ``lm_head``."""
+    with jax.named_scope("embed"):
+        x = embed_inputs(cfg, params["embed"], {"tokens": tokens},
+                         rows.lengths[:, None])
 
-    new_blocks = caches["blocks"]
-    if cfg.num_blocks:
-        def body(x, inp):
-            block_params, block_caches = _barrier(inp)
-            outs = []
-            for kind, p, c in zip(cfg.block_pattern, block_params, block_caches):
-                x, c2 = tfm.layer_decode_rows(cfg, rt, kind, p, x, rows, c)
-                outs.append(c2)
-            return x, tuple(outs)
+    with jax.named_scope("layers"):
+        new_prefix = []
+        for kind, p, c in zip(cfg.prefix_pattern, params["prefix"], caches["prefix"]):
+            x, c2 = tfm.layer_decode_rows(cfg, rt, kind, p, x, rows, c)
+            new_prefix.append(c2)
 
-        x, new_blocks = jax.lax.scan(
-            body, x, (tuple(params["blocks"]), tuple(caches["blocks"])))
-        new_blocks = list(new_blocks)
+        new_blocks = caches["blocks"]
+        if cfg.num_blocks:
+            def body(x, inp):
+                block_params, block_caches = _barrier(inp)
+                outs = []
+                for kind, p, c in zip(cfg.block_pattern, block_params, block_caches):
+                    x, c2 = tfm.layer_decode_rows(cfg, rt, kind, p, x, rows, c)
+                    outs.append(c2)
+                return x, tuple(outs)
 
-    x = apply_norm(cfg, params["final_norm"], x)
-    logits = lm_logits(cfg, params, x)[:, 0]
+            x, new_blocks = jax.lax.scan(
+                body, x, (tuple(params["blocks"]), tuple(caches["blocks"])))
+            new_blocks = list(new_blocks)
+
+    with jax.named_scope("lm_head"):
+        x = apply_norm(cfg, params["final_norm"], x)
+        logits = lm_logits(cfg, params, x)[:, 0]
     return logits, {"prefix": new_prefix, "blocks": new_blocks}
 
 
@@ -274,33 +283,36 @@ def _chunk_forward(cfg: ModelConfig, rt: AttentionRuntime, tier: int,
     ``block_row``; the chunk's queries attend ``[0, offset + i]`` via the
     per-query-row causal mask). Returns the pre-norm hidden states
     (1, C, D) and the updated caches — the prefill head keeps the last
-    valid position's logits, the speculative verify head keeps them all."""
+    valid position's logits, the speculative verify head keeps them all.
+    Profiler scopes as in ``decode_step_rows``; the heads add ``lm_head``."""
     C = tokens.shape[1]
     positions = offset + jnp.arange(C, dtype=jnp.int32)
-    x = embed_inputs(cfg, params["embed"], {"tokens": tokens}, positions)
+    with jax.named_scope("embed"):
+        x = embed_inputs(cfg, params["embed"], {"tokens": tokens}, positions)
 
-    new_prefix = []
-    for kind, p, c in zip(cfg.prefix_pattern, params["prefix"], caches["prefix"]):
-        x, c2 = tfm.layer_prefill_chunk(cfg, rt, tier, first, kind, p, x,
-                                        positions, slot, block_row, offset,
-                                        valid, c)
-        new_prefix.append(c2)
+    with jax.named_scope("layers"):
+        new_prefix = []
+        for kind, p, c in zip(cfg.prefix_pattern, params["prefix"], caches["prefix"]):
+            x, c2 = tfm.layer_prefill_chunk(cfg, rt, tier, first, kind, p, x,
+                                            positions, slot, block_row, offset,
+                                            valid, c)
+            new_prefix.append(c2)
 
-    new_blocks = caches["blocks"]
-    if cfg.num_blocks:
-        def body(x, inp):
-            block_params, block_caches = _barrier(inp)
-            outs = []
-            for kind, p, c in zip(cfg.block_pattern, block_params, block_caches):
-                x, c2 = tfm.layer_prefill_chunk(cfg, rt, tier, first, kind, p,
-                                                x, positions, slot, block_row,
-                                                offset, valid, c)
-                outs.append(c2)
-            return x, tuple(outs)
+        new_blocks = caches["blocks"]
+        if cfg.num_blocks:
+            def body(x, inp):
+                block_params, block_caches = _barrier(inp)
+                outs = []
+                for kind, p, c in zip(cfg.block_pattern, block_params, block_caches):
+                    x, c2 = tfm.layer_prefill_chunk(cfg, rt, tier, first, kind, p,
+                                                    x, positions, slot, block_row,
+                                                    offset, valid, c)
+                    outs.append(c2)
+                return x, tuple(outs)
 
-        x, new_blocks = jax.lax.scan(
-            body, x, (tuple(params["blocks"]), tuple(caches["blocks"])))
-        new_blocks = list(new_blocks)
+            x, new_blocks = jax.lax.scan(
+                body, x, (tuple(params["blocks"]), tuple(caches["blocks"])))
+            new_blocks = list(new_blocks)
 
     return x, {"prefix": new_prefix, "blocks": new_blocks}
 
@@ -320,9 +332,10 @@ def prefill_chunk_rows(cfg: ModelConfig, rt: AttentionRuntime, tier: int,
     the updated paged caches)."""
     x, caches = _chunk_forward(cfg, rt, tier, first, params, tokens, slot,
                                block_row, offset, valid, caches)
-    x = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
-    x = apply_norm(cfg, params["final_norm"], x)
-    logits = lm_logits(cfg, params, x)[:, 0]
+    with jax.named_scope("lm_head"):
+        x = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
+        x = apply_norm(cfg, params["final_norm"], x)
+        logits = lm_logits(cfg, params, x)[:, 0]
     return logits, caches
 
 
@@ -340,8 +353,9 @@ def verify_chunk_rows(cfg: ModelConfig, rt: AttentionRuntime, tier: int,
     ``i >= valid`` are jit padding (never sampled, writes masked)."""
     x, caches = _chunk_forward(cfg, rt, tier, first, params, tokens, slot,
                                block_row, offset, valid, caches)
-    x = apply_norm(cfg, params["final_norm"], x)
-    logits = lm_logits(cfg, params, x)
+    with jax.named_scope("lm_head"):
+        x = apply_norm(cfg, params["final_norm"], x)
+        logits = lm_logits(cfg, params, x)
     return logits, caches
 
 

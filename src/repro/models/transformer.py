@@ -6,6 +6,7 @@ hence compile time and remat behaviour — is independent of depth).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Optional
 
 import jax
@@ -61,11 +62,13 @@ def stacked_block_defs(cfg: ModelConfig):
 def _apply_mlp_part(cfg: ModelConfig, mlp: str, p, x):
     if mlp == "none":
         return x, jnp.zeros((), jnp.float32)
-    h = apply_norm(cfg, p["norm2"], x)
-    if mlp == "moe":
-        y, aux = moe_lib.apply_moe(cfg, p["mlp"], h)
-        return x + y, aux
-    return x + apply_mlp(cfg, p["mlp"], h), jnp.zeros((), jnp.float32)
+    with jax.named_scope("norm"):
+        h = apply_norm(cfg, p["norm2"], x)
+    with jax.named_scope("mlp"):
+        if mlp == "moe":
+            y, aux = moe_lib.apply_moe(cfg, p["mlp"], h)
+            return x + y, aux
+        return x + apply_mlp(cfg, p["mlp"], h), jnp.zeros((), jnp.float32)
 
 
 def layer_train(cfg: ModelConfig, kind: tuple[str, str], p, x: jax.Array,
@@ -134,7 +137,8 @@ def layer_decode_rows(cfg: ModelConfig, rt: AttentionRuntime, kind: tuple[str, s
     reuse their contiguous decode; retired slots' garbage state is overwritten
     at the next admission."""
     mixer, mlp = kind
-    h = apply_norm(cfg, p["norm1"], x_t)
+    with jax.named_scope("norm"):
+        h = apply_norm(cfg, p["norm1"], x_t)
     if mixer == "attn":
         y, cache = attn.attn_decode_rows(cfg, rt, p["mixer"], h, rows, cache)
     elif mixer == "xattn":
@@ -147,7 +151,8 @@ def layer_decode_rows(cfg: ModelConfig, rt: AttentionRuntime, kind: tuple[str, s
         y, cache = xlstm_lib.mlstm_decode(cfg, p["mixer"], h, cache)
     elif mixer == "slstm":
         y, cache = xlstm_lib.slstm_decode(cfg, p["mixer"], h, cache)
-    x_t = x_t + y
+    with jax.named_scope("attn.out") if mixer == "attn" else contextlib.nullcontext():
+        x_t = x_t + y
     x_t, _ = _apply_mlp_part(cfg, mlp, p, x_t)
     return x_t, cache
 
@@ -162,7 +167,8 @@ def layer_prefill_chunk(cfg: ModelConfig, rt: AttentionRuntime, tier: int,
     mixers never reach here — the engine keeps their exact one-shot
     admission (state integration cannot be cut at page boundaries)."""
     mixer, mlp = kind
-    h = apply_norm(cfg, p["norm1"], x)
+    with jax.named_scope("norm"):
+        h = apply_norm(cfg, p["norm1"], x)
     if mixer == "attn":
         y, cache = attn.attn_prefill_chunk(cfg, rt, tier, first, p["mixer"], h,
                                            positions, slot, block_row, offset,
@@ -174,7 +180,8 @@ def layer_prefill_chunk(cfg: ModelConfig, rt: AttentionRuntime, tier: int,
     else:
         raise ValueError(f"chunked prefill has no {mixer!r} path "
                          "(engine falls back to one-shot admission)")
-    x = x + y
+    with jax.named_scope("attn.out") if mixer == "attn" else contextlib.nullcontext():
+        x = x + y
     x, _ = _apply_mlp_part(cfg, mlp, p, x)
     return x, cache
 

@@ -49,6 +49,28 @@ from repro.serving import paged_cache as pgc
 from repro.serving.request import RequestOutput, SamplingParams, ServeRequest
 from repro.serving.scheduler import Request, Scheduler, SchedulerConfigError
 
+# Host spans of the serving loop. A span costs about half a microsecond
+# while no profiler session is open, and lands on the trace's host plane, on
+# the device planes' clock, when one is.
+_span = jax.profiler.TraceAnnotation
+
+
+def _named_jit(fn, *bound):
+    """``jax.jit(partial(fn, *bound))`` that keeps ``fn``'s name: the
+    compiled program reaches the profiler as ``jit_<fn name>(...)``, where a
+    bare ``partial`` shows as ``jit__unknown``."""
+    f = partial(fn, *bound)
+    f.__name__ = f.__qualname__ = fn.__name__
+    return jax.jit(f)
+
+
+def _device_memory() -> dict:
+    """Device 0's ``bytes_in_use`` and ``peak_bytes_in_use``, the args of the
+    once-a-session ``serve.reset``/``serve.release`` spans; empty where the
+    backend keeps no memory stats (the CPU)."""
+    m = jax.local_devices()[0].memory_stats() or {}
+    return {k: m[k] for k in ("bytes_in_use", "peak_bytes_in_use") if k in m}
+
 
 @dataclasses.dataclass(frozen=True)
 class GenerationConfig:
@@ -130,8 +152,8 @@ class ServeEngine:
         self.rt = rt or cfg.attention
         self.params = params
         self.max_len = max_len
-        self._prefill = jax.jit(partial(M.prefill, cfg, self.rt))
-        self._decode = jax.jit(partial(M.decode_step, cfg, self.rt))
+        self._prefill = _named_jit(M.prefill, cfg, self.rt)
+        self._decode = _named_jit(M.decode_step, cfg, self.rt)
 
     def _sample(self, logits: jax.Array, key, gen: GenerationConfig) -> jax.Array:
         return sample_tokens(logits, key, gen)
@@ -223,10 +245,6 @@ class _ServeState:
         self.util_n = 0
         self.defrag_mark = 0          # retirements at the last compaction
         self.has_deadlines = False    # any finite request deadline admitted
-        # per-decode-tick utilization traces (active rows / arena fill) —
-        # the idle-vs-active series bench_e2e_energy's device model charges
-        self.trace_active: list[int] = []
-        self.trace_util: list[float] = []
         self.t0 = time.time()
 
 
@@ -307,10 +325,10 @@ class ContinuousServeEngine:
         # prefill at exact lengths (more jit variants, exact math)
         self._exact_prefill = any(m in ("mamba", "mlstm", "slstm")
                                   for m, _ in cfg.layer_kinds)
-        self._decode = jax.jit(partial(M.decode_step_rows, cfg, rt))
-        self._pack = jax.jit(partial(M.pack_prefill_caches, cfg, rt))
-        self._escalate = jax.jit(partial(M.escalate_slot, cfg, rt))
-        self._defrag = jax.jit(partial(M.defrag_caches, cfg, rt))
+        self._decode = _named_jit(M.decode_step_rows, cfg, rt)
+        self._pack = _named_jit(M.pack_prefill_caches, cfg, rt)
+        self._escalate = _named_jit(M.escalate_slot, cfg, rt)
+        self._defrag = _named_jit(M.defrag_caches, cfg, rt)
         self._prefills: dict[str, object] = {}   # one-shot oracle path only
         self._chunk_fns: dict[tuple[int, bool], object] = {}
         # two layer families keep the exact one-shot admission: recurrent
@@ -337,7 +355,7 @@ class ContinuousServeEngine:
         self.spec_on = (serving.spec_len > 0 and self.chunked
                         and rt.mode in ("dense", "decomposed"))
         self._verify_fns: dict[int, object] = {}
-        self._copy_page = jax.jit(partial(M.copy_page_caches, cfg, rt))
+        self._copy_page = _named_jit(M.copy_page_caches, cfg, rt)
         # cache-bearing layer count for the traffic model
         self._n_cache_layers = sum(1 for m, _ in cfg.layer_kinds if m in ("attn", "mla"))
         self.policy = policy          # object/str override of serving.policy
@@ -368,7 +386,7 @@ class ContinuousServeEngine:
 
     def _prefill_for(self, rt: AttentionRuntime):
         if rt.mode not in self._prefills:
-            self._prefills[rt.mode] = jax.jit(partial(M.prefill, self.cfg, rt))
+            self._prefills[rt.mode] = _named_jit(M.prefill, self.cfg, rt)
         return self._prefills[rt.mode]
 
     def _chunk_fn(self, tier: int, first: bool):
@@ -378,8 +396,8 @@ class ContinuousServeEngine:
         key = (tier, first)
         if key not in self._chunk_fns:
             rt_t = self._rt_for_tier(tier)
-            self._chunk_fns[key] = jax.jit(
-                partial(M.prefill_chunk_rows, self.cfg, rt_t, tier, first))
+            self._chunk_fns[key] = _named_jit(
+                M.prefill_chunk_rows, self.cfg, rt_t, tier, first)
         return self._chunk_fns[key]
 
     def _verify_fn(self, tier: int):
@@ -389,8 +407,8 @@ class ContinuousServeEngine:
         (``first=False``: a running row always has history)."""
         if tier not in self._verify_fns:
             rt_t = self._rt_for_tier(tier)
-            self._verify_fns[tier] = jax.jit(
-                partial(M.verify_chunk_rows, self.cfg, rt_t, tier, False))
+            self._verify_fns[tier] = _named_jit(
+                M.verify_chunk_rows, self.cfg, rt_t, tier, False)
         return self._verify_fns[tier]
 
     def _bucketed(self, ctx: np.ndarray) -> tuple[np.ndarray, int]:
@@ -554,7 +572,14 @@ class ContinuousServeEngine:
         """Start a fresh serving session: new scheduler (fresh policy
         instance), empty arenas, empty output buffer. ``gen`` supplies
         session-wide legacy defaults — ``eos_id`` and the SamplingParams
-        derived for plain scheduler ``Request`` objects."""
+        derived for plain scheduler ``Request`` objects. Traced as one
+        ``serve.reset`` span carrying device 0's memory once the new arenas
+        are placed."""
+        with _span("serve.reset") as span:
+            self._st = self._new_session(gen)
+            span.set_metadata(**_device_memory())
+
+    def _new_session(self, gen: GenerationConfig) -> _ServeState:
         st = _ServeState(self, gen)
         st.bpt0, st.bpt1 = self._tier_bpt(st.caches)
         st.quantum = self.serving.prefill_chunk or self.serving.prefill_bucket
@@ -582,7 +607,7 @@ class ContinuousServeEngine:
         # per-invocation pool all-gather — charged per model invocation, not
         # per token (zero for head-sharded tiers and unsharded engines)
         st.gather_bps = self._latent_gather_bytes_per_step(st.caches)
-        self._st = st
+        return st
 
     def _ensure_state(self) -> _ServeState:
         if self._st is None:
@@ -594,7 +619,14 @@ class ContinuousServeEngine:
         """Submit one request to the live session (created on first use; see
         ``reset``). Accepts the public ``ServeRequest`` spec or a raw
         scheduler ``Request`` (legacy). ``stream`` overrides the request's
-        per-token ``RequestOutput`` callback. Returns the request id."""
+        per-token ``RequestOutput`` callback. Returns the request id.
+        Traced as one ``serve.add_request`` span with the request's ``rid``."""
+        with _span("serve.add_request") as span:
+            rid = self._add_request(req, stream)
+            span.set_metadata(rid=rid)
+        return rid
+
+    def _add_request(self, req: Union[ServeRequest, Request], stream) -> int:
         st = self._ensure_state()
         if isinstance(req, ServeRequest):
             rid = req.rid if req.rid is not None else st.next_rid
@@ -774,8 +806,12 @@ class ContinuousServeEngine:
     def release(self) -> None:
         """Drop the live serving session — scheduler, arenas (device
         memory goes with them), sampling arrays, output buffers. The next
-        ``add_request()`` / ``reset()`` starts a fresh session."""
-        self._st = None
+        ``add_request()`` / ``reset()`` starts a fresh session. Traced as
+        one ``serve.release`` span carrying device 0's memory as the session
+        ends, before its arenas go."""
+        with _span("serve.release") as span:
+            span.set_metadata(**_device_memory())
+            self._st = None
 
     # ----------------------------------------------------- result plumbing
 
@@ -1018,8 +1054,6 @@ class ContinuousServeEngine:
         st.util_peak = max(st.util_peak, util)
         st.util_sum += util
         st.util_n += 1
-        st.trace_active.append(1)
-        st.trace_util.append(util)
         st.step += 1
 
         draws = self._verify_draws(req, logits[0])
@@ -1053,146 +1087,139 @@ class ContinuousServeEngine:
         front — ``ceil(padded_len / quantum)`` ticks during which no row
         decodes — which is exactly the head-of-line stall chunked admission
         removes (quantum = ``prefill_chunk`` or, on the one-shot path,
-        ``prefill_bucket``)."""
+        ``prefill_bucket``).
+
+        A tick that does work is one ``serve.tick`` profiler span whose
+        children name its host phases, in order: ``serve.admit``,
+        ``serve.prefill_chunk``, ``serve.grow``, ``serve.speculate``,
+        ``serve.decode``, ``serve.sample``, ``serve.emit`` (docs/serving.md,
+        Observability)."""
         st = self._ensure_state()
         st.step_outputs = []
-        sched = st.sched
-        if not sched.has_work():
+        if not st.sched.has_work():
             return []
+        with _span("serve.tick"):
+            self._tick(st)
+        return st.step_outputs
+
+    def _tick(self, st: _ServeState) -> None:
+        """The body of ``step()``; its events land in ``st.step_outputs``."""
+        sched = st.sched
         B = self.serving.num_slots
 
-        # -1) deadline-aware shedding: blown budgets retire BEFORE this
-        #     tick's admissions, so their freed slots/pages refill now
-        self._expire_deadlines(st)
-        if not sched.has_work():
-            return st.step_outputs
+        with _span("serve.admit") as span:
+            # -1) deadline-aware shedding: blown budgets retire BEFORE this
+            #     tick's admissions, so their freed slots/pages refill now
+            self._expire_deadlines(st)
+            if not sched.has_work():
+                return
 
-        # 0) periodic base-arena compaction (defrag_every retirements):
-        #    the scheduler relabels mapped pages onto the lowest ids and
-        #    the jitted permutation moves every base page pool to match
-        if (self.serving.defrag_every
-                and sched.stats["retired"] - st.defrag_mark
-                >= self.serving.defrag_every):
-            st.defrag_mark = sched.stats["retired"]
-            perm = sched.plan_defrag()
-            if perm is not None:
-                st.caches = self._defrag(st.caches, jnp.asarray(perm))
+            # 0) periodic base-arena compaction (defrag_every retirements):
+            #    the scheduler relabels mapped pages onto the lowest ids and
+            #    the jitted permutation moves every base page pool to match
+            if (self.serving.defrag_every
+                    and sched.stats["retired"] - st.defrag_mark
+                    >= self.serving.defrag_every):
+                st.defrag_mark = sched.stats["retired"]
+                perm = sched.plan_defrag()
+                if perm is not None:
+                    st.caches = self._defrag(st.caches, jnp.asarray(perm))
 
-        # 1) admissions into vacated slots (the POLICY picks who and which
-        #    tier). Chunked (default): the slot enters the prefilling state
-        #    and its prompt streams below. One-shot oracle: prefill the
-        #    whole context now and charge the clock its chunk-equivalents
-        #    (the head-of-line stall).
-        while (req := sched.admit_next(now=st.step, step=st.step)) is not None:
-            self._resolve_sampling(req, st)
-            if self.chunked:
-                continue  # pump below interleaves one chunk per tick
-            tok, padded = self._admit(req, st)
-            st.step += -(-padded // st.quantum)  # monolithic prefill stall
-            # no interconnect charge: the one-shot prefill runs as a
-            # replicated global jit (no shard_map), so under a mesh it
-            # pays mp-fold redundant FLOPs instead of concat traffic;
-            # the pack then writes each device's arena slice from the
-            # locally-present replicated payload
-            st.prefill_tokens += req.length
-            st.prefill_write_bytes += (req.length
-                                       * (st.bpt1 if req.tier else st.bpt0)
-                                       * self._n_cache_layers)
-            self._emit_token(st, req, tok, st.step)  # ready after the stall
+            # 1) admissions into vacated slots (the POLICY picks who and
+            #    which tier). Chunked (default): the slot enters the
+            #    prefilling state and its prompt streams below. One-shot
+            #    oracle: prefill the whole context now and charge the clock
+            #    its chunk-equivalents (the head-of-line stall).
+            admitted = []
+            while (req := sched.admit_next(now=st.step, step=st.step)) is not None:
+                admitted.append(req.rid)
+                self._resolve_sampling(req, st)
+                if self.chunked:
+                    continue  # pump below interleaves one chunk per tick
+                tok, padded = self._admit(req, st)
+                st.step += -(-padded // st.quantum)  # monolithic prefill stall
+                # no interconnect charge: the one-shot prefill runs as a
+                # replicated global jit (no shard_map), so under a mesh it
+                # pays mp-fold redundant FLOPs instead of concat traffic;
+                # the pack then writes each device's arena slice from the
+                # locally-present replicated payload
+                st.prefill_tokens += req.length
+                st.prefill_write_bytes += (req.length
+                                           * (st.bpt1 if req.tier else st.bpt0)
+                                           * self._n_cache_layers)
+                self._emit_token(st, req, tok, st.step)  # ready after the stall
+            if admitted:
+                # space-separated: the profiler splits span args at commas
+                span.set_metadata(rid=" ".join(map(str, admitted)))
 
-        # 2) watermark policy: escalate running dense requests under
-        #    critical memory pressure (dense -> T2, pages freed)
-        while (cand := sched.escalation_candidate()) is not None:
-            slot, length = cand.slot, cand.length
-            dense_row, cpq_row = sched.apply_escalation(cand)
-            st.caches = self._escalate(st.caches, jnp.asarray(dense_row),
-                                       jnp.asarray(cpq_row),
-                                       jnp.asarray(slot, jnp.int32),
-                                       jnp.asarray(length, jnp.int32))
+            # 2) watermark policy: escalate running dense requests under
+            #    critical memory pressure (dense -> T2, pages freed)
+            while (cand := sched.escalation_candidate()) is not None:
+                slot, length = cand.slot, cand.length
+                dense_row, cpq_row = sched.apply_escalation(cand)
+                st.caches = self._escalate(st.caches, jnp.asarray(dense_row),
+                                           jnp.asarray(cpq_row),
+                                           jnp.asarray(slot, jnp.int32),
+                                           jnp.asarray(length, jnp.int32))
 
-        # 2b) recovery: when the dense free fraction sits above the HIGH
-        #     watermark, the policy may de-escalate ONE T2 row per tick
-        #     back to dense via chunked re-admission (bounded churn; CPQ
-        #     codes are lossy, so the dense K/V is rebuilt by exact
-        #     context replay through the admission path)
-        if (cand := sched.deescalation_candidate()) is not None:
-            slot = cand.slot
-            sched.deescalate(cand)
-            self._clear_row_sampling(st, slot)
+            # 2b) recovery: when the dense free fraction sits above the HIGH
+            #     watermark, the policy may de-escalate ONE T2 row per tick
+            #     back to dense via chunked re-admission (bounded churn; CPQ
+            #     codes are lossy, so the dense K/V is rebuilt by exact
+            #     context replay through the admission path)
+            if (cand := sched.deescalation_candidate()) is not None:
+                slot = cand.slot
+                sched.deescalate(cand)
+                self._clear_row_sampling(st, slot)
 
         # 3) chunked-prefill pump: at most ONE prompt chunk per tick
         #    (the per-step prefill token budget), written straight into
         #    the slot's arena pages and interleaved with the decode step
         #    below — long prompts no longer freeze running rows
-        did_chunk = False
-        fresh_slot = -1  # row whose prefill finished THIS tick
-        if self.chunked and (pre := sched.prefilling()):
-            req = pre[0]
-            # the first tail write of a shared-prefix admission may land
-            # inside a shared page (divergence mid-page): split it first
-            if self._cow_guard(st, req):
-                tok, valid = self._prefill_chunk(req, st)
-                did_chunk = True
-                st.prefill_chunks += 1
-                st.prefill_tokens += valid
-                st.prefill_write_bytes += (valid
-                                           * (st.bpt1 if req.tier else st.bpt0)
-                                           * self._n_cache_layers)
-                st.interconnect += valid * st.concat_bpt + st.gather_bps
-                # every page the chunk just FILLED is immutable from here on
-                # (later chunks write strictly past req.length), so register
-                # eagerly — concurrent admissions can mount a prefix that is
-                # still mid-prefill, and the entries outlive this request's
-                # retirement for as long as any borrower keeps them resident
-                sched.register_prefix(req)
-                if tok is not None:
-                    # the final chunk runs during THIS tick: its first token
-                    # is available at the tick's end (step + 1), and the row
-                    # joins the decode batch from the NEXT tick
-                    self._emit_token(st, req, tok, st.step + 1)
-                    if req.state == "running":
-                        fresh_slot = req.slot
+        did_chunk, fresh_slot = (self._pump_chunk(st) if self.chunked
+                                 else (False, -1))
 
         # 4) growth: map a page for every running row's next write.
         #    Out of pages: a dense grower first escalates itself to the
         #    CPQ arena (frees its dense pages), else the policy's victim
         #    (default: youngest same-arena) is preempted (recompute)
-        for req in sorted(sched.running(), key=lambda r: r.admitted_step):
-            if req.state != "running":
-                continue
-            while not sched.ensure_writable(req):
-                if req.length // self.serving.page_size >= \
-                        self.serving.max_blocks_per_slot:
-                    self._finish(st, req, "length_cap")
-                    break
-                if self.tiered and req.tier == 0 and sched.cpq_alloc.can_alloc(
-                        pgc.pages_needed(req.length + 1,
-                                         self.serving.page_size)):
-                    slot, length = req.slot, req.length
-                    dense_row, cpq_row = sched.apply_escalation(req)
-                    st.caches = self._escalate(st.caches,
-                                               jnp.asarray(dense_row),
-                                               jnp.asarray(cpq_row),
-                                               jnp.asarray(slot, jnp.int32),
-                                               jnp.asarray(length, jnp.int32))
+        with _span("serve.grow"):
+            for req in sorted(sched.running(), key=lambda r: r.admitted_step):
+                if req.state != "running":
                     continue
-                victim = sched.preemption_victim(exclude=req)
-                if victim is None:
-                    self._finish(st, req, "oom")
-                    break
-                vslot = victim.slot
-                sched.preempt(victim)
-                self._clear_row_sampling(st, vslot)
-            if req.state == "running":
-                # a decode write into a still-shared page splits it first
-                # (reachable only via adversarial schedules — tail chunks
-                # normally privatize the write frontier — but the refcount
-                # invariant must hold for ANY interleaving)
-                self._cow_guard(st, req)
+                while not sched.ensure_writable(req):
+                    if req.length // self.serving.page_size >= \
+                            self.serving.max_blocks_per_slot:
+                        self._finish(st, req, "length_cap")
+                        break
+                    if self.tiered and req.tier == 0 and sched.cpq_alloc.can_alloc(
+                            pgc.pages_needed(req.length + 1,
+                                             self.serving.page_size)):
+                        slot, length = req.slot, req.length
+                        dense_row, cpq_row = sched.apply_escalation(req)
+                        st.caches = self._escalate(st.caches,
+                                                   jnp.asarray(dense_row),
+                                                   jnp.asarray(cpq_row),
+                                                   jnp.asarray(slot, jnp.int32),
+                                                   jnp.asarray(length, jnp.int32))
+                        continue
+                    victim = sched.preemption_victim(exclude=req)
+                    if victim is None:
+                        self._finish(st, req, "oom")
+                        break
+                    vslot = victim.slot
+                    sched.preempt(victim)
+                    self._clear_row_sampling(st, vslot)
+                if req.state == "running":
+                    # a decode write into a still-shared page splits it first
+                    # (reachable only via adversarial schedules — tail chunks
+                    # normally privatize the write frontier — but the
+                    # refcount invariant must hold for ANY interleaving)
+                    self._cow_guard(st, req)
 
-        active = sched.active_mask()
-        if fresh_slot >= 0:
-            active[fresh_slot] = False
+            active = sched.active_mask()
+            if fresh_slot >= 0:
+                active[fresh_slot] = False
 
         # 4b) speculative decoding: eligible rows take a per-row verify
         #     chunk instead of joining the batched decode (each verify is
@@ -1201,24 +1228,25 @@ class ContinuousServeEngine:
         #     stays in ``active`` and decodes normally below.
         did_spec = False
         if self.spec_on:
-            for req in sorted(sched.running(), key=lambda r: r.admitted_step):
-                slot = req.slot
-                if slot < 0 or not active[slot]:
-                    continue
-                if not self._spec_eligible(req):
-                    continue
-                if self._speculate_row(st, req):
-                    active[slot] = False
-                    did_spec = True
+            with _span("serve.speculate"):
+                for req in sorted(sched.running(), key=lambda r: r.admitted_step):
+                    slot = req.slot
+                    if slot < 0 or not active[slot]:
+                        continue
+                    if not self._spec_eligible(req):
+                        continue
+                    if self._speculate_row(st, req):
+                        active[slot] = False
+                        did_spec = True
 
         if not active.any():
             if did_spec:
                 # the verify invocations already charged their ticks (and
                 # this tick's prompt chunk, if any, rode along with them)
-                return st.step_outputs
+                return
             if did_chunk:
                 st.step += 1     # prefill-only tick still costs a tick
-                return st.step_outputs
+                return
             if not sched.occupied():
                 # a slot may have been vacated AFTER this tick's admission
                 # phase (growth-cap retirement, de-escalation requeue): if
@@ -1226,7 +1254,7 @@ class ContinuousServeEngine:
                 # next tick's admission phase admits them normally
                 if sched.queue and sched.policy.select_admission(
                         sched, st.step) is not None:
-                    return st.step_outputs
+                    return
                 cands = sched.policy.admission_order(sched, st.step)
                 if cands and cands[0].arrival <= st.step:
                     # empty machine (every page free) and the policy's pick
@@ -1236,7 +1264,7 @@ class ContinuousServeEngine:
                     req.state, req.done_step = "done", st.step
                     req.finish_reason = "unschedulable"
                     st.results[req.rid] = self._result_of(req)
-                    return st.step_outputs
+                    return
                 # idle: jump the clock to the arrival that unblocks
                 # admission — the policy's blocked pick if it has one
                 # (a no-bypass FIFO head gates everyone behind it), else
@@ -1245,47 +1273,84 @@ class ContinuousServeEngine:
                     nxt = (cands[0].arrival if cands
                            else min(r.arrival for r in sched.queue))
                     st.step = max(st.step + 1, int(np.ceil(nxt)))
-            return st.step_outputs
+            return
 
         # 5) one jitted decode step over per-row positions (rows still
         #    prefilling — and a row whose final chunk landed this very
         #    tick — are inactive: their writes hit the null page), then
         #    ONE jitted per-row sampling call for the whole mixed batch
-        rows = self._row_state(sched, active)
-        logits, st.caches = self._decode(self.params,
-                                         jnp.asarray(st.last_tok[:, None]),
-                                         rows, st.caches)
-        toks, finite = self._sample_active(st, logits)
-        st.nonfinite_rows += int((active & ~finite).sum())
-        st.decode_steps += 1
-        st.live_steps += int(active.sum())
-        tier_arr = sched.tiers
-        st.traffic += float(sum(
-            (sched.lengths[s] + 1.0) * (st.bpt1 if tier_arr[s] else st.bpt0)
-            for s in range(B) if active[s])) * self._n_cache_layers
-        st.interconnect += int(active.sum()) * st.concat_bpt + st.gather_bps
-        util = sched.dense_alloc.utilization
-        st.util_peak = max(st.util_peak, util)
-        st.util_sum += util
-        st.util_n += 1
-        st.trace_active.append(int(active.sum()))
-        st.trace_util.append(util)
-        st.step += 1
+        n_active = int(active.sum())
+        with _span("serve.decode", rows=n_active):
+            rows = self._row_state(sched, active)
+            logits, st.caches = self._decode(self.params,
+                                             jnp.asarray(st.last_tok[:, None]),
+                                             rows, st.caches)
+        with _span("serve.sample"):
+            toks, finite = self._sample_active(st, logits)
 
-        for slot in range(B):
-            if not active[slot]:
-                continue
-            req = sched.slots[slot]
-            self._emit_token(st, req, int(toks[slot]), st.step, grow=True)
-            # decode just completed a page? register it — multi-turn
-            # follow-ups then mount this request's whole history
+        with _span("serve.emit"):
+            st.nonfinite_rows += int((active & ~finite).sum())
+            st.decode_steps += 1
+            st.live_steps += n_active
+            tier_arr = sched.tiers
+            st.traffic += float(sum(
+                (sched.lengths[s] + 1.0) * (st.bpt1 if tier_arr[s] else st.bpt0)
+                for s in range(B) if active[s])) * self._n_cache_layers
+            st.interconnect += n_active * st.concat_bpt + st.gather_bps
+            util = sched.dense_alloc.utilization
+            st.util_peak = max(st.util_peak, util)
+            st.util_sum += util
+            st.util_n += 1
+            st.step += 1
+
+            for slot in range(B):
+                if not active[slot]:
+                    continue
+                req = sched.slots[slot]
+                self._emit_token(st, req, int(toks[slot]), st.step, grow=True)
+                # decode just completed a page? register it — multi-turn
+                # follow-ups then mount this request's whole history
+                sched.register_prefix(req)
+
+    def _pump_chunk(self, st: _ServeState) -> tuple[bool, int]:
+        """A tick's prompt chunk for the oldest prefilling row, under the
+        ``serve.prefill_chunk`` span. Returns whether a chunk ran, and the
+        slot whose prefill it finished (-1 for none): that row joins the
+        decode batch from the NEXT tick."""
+        sched = st.sched
+        pre = sched.prefilling()
+        if not pre:
+            return False, -1
+        req = pre[0]
+        with _span("serve.prefill_chunk", rid=req.rid) as span:
+            # the first tail write of a shared-prefix admission may land
+            # inside a shared page (divergence mid-page): split it first
+            if not self._cow_guard(st, req):
+                return False, -1
+            tok, valid = self._prefill_chunk(req, st)
+            span.set_metadata(tokens=valid)
+            st.prefill_chunks += 1
+            st.prefill_tokens += valid
+            st.prefill_write_bytes += (valid * (st.bpt1 if req.tier else st.bpt0)
+                                       * self._n_cache_layers)
+            st.interconnect += valid * st.concat_bpt + st.gather_bps
+            # every page the chunk just FILLED is immutable from here on
+            # (later chunks write strictly past req.length), so register
+            # eagerly — concurrent admissions can mount a prefix that is
+            # still mid-prefill, and the entries outlive this request's
+            # retirement for as long as any borrower keeps them resident
             sched.register_prefix(req)
-        return st.step_outputs
+            if tok is None:
+                return True, -1
+            # the final chunk runs during THIS tick: its first token is
+            # available at the tick's end (step + 1)
+            self._emit_token(st, req, tok, st.step + 1)
+            return True, req.slot if req.state == "running" else -1
 
     def stats(self) -> dict:
         """Session counters in the same shape ``serve`` has always returned
         (throughput, latency inputs, traffic accounting, allocator surface),
-        plus the policy name and the per-tick utilization traces."""
+        plus the policy name."""
         st = self._ensure_state()
         sched = st.sched
         B = self.serving.num_slots
@@ -1315,10 +1380,6 @@ class ContinuousServeEngine:
             "slot_utilization": st.live_steps / max(st.decode_steps * B, 1),
             "arena_utilization_mean": st.util_sum / max(st.util_n, 1),
             "arena_utilization_peak": st.util_peak,
-            # per-decode-tick idle-vs-active series (live rows / arena fill):
-            # bench_serving folds these into bench_e2e_energy's device model
-            "trace_active_rows": np.asarray(st.trace_active, np.int32),
-            "trace_arena_util": np.asarray(st.trace_util, np.float64),
             "decode_traffic_bytes": st.traffic,
             "prefill_write_bytes": st.prefill_write_bytes,
             "bytes_per_token_layer": st.bpt0,

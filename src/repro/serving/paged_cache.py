@@ -58,6 +58,11 @@ class RowState(NamedTuple):
 # -------------------------------------------------------------- page plumbing
 
 
+# Every write of new K/V (or X, CPQ codes and levels) into the arena runs
+# under the ``kv_write`` profiler scope (metadata only; docs/serving.md).
+_kv_write = jax.named_scope("kv_write")
+
+
 def gather_pages(pages: jax.Array, block_table: jax.Array) -> jax.Array:
     """Materialize logical views: (P, page, ...) x (B, max_blocks)
     -> (B, max_blocks * page, ...). Unmapped blocks read the null page and
@@ -66,6 +71,7 @@ def gather_pages(pages: jax.Array, block_table: jax.Array) -> jax.Array:
     return g.reshape(g.shape[0], g.shape[1] * g.shape[2], *g.shape[3:])
 
 
+@_kv_write
 def write_token_pages(pages: jax.Array, block_table: jax.Array, lengths: jax.Array,
                       active: jax.Array, val: jax.Array) -> jax.Array:
     """Scatter one token per row at slot ``lengths[b]``. val: (B, ...) —
@@ -78,6 +84,7 @@ def write_token_pages(pages: jax.Array, block_table: jax.Array, lengths: jax.Arr
     return pages.at[page_idx, off].set(val.astype(pages.dtype))
 
 
+@_kv_write
 def write_prompt_pages(pages: jax.Array, block_row: jax.Array, val: jax.Array) -> jax.Array:
     """Bulk-write a prompt into one slot's pages. block_row: (max_blocks,);
     val: (S, ...). Positions whose block is unmapped or beyond max_blocks
@@ -93,6 +100,7 @@ def write_prompt_pages(pages: jax.Array, block_row: jax.Array, val: jax.Array) -
     return pages.at[pidx, pos % page_size].set(val.astype(pages.dtype))
 
 
+@_kv_write
 def write_chunk_pages(pages: jax.Array, block_row: jax.Array, offset: jax.Array,
                       valid: jax.Array, vals: jax.Array) -> jax.Array:
     """Bulk-write one prompt CHUNK into one slot's pages at positions
@@ -463,6 +471,7 @@ def append_x(cache: PagedXCache, rows: RowState,
                 if k_rope_t is not None else cache.k_rope))
 
 
+@_kv_write
 def append_cpq_tensor(t: PagedCPQTensor, rows: RowState, x_t: jax.Array,
                       cfg: CPQCfg) -> PagedCPQTensor:
     """HQE-encode one token per row (shared math with the contiguous path)
@@ -566,6 +575,7 @@ def _slot_cpq(t: PagedCPQTensor, block_row: jax.Array, slot: jax.Array
         num_levels=sl(t.num_levels), prune_thr=sl(t.prune_thr))
 
 
+@_kv_write
 def chunk_cpq_tensor(t: PagedCPQTensor, slot: jax.Array, block_row: jax.Array,
                      offset: jax.Array, valid: jax.Array, x_c: jax.Array,
                      cfg: CPQCfg, first: bool) -> PagedCPQTensor:

@@ -283,13 +283,11 @@ def run_trace(cfg, params, work: list[WorkItem], serving: ServingCfg,
         "shared_prefix_tokens": stats["shared_prefix_tokens"],
         "shared_prefix_pages": stats["shared_prefix_pages"],
         "cow_copies": stats["cow_copies"],
-        # per-tick idle-vs-active traces (what bench_e2e_energy's device
-        # model charges idle energy from) + the per-request records the
-        # policy metrics are scored on
+        # idle-vs-active utilization (what bench_e2e_energy's device model
+        # charges idle energy from) + the per-request records the policy
+        # metrics are scored on
         "policy": stats["policy"],
         "slot_utilization": stats["slot_utilization"],
-        "trace_active_rows": stats["trace_active_rows"],
-        "trace_arena_util": stats["trace_arena_util"],
         "results": res,
     }
     if slos is not None:

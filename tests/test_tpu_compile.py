@@ -97,6 +97,30 @@ def test_paged_kernel_compiles_for_v5e(one_chip, name):
     assert compiled.memory_analysis() is not None
 
 
+KERNEL_NAMES = {"flash_decode": "paged_flash_decode",
+                "flash_prefill": "paged_flash_prefill",
+                "cpq_decode": "paged_cpq_decode",
+                "cpq_prefill": "paged_cpq_prefill",
+                "decomposed_decode": "paged_t1_decode",
+                "decomposed_prefill": "paged_t1_prefill"}
+
+
+@pytest.mark.parametrize("name", list(KERNEL_NAMES))
+def test_paged_kernel_carries_its_name(one_chip, name):
+    """Each compiled kernel is named by its ``pallas_call``'s ``name``, so
+    a profiler trace tells the kernels apart (the op is named after it and
+    its op_name path ends ``<name>/pallas_call``)."""
+    fwd, args = _cases(one_chip)[name]
+    hlo = jax.jit(lambda *a: fwd(*a, scale=DH ** -0.5, interpret=False)).lower(
+        *args).compile().as_text()
+    (kernel,) = [ln for ln in hlo.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln]
+    want = KERNEL_NAMES[name]
+    assert kernel.split(" = ")[0].split()[-1].startswith(f"%{want}")
+    assert f'/{want}/pallas_call"' in kernel
+    assert len(set(KERNEL_NAMES.values())) == len(KERNEL_NAMES)
+
+
 def test_engine_kernel_entry_compiles_mosaic_on_tpu(one_chip):
     """The engine-facing wrapper, left to pick its own mode, lowers the
     compiled Mosaic kernel for a TPU (the CPU side is covered by
