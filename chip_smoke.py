@@ -112,15 +112,29 @@ def check_kernels(jax, seed: int) -> None:
         return jnp.asarray(x * 0.1 if zero else np.abs(x) * 4e-3 + 1e-3,
                            jnp.float32)
 
-    kp, vp = normal(P, PAGE, KV, Dh), normal(P, PAGE, KV, Dh)
+    # the dense arena as served: three stacked lane-dense layers, read at
+    # the middle one; the oracles take that layer's per-head pools
+    kp, vp = normal(3, P, PAGE, KV * Dh), normal(3, P, PAGE, KV * Dh)
+    layer = jnp.asarray(1, jnp.int32)
     xp, krp = normal(P, PAGE, Dm), normal(P, PAGE, KV, R)
+
+    def at_layer(ref):
+        def heads(pool, layer):
+            return pool[layer].reshape(P, PAGE, KV, -1)
+        # (q, k, v, ..., layer, scale) -> the oracle's (q, k, v, ..., scale)
+        return lambda q, k, v, *rest: ref(
+            q, heads(k, rest[-2]), heads(v, rest[-2]), *rest[:-2], rest[-1])
+
     cases = {
-        "flash_decode": (fk.paged_flash_decode_fwd, fr.paged_flash_decode_ref,
-                         (normal(SLOTS, 1, H, Dh), kp, vp, table, lengths),
+        "flash_decode": (fk.paged_flash_decode_fwd,
+                         at_layer(fr.paged_flash_decode_ref),
+                         (normal(SLOTS, 1, H, Dh), kp, vp, table, lengths,
+                          layer),
                          Dh ** -0.5, lambda o: o),
         "flash_prefill": (fk.paged_flash_prefill_fwd,
-                          fr.paged_flash_prefill_ref,
-                          (normal(1, CHUNK, H, Dh), kp, vp, row, off, val),
+                          at_layer(fr.paged_flash_prefill_ref),
+                          (normal(1, CHUNK, H, Dh), kp, vp, row, off, val,
+                           layer),
                           Dh ** -0.5, lambda o: o[0, :valid]),
         "cpq_decode": (ck.paged_cpq_decode_fwd, cr.paged_cpq_decode_ref,
                        (normal(SLOTS, KV, H // KV, Dh), codes(), codes(),

@@ -138,9 +138,14 @@ def paged_container_specs(container, rules: dict | None = None):
         return pgc.TieredPagedCache(dense=paged_container_specs(c.dense, rules),
                                     cpq=paged_container_specs(c.cpq, rules))
     if isinstance(c, pgc.PagedDenseKVCache):
-        return pgc.PagedDenseKVCache(
-            k=r("page_pool", "page", "kv_heads", "head_dim"),
-            v=r("page_pool", "page", "kv_heads", "head_dim"))
+        # lane-dense pools: the merged KV*Dh axis splits into contiguous head
+        # groups; inside the layer scan the pools are stacked and carry the
+        # layer index
+        pool = r("page_pool", "page", "kv_heads")
+        if c.layer is None:
+            return pgc.PagedDenseKVCache(k=pool, v=pool)
+        return pgc.PagedDenseKVCache(k=P(None, *pool), v=P(None, *pool),
+                                     layer=P())
     if isinstance(c, pgc.PagedXCache):
         return pgc.PagedXCache(
             x=r("page_pool", "page", "latent"),

@@ -2,7 +2,7 @@
 
   flash_attn/        baseline dense flash attention (train/prefill) +
                      ``paged_flash_decode_*``: paged single-token decode over
-                     a (P, page, KV, Dh) arena
+                     one layer of an (L, P, page, KV*Dh) stacked arena
   decomposed_attn/   T1: fused two-stage (Q W_K^T) X^T decode attention —
                      the sub-matrix pipeline realized as one VMEM-resident
                      streaming kernel over the X cache +

@@ -84,14 +84,16 @@ def online_update(s, v, m_sc, l_sc, acc_sc, h):
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_sc, l_sc, acc_sc, *, scale: float, page_size: int,
-                         nb: int, kv_heads: int):
-    """One (b, ib) step: the K/V tile IS physical page bt[b, ib] with every
-    kv head of the page (block (1, page, KV, Dh): the last two block dims
-    span whole array dims, which Mosaic's (8, 128) tiling accepts at any
-    head count) — the BlockSpec index map resolved the block table before
-    the body ran, so the page was DMA'd straight from the arena into VMEM.
+def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
+                         o_ref, m_sc, l_sc, acc_sc, *, scale: float,
+                         page_size: int, nb: int, kv_heads: int, head_dim: int,
+                         v_dim: int):
+    """One (b, ib) step: the K/V tile IS physical page bt[b, ib] of layer
+    ``layer`` in the stacked lane-dense arena (block (1, page, KV*Dh): the
+    last two block dims span whole array dims, which Mosaic's (8, 128)
+    tiling accepts at any head count) — the BlockSpec index map resolved the
+    layer and the block table before the body ran, so the page was DMA'd
+    straight from the arena into VMEM. Head h is lanes [h*Dh, (h+1)*Dh).
 
     One sweep serves both attention matmuls of every head per page (scores
     AND weighted-V accumulate while the page sits in VMEM); softmax state is
@@ -111,8 +113,10 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     def _compute():
         for h in range(kv_heads):                      # static: per kv head
             q = q_ref[0, h].astype(jnp.float32)        # (G, Dh)
-            k = k_ref[0, :, h, :].astype(jnp.float32)  # (page, Dh)
-            v = v_ref[0, :, h, :].astype(jnp.float32)  # (page, Dv)
+            k = k_ref[0, :, h * head_dim:(h + 1) * head_dim].astype(
+                jnp.float32)                           # (page, Dh)
+            v = v_ref[0, :, h * v_dim:(h + 1) * v_dim].astype(
+                jnp.float32)                           # (page, Dv)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale      # (G, page)
@@ -128,14 +132,17 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_prefill_kernel(bt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                          m_sc, l_sc, acc_sc, *, scale: float, page_size: int,
-                          nb: int, group: int, kv_heads: int):
+def _paged_prefill_kernel(bt_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref,
+                          o_ref, m_sc, l_sc, acc_sc, *, scale: float,
+                          page_size: int, nb: int, group: int, kv_heads: int,
+                          head_dim: int, v_dim: int):
     """One ib step of the Q-chunk>1 paged prefill sweep: queries are the
     admission chunk's C tokens (flattened (C*G) rows per kv head), the K/V
-    tile IS physical page bt[ib] of the slot being admitted, all kv heads at
-    once. lens holds (offset, total): ``offset`` tokens preceded this chunk,
-    ``total`` = offset + valid masks the chunk's jit padding. Causal masking
+    tile IS physical page bt[ib] of layer ``layer`` in the stacked
+    lane-dense arena, all kv heads at once (head h: lanes
+    [h*Dh, (h+1)*Dh)). lens holds (offset, total): ``offset`` tokens
+    preceded this chunk, ``total`` = offset + valid masks the chunk's jit
+    padding. Causal masking
     is per query ROW: row r is chunk token r // G at absolute position
     offset + r // G."""
     ib = pl.program_id(0)
@@ -151,8 +158,10 @@ def _paged_prefill_kernel(bt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     def _compute():
         for h in range(kv_heads):                      # static: per kv head
             q = q_ref[h].astype(jnp.float32)           # (C*G, Dh)
-            k = k_ref[0, :, h, :].astype(jnp.float32)  # (page, Dh)
-            v = v_ref[0, :, h, :].astype(jnp.float32)  # (page, Dv)
+            k = k_ref[0, :, h * head_dim:(h + 1) * head_dim].astype(
+                jnp.float32)                           # (page, Dh)
+            v = v_ref[0, :, h * v_dim:(h + 1) * v_dim].astype(
+                jnp.float32)                           # (page, Dv)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale      # (C*G, page)
@@ -169,9 +178,15 @@ def _paged_prefill_kernel(bt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
             acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(o_ref.dtype)
 
 
+def _layer_index(layer) -> jax.Array:
+    """The stacked arena's layer to read, as a (1,) int32 scalar-prefetch
+    operand."""
+    return jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+
+
 def paged_flash_prefill_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                             block_row: jax.Array, offset: jax.Array,
-                            valid: jax.Array, *, scale: float,
+                            valid: jax.Array, layer: jax.Array, *, scale: float,
                             interpret: bool = True) -> jax.Array:
     """Chunked paged prefill attention for ONE request slot: the chunk's C
     queries attend over the slot's pages [0, offset + valid) — the chunk's own
@@ -179,15 +194,16 @@ def paged_flash_prefill_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array
     exists. Same scalar-prefetch construction as the decode kernel, with a
     per-query-row causal mask (query i sits at absolute position offset + i).
 
-    q: (1, C, H, Dh); k_pages/v_pages: (P, page, KV, Dh|Dv) pools;
-    block_row: (max_blocks,) int32 (0 = null page); offset/valid: () int32 —
-    tokens already in the slot before this chunk / real tokens in this chunk
-    (the tail up to C is jit padding whose output is garbage).
-    Returns (1, C, H, Dv)."""
+    q: (1, C, H, Dh); k_pages/v_pages: (L, P, page, KV*Dh|KV*Dv) stacked
+    lane-dense pools (head h at lanes [h*Dh, (h+1)*Dh)), read at ``layer``;
+    block_row: (max_blocks,) int32 (0 = null page); offset/valid/layer: ()
+    int32 — tokens already in the slot before this chunk / real tokens in
+    this chunk (the tail up to C is jit padding whose output is garbage) /
+    the layer of the stack. Returns (1, C, H, Dv)."""
     _, C, H, Dh = q.shape
-    page = k_pages.shape[1]
-    KV = k_pages.shape[2]
-    Dv = v_pages.shape[-1]
+    page = k_pages.shape[2]
+    KV = k_pages.shape[3] // Dh
+    Dv = v_pages.shape[3] // KV
     g = H // KV
     nb = block_row.shape[0]
     # (KV, C*G, Dh), token-major rows within each kv head: row r = token r // g
@@ -195,20 +211,22 @@ def paged_flash_prefill_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array
     lens = jnp.stack([offset, offset + valid]).astype(jnp.int32)
 
     kern = functools.partial(_paged_prefill_kernel, scale=scale,
-                             page_size=page, nb=nb, group=g, kv_heads=KV)
+                             page_size=page, nb=nb, group=g, kv_heads=KV,
+                             head_dim=Dh, v_dim=Dv)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # block_row, (offset, total)
+            num_scalar_prefetch=3,  # block_row, (offset, total), layer
             grid=(nb,),             # sweeps the slot's block-table entries
             in_specs=[
-                pl.BlockSpec((KV, C * g, Dh), lambda ib, bt, ln: (0, 0, 0)),
-                pl.BlockSpec((1, page, KV, Dh),
-                             lambda ib, bt, ln: (bt[ib], 0, 0, 0)),
-                pl.BlockSpec((1, page, KV, Dv),
-                             lambda ib, bt, ln: (bt[ib], 0, 0, 0)),
+                pl.BlockSpec((KV, C * g, Dh), lambda ib, bt, ln, ly: (0, 0, 0)),
+                pl.BlockSpec((pl.squeezed, 1, page, KV * Dh),
+                             lambda ib, bt, ln, ly: (ly[0], bt[ib], 0, 0)),
+                pl.BlockSpec((pl.squeezed, 1, page, KV * Dv),
+                             lambda ib, bt, ln, ly: (ly[0], bt[ib], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((KV, C * g, Dv), lambda ib, bt, ln: (0, 0, 0)),
+            out_specs=pl.BlockSpec((KV, C * g, Dv),
+                                   lambda ib, bt, ln, ly: (0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((KV, C * g, 1), jnp.float32),
                 pltpu.VMEM((KV, C * g, 1), jnp.float32),
@@ -218,50 +236,56 @@ def paged_flash_prefill_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array
         out_shape=jax.ShapeDtypeStruct((KV, C * g, Dv), q.dtype),
         name="paged_flash_prefill",
         interpret=interpret,
-    )(block_row.astype(jnp.int32), lens, qg, k_pages, v_pages)
+    )(block_row.astype(jnp.int32), lens, _layer_index(layer), qg, k_pages,
+      v_pages)
     return out.reshape(KV, C, g, Dv).transpose(1, 0, 2, 3).reshape(1, C, H, Dv)
 
 
 def paged_flash_decode_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                           block_table: jax.Array, lengths: jax.Array, *,
-                           scale: float, interpret: bool = True) -> jax.Array:
+                           block_table: jax.Array, lengths: jax.Array,
+                           layer: jax.Array, *, scale: float,
+                           interpret: bool = True) -> jax.Array:
     """Paged single-token flash decode: grid iterates block-table entries and
-    DMAs each mapped page from the arena into VMEM via the BlockSpec index map
-    (scalar-prefetched block table) — the contiguous logical K/V view is never
+    DMAs each mapped page of layer ``layer`` from the stacked arena into VMEM
+    via the BlockSpec index map (scalar-prefetched block table and layer) —
+    neither the layer's pool nor the contiguous logical K/V view is ever
     materialized.
 
-    q: (B, 1, H, Dh); k_pages/v_pages: (P, page, KV, Dh|Dv) physical pools;
-    block_table: (B, max_blocks) int32, 0 = unmapped (null page);
-    lengths: (B,) int32 valid tokens per row. Returns (B, 1, H, Dv).
+    q: (B, 1, H, Dh); k_pages/v_pages: (L, P, page, KV*Dh|KV*Dv) stacked
+    lane-dense pools (head h at lanes [h*Dh, (h+1)*Dh)); block_table:
+    (B, max_blocks) int32, 0 = unmapped (null page); lengths: (B,) int32
+    valid tokens per row; layer: () int32. Returns (B, 1, H, Dv).
 
     Masking convention (shared with serving/paged_cache.py): positions >=
     lengths[b] — including every slot of an unmapped/null page and the tail of
     a partial last page — contribute nothing; a row with lengths[b] == 0
     returns zeros."""
     B, _, H, Dh = q.shape
-    page = k_pages.shape[1]
-    KV = k_pages.shape[2]
-    Dv = v_pages.shape[-1]
+    page = k_pages.shape[2]
+    KV = k_pages.shape[3] // Dh
+    Dv = v_pages.shape[3] // KV
     g = H // KV
     nb = block_table.shape[1]
     qg = q[:, 0].reshape(B, KV, g, Dh)
 
     kern = functools.partial(_paged_decode_kernel, scale=scale,
-                             page_size=page, nb=nb, kv_heads=KV)
+                             page_size=page, nb=nb, kv_heads=KV, head_dim=Dh,
+                             v_dim=Dv)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # block_table, lengths
+            num_scalar_prefetch=3,  # block_table, lengths, layer
             grid=(B, nb),           # innermost axis sweeps block-table entries
             in_specs=[
-                pl.BlockSpec((1, KV, g, Dh), lambda b, ib, bt, ln: (b, 0, 0, 0)),
-                pl.BlockSpec((1, page, KV, Dh),
-                             lambda b, ib, bt, ln: (bt[b, ib], 0, 0, 0)),
-                pl.BlockSpec((1, page, KV, Dv),
-                             lambda b, ib, bt, ln: (bt[b, ib], 0, 0, 0)),
+                pl.BlockSpec((1, KV, g, Dh),
+                             lambda b, ib, bt, ln, ly: (b, 0, 0, 0)),
+                pl.BlockSpec((pl.squeezed, 1, page, KV * Dh),
+                             lambda b, ib, bt, ln, ly: (ly[0], bt[b, ib], 0, 0)),
+                pl.BlockSpec((pl.squeezed, 1, page, KV * Dv),
+                             lambda b, ib, bt, ln, ly: (ly[0], bt[b, ib], 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, KV, g, Dv),
-                                   lambda b, ib, bt, ln: (b, 0, 0, 0)),
+                                   lambda b, ib, bt, ln, ly: (b, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((KV, g, 1), jnp.float32),
                 pltpu.VMEM((KV, g, 1), jnp.float32),
@@ -272,7 +296,7 @@ def paged_flash_decode_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         name="paged_flash_decode",
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      qg, k_pages, v_pages)
+      _layer_index(layer), qg, k_pages, v_pages)
     return out.reshape(B, 1, H, Dv)
 
 

@@ -222,12 +222,59 @@ def init_paged_caches(cfg: ModelConfig, rt: AttentionRuntime, serving,
               for k in cfg.prefix_pattern]
 
     def stacked(kind):
-        one = tfm.layer_paged_cache_init(cfg, rt, kind, serving, tiered)
+        init = functools.partial(tfm.layer_paged_cache_init, cfg, rt, kind,
+                                 serving, tiered)
+        shapes = jax.eval_shape(init)
+        if isinstance(shapes, pgc.PagedDenseKVCache):
+            # the carried arena: each pool one zeros at its stacked shape
+            return jax.tree.map(
+                lambda a: jnp.zeros((cfg.num_blocks,) + a.shape, a.dtype), shapes)
         return jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (cfg.num_blocks,) + a.shape).copy(), one)
+            lambda a: jnp.broadcast_to(a, (cfg.num_blocks,) + a.shape).copy(), init())
 
     blocks = [stacked(k) for k in cfg.block_pattern]
     return {"prefix": prefix, "blocks": blocks}
+
+
+def carries_arenas(caches) -> bool:
+    """Whether every scanned cache is a dense arena that rides in the layer
+    scan's carry (``_scan_layers``). Only then may a step donate its caches:
+    a donated cache that went through the scan's xs/ys would cost whole-arena
+    copies instead of saving them."""
+    return all(isinstance(c, pgc.PagedDenseKVCache) for c in caches["blocks"])
+
+
+def _scan_layers(cfg: ModelConfig, layer_fn, x, block_params, block_caches):
+    """Run the scanned blocks: ``layer_fn(kind, p, x, cache) -> (x, cache)``.
+
+    A dense paged arena (``PagedDenseKVCache``) rides in the carry as its
+    whole stacked (L, P, page, KV*Dh) pools next to a layer counter; the
+    layer reads and writes it in place at that index, so no per-layer slice
+    of it is copied out or back. Every other cache is sliced per layer
+    through xs/ys, as is every weight. Returns (x, new block caches)."""
+    carried = [isinstance(c, pgc.PagedDenseKVCache) for c in block_caches]
+    held0 = tuple(c for c, k in zip(block_caches, carried) if k)
+    sliced0 = tuple(c for c, k in zip(block_caches, carried) if not k)
+
+    def body(carry, inp):
+        x, layer, held = carry
+        block_params, sliced = _barrier(inp)
+        held, sliced = list(held), list(sliced)
+        new_held, outs = [], []
+        for kind, p, is_held in zip(cfg.block_pattern, block_params, carried):
+            if is_held:
+                x, c = layer_fn(kind, p, x, held.pop(0)._replace(layer=layer))
+                new_held.append(c._replace(layer=None))
+            else:
+                x, c = layer_fn(kind, p, x, sliced.pop(0))
+                outs.append(c)
+        return (x, layer + 1, tuple(new_held)), tuple(outs)
+
+    (x, _, held), outs = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.int32), held0),
+        (tuple(block_params), sliced0))
+    held, outs = list(held), list(outs)
+    return x, [held.pop(0) if k else outs.pop(0) for k in carried]
 
 
 def decode_step_rows(cfg: ModelConfig, rt: AttentionRuntime, params,
@@ -239,10 +286,14 @@ def decode_step_rows(cfg: ModelConfig, rt: AttentionRuntime, params,
     otherwise caches are gathered through the block table in jnp.
     tokens: (B, 1) int32. Returns (logits (B, V), caches).
 
+    Dense arenas ride in the layer scan's carry and are updated in place
+    (``_scan_layers``); a caller that donates ``caches`` must not read them
+    again.
+
     Profiler scopes (metadata only): ``embed``; ``layers`` around the layer
-    stack, whose own ops are the scan's moves of the stacked arena and
-    weights; per layer ``norm``, ``attn.qkv``, ``kv_write``,
-    ``attn.kernel``, ``attn.out``, ``mlp``; then ``lm_head``."""
+    stack, whose own ops are the scan's per-layer slices of the weights (and
+    of any cache that is not carried); per layer ``norm``, ``attn.qkv``,
+    ``kv_write``, ``attn.kernel``, ``attn.out``, ``mlp``; then ``lm_head``."""
     with jax.named_scope("embed"):
         x = embed_inputs(cfg, params["embed"], {"tokens": tokens},
                          rows.lengths[:, None])
@@ -255,17 +306,10 @@ def decode_step_rows(cfg: ModelConfig, rt: AttentionRuntime, params,
 
         new_blocks = caches["blocks"]
         if cfg.num_blocks:
-            def body(x, inp):
-                block_params, block_caches = _barrier(inp)
-                outs = []
-                for kind, p, c in zip(cfg.block_pattern, block_params, block_caches):
-                    x, c2 = tfm.layer_decode_rows(cfg, rt, kind, p, x, rows, c)
-                    outs.append(c2)
-                return x, tuple(outs)
-
-            x, new_blocks = jax.lax.scan(
-                body, x, (tuple(params["blocks"]), tuple(caches["blocks"])))
-            new_blocks = list(new_blocks)
+            x, new_blocks = _scan_layers(
+                cfg, lambda kind, p, x, c: tfm.layer_decode_rows(
+                    cfg, rt, kind, p, x, rows, c),
+                x, params["blocks"], caches["blocks"])
 
     with jax.named_scope("lm_head"):
         x = apply_norm(cfg, params["final_norm"], x)
@@ -300,19 +344,11 @@ def _chunk_forward(cfg: ModelConfig, rt: AttentionRuntime, tier: int,
 
         new_blocks = caches["blocks"]
         if cfg.num_blocks:
-            def body(x, inp):
-                block_params, block_caches = _barrier(inp)
-                outs = []
-                for kind, p, c in zip(cfg.block_pattern, block_params, block_caches):
-                    x, c2 = tfm.layer_prefill_chunk(cfg, rt, tier, first, kind, p,
-                                                    x, positions, slot, block_row,
-                                                    offset, valid, c)
-                    outs.append(c2)
-                return x, tuple(outs)
-
-            x, new_blocks = jax.lax.scan(
-                body, x, (tuple(params["blocks"]), tuple(caches["blocks"])))
-            new_blocks = list(new_blocks)
+            x, new_blocks = _scan_layers(
+                cfg, lambda kind, p, x, c: tfm.layer_prefill_chunk(
+                    cfg, rt, tier, first, kind, p, x, positions, slot,
+                    block_row, offset, valid, c),
+                x, params["blocks"], caches["blocks"])
 
     return x, {"prefix": new_prefix, "blocks": new_blocks}
 
@@ -359,7 +395,7 @@ def verify_chunk_rows(cfg: ModelConfig, rt: AttentionRuntime, tier: int,
     return logits, caches
 
 
-def pack_prefill_caches(cfg: ModelConfig, rt: AttentionRuntime, paged, src,
+def pack_prefill_caches(cfg: ModelConfig, rt: AttentionRuntime, caches, src,
                         block_row: jax.Array, slot: jax.Array):
     """Scatter a freshly prefilled B=1 contiguous cache pytree (``src``, from
     ``prefill``) into slot ``slot`` of the paged cache pytree (admission)."""
@@ -374,9 +410,9 @@ def pack_prefill_caches(cfg: ModelConfig, rt: AttentionRuntime, paged, src,
         return jax.tree.map(lambda d, s: d.at[slot].set(s[0]), pc, sc)
 
     prefix = [pack_layer(k, pc, sc)
-              for k, pc, sc in zip(cfg.prefix_pattern, paged["prefix"], src["prefix"])]
+              for k, pc, sc in zip(cfg.prefix_pattern, caches["prefix"], src["prefix"])]
     blocks = [jax.vmap(lambda c, s, kind=kind: pack_layer(kind, c, s))(pc, sc)
-              for kind, pc, sc in zip(cfg.block_pattern, paged["blocks"], src["blocks"])]
+              for kind, pc, sc in zip(cfg.block_pattern, caches["blocks"], src["blocks"])]
     return {"prefix": prefix, "blocks": blocks}
 
 
@@ -433,9 +469,10 @@ def escalate_slot(cfg: ModelConfig, rt: AttentionRuntime, caches,
         mixer, _ = kind
         if mixer != "attn" or not isinstance(c, pgc.TieredPagedCache):
             return c
-        src = pgc.compress_dense_slot(
-            pgc.gather_pages(c.dense.k, dense_row[None]),
-            pgc.gather_pages(c.dense.v, dense_row[None]), length, cpq_cfg)
+        k, v = (pgc.gather_layer_heads(pool[None], 0, dense_row[None],
+                                       cfg.num_kv_heads)
+                for pool in (c.dense.k, c.dense.v))
+        src = pgc.compress_dense_slot(k, v, length, cpq_cfg)
         return c._replace(cpq=pgc.pack_cpq(c.cpq, src, cpq_row, slot))
 
     prefix = [esc_layer(k, c) for k, c in zip(cfg.prefix_pattern, caches["prefix"])]

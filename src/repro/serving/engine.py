@@ -55,13 +55,15 @@ from repro.serving.scheduler import Request, Scheduler, SchedulerConfigError
 _span = jax.profiler.TraceAnnotation
 
 
-def _named_jit(fn, *bound):
+def _named_jit(fn, *bound, donate: bool = False):
     """``jax.jit(partial(fn, *bound))`` that keeps ``fn``'s name: the
     compiled program reaches the profiler as ``jit_<fn name>(...)``, where a
-    bare ``partial`` shows as ``jit__unknown``."""
+    bare ``partial`` shows as ``jit__unknown``. With ``donate`` the call may
+    reuse the buffers of its ``caches`` argument for its output; the caller
+    must not read what it passed there again."""
     f = partial(fn, *bound)
     f.__name__ = f.__qualname__ = fn.__name__
-    return jax.jit(f)
+    return jax.jit(f, donate_argnames="caches" if donate else None)
 
 
 def _device_memory() -> dict:
@@ -325,10 +327,19 @@ class ContinuousServeEngine:
         # prefill at exact lengths (more jit variants, exact math)
         self._exact_prefill = any(m in ("mamba", "mlstm", "slstm")
                                   for m, _ in cfg.layer_kinds)
-        self._decode = _named_jit(M.decode_step_rows, cfg, rt)
-        self._pack = _named_jit(M.pack_prefill_caches, cfg, rt)
-        self._escalate = _named_jit(M.escalate_slot, cfg, rt)
-        self._defrag = _named_jit(M.defrag_caches, cfg, rt)
+        # the steps donate the arenas only where every scanned cache rides in
+        # the layer scan's carry: then each call updates the arena in place;
+        # donating a cache that is sliced through the scan would add copies
+        self.donates = M.carries_arenas(jax.eval_shape(partial(
+            M.init_paged_caches, cfg, rt, serving, self.tiered)))
+        self._decode = _named_jit(M.decode_step_rows, cfg, rt,
+                                  donate=self.donates)
+        self._pack = _named_jit(M.pack_prefill_caches, cfg, rt,
+                                donate=self.donates)
+        self._escalate = _named_jit(M.escalate_slot, cfg, rt,
+                                    donate=self.donates)
+        self._defrag = _named_jit(M.defrag_caches, cfg, rt,
+                                  donate=self.donates)
         self._prefills: dict[str, object] = {}   # one-shot oracle path only
         self._chunk_fns: dict[tuple[int, bool], object] = {}
         # two layer families keep the exact one-shot admission: recurrent
@@ -355,7 +366,8 @@ class ContinuousServeEngine:
         self.spec_on = (serving.spec_len > 0 and self.chunked
                         and rt.mode in ("dense", "decomposed"))
         self._verify_fns: dict[int, object] = {}
-        self._copy_page = _named_jit(M.copy_page_caches, cfg, rt)
+        self._copy_page = _named_jit(M.copy_page_caches, cfg, rt,
+                                     donate=self.donates)
         # cache-bearing layer count for the traffic model
         self._n_cache_layers = sum(1 for m, _ in cfg.layer_kinds if m in ("attn", "mla"))
         self.policy = policy          # object/str override of serving.policy
@@ -397,7 +409,8 @@ class ContinuousServeEngine:
         if key not in self._chunk_fns:
             rt_t = self._rt_for_tier(tier)
             self._chunk_fns[key] = _named_jit(
-                M.prefill_chunk_rows, self.cfg, rt_t, tier, first)
+                M.prefill_chunk_rows, self.cfg, rt_t, tier, first,
+                donate=self.donates)
         return self._chunk_fns[key]
 
     def _verify_fn(self, tier: int):
@@ -408,7 +421,8 @@ class ContinuousServeEngine:
         if tier not in self._verify_fns:
             rt_t = self._rt_for_tier(tier)
             self._verify_fns[tier] = _named_jit(
-                M.verify_chunk_rows, self.cfg, rt_t, tier, False)
+                M.verify_chunk_rows, self.cfg, rt_t, tier, False,
+                donate=self.donates)
         return self._verify_fns[tier]
 
     def _bucketed(self, ctx: np.ndarray) -> tuple[np.ndarray, int]:

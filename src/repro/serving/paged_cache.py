@@ -22,9 +22,15 @@ Layout invariants (shared by every paged container):
   * Per-token state pages; per-SEQUENCE state (CPQ scale/zero/levels,
     retrieval proxy calibration) stays slot-indexed ``(B, ...)`` — it is
     O(1) per request and is overwritten at admission.
+  * Dense K/V pools are lane-dense: ``(P, page, KV*Dh)``, head h at lanes
+    ``[h*Dh, (h+1)*Dh)``; the scanned layers' pools are stacked
+    ``(L, P, page, KV*Dh)``, the TPU's default row-major layout, which the
+    paged flash kernels DMA pages from directly. Inside the layer scan the
+    stack rides in the carry and is read and written in place at the layer
+    index (``PagedDenseKVCache.layer``); no per-layer slice of it exists.
 
 Mode -> paged container (mirrors core/kv_cache.py):
-  dense      PagedDenseKVCache   K,V pages
+  dense      PagedDenseKVCache   K,V pages, lane-dense (heads merged)
   decomposed PagedXCache         X pages (+ roped key pages)     (T1)
   cpq        PagedCPQKVCache     CPQ code/level pages, slot stats (T2)
   retrieval  PagedRetrievalCache K,V,proxy pages, slot calibration (T3)
@@ -71,17 +77,27 @@ def gather_pages(pages: jax.Array, block_table: jax.Array) -> jax.Array:
     return g.reshape(g.shape[0], g.shape[1] * g.shape[2], *g.shape[3:])
 
 
+def _at(layer, page_idx, off) -> tuple:
+    """Scatter index into a pool: (page, offset), or (layer, page, offset)
+    into a stacked (L, P, page, ...) pool."""
+    return (page_idx, off) if layer is None else (layer, page_idx, off)
+
+
 @_kv_write
 def write_token_pages(pages: jax.Array, block_table: jax.Array, lengths: jax.Array,
-                      active: jax.Array, val: jax.Array) -> jax.Array:
+                      active: jax.Array, val: jax.Array,
+                      layer: Optional[jax.Array] = None) -> jax.Array:
     """Scatter one token per row at slot ``lengths[b]``. val: (B, ...) —
-    token payload per row. Inactive rows write the null page."""
-    page_size, max_blocks = pages.shape[1], block_table.shape[1]
+    token payload per row. Inactive rows write the null page. With
+    ``layer``, ``pages`` is a stacked (L, P, page, ...) pool written in
+    place at that layer."""
+    page_size = pages.shape[1 if layer is None else 2]
+    max_blocks = block_table.shape[1]
     blk = jnp.clip(lengths // page_size, 0, max_blocks - 1)
     page_idx = jnp.take_along_axis(block_table, blk[:, None], axis=1)[:, 0]
     page_idx = jnp.where(active, page_idx, NULL_PAGE)
     off = lengths % page_size
-    return pages.at[page_idx, off].set(val.astype(pages.dtype))
+    return pages.at[_at(layer, page_idx, off)].set(val.astype(pages.dtype))
 
 
 @_kv_write
@@ -102,15 +118,18 @@ def write_prompt_pages(pages: jax.Array, block_row: jax.Array, val: jax.Array) -
 
 @_kv_write
 def write_chunk_pages(pages: jax.Array, block_row: jax.Array, offset: jax.Array,
-                      valid: jax.Array, vals: jax.Array) -> jax.Array:
+                      valid: jax.Array, vals: jax.Array,
+                      layer: Optional[jax.Array] = None) -> jax.Array:
     """Bulk-write one prompt CHUNK into one slot's pages at positions
     ``offset .. offset+C-1`` (chunked paged prefill: the chunk's payload goes
     straight into the arena — no contiguous scratch cache). vals: (C, ...).
     Positions past ``offset + valid`` (the chunk's jit padding), positions
     whose block is unmapped, and positions beyond the slot's page capacity
     all land on the null page, so page contents are independent of how a
-    prompt is split into chunks (property-tested)."""
-    C, page_size = vals.shape[0], pages.shape[1]
+    prompt is split into chunks (property-tested). With ``layer``,
+    ``pages`` is a stacked (L, P, page, ...) pool written in place at that
+    layer."""
+    C, page_size = vals.shape[0], pages.shape[1 if layer is None else 2]
     idx = jnp.arange(C, dtype=jnp.int32)
     pos = offset + idx
     blk = pos // page_size
@@ -118,7 +137,8 @@ def write_chunk_pages(pages: jax.Array, block_row: jax.Array, offset: jax.Array,
     pidx = jnp.where(ok,
                      block_row[jnp.clip(blk, 0, block_row.shape[0] - 1)],
                      NULL_PAGE)
-    return pages.at[pidx, pos % page_size].set(vals.astype(pages.dtype))
+    return pages.at[_at(layer, pidx, pos % page_size)].set(
+        vals.astype(pages.dtype))
 
 
 def _sel_rows(active: jax.Array, new, old):
@@ -333,8 +353,14 @@ def copy_page(cache: "PagedCache", src: jax.Array, dst: jax.Array) -> "PagedCach
 
 
 class PagedDenseKVCache(NamedTuple):
-    k: jax.Array  # (P, page, KV, Dh)
-    v: jax.Array  # (P, page, KV, Dh)
+    """Lane-dense K/V pools: (P, page, KV*Dh) for one layer, stacked
+    (L, P, page, KV*Dh) for the scanned layers. ``layer`` is set only inside
+    the layer scan, where the container carries the whole stack and the
+    index of the layer being run (``models/model.py``)."""
+
+    k: jax.Array
+    v: jax.Array
+    layer: Optional[jax.Array] = None
 
 
 class PagedXCache(NamedTuple):
@@ -388,8 +414,10 @@ PagedCache = (PagedDenseKVCache | PagedXCache | PagedCPQKVCache
 
 def init_paged_dense(num_pages: int, page_size: int, kv: int, dh: int,
                      dtype=jnp.bfloat16) -> PagedDenseKVCache:
-    z = jnp.zeros((num_pages, page_size, kv, dh), dtype)
-    return PagedDenseKVCache(z, z)
+    """Lane-dense pools; K and V are distinct buffers, so a step may donate
+    both."""
+    shape = (num_pages, page_size, kv * dh)
+    return PagedDenseKVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
 def init_paged_x(num_pages: int, page_size: int, dm: int, kv: int, rope_dims: int,
@@ -440,6 +468,40 @@ def init_paged_cpq_x(num_pages: int, page_size: int, num_slots: int, dm: int,
 # ------------------------------------------------------------ logical views
 
 
+def _dense_stack(cache: PagedDenseKVCache):
+    """(k, v, layer) that the dense read and write paths index: inside the
+    layer scan the container already holds the stacked (L, P, page, KV*Dh)
+    pools and the layer being run; a single layer's (P, page, KV*Dh) pools
+    are read as a stack of one, at layer 0."""
+    if cache.layer is None:
+        return cache.k[None], cache.v[None], jnp.zeros((), jnp.int32)
+    return cache.k, cache.v, cache.layer
+
+
+def _dense_like(cache: PagedDenseKVCache, k: jax.Array,
+                v: jax.Array) -> PagedDenseKVCache:
+    """Put stacked pools back in the form ``cache`` came in."""
+    if cache.layer is None:
+        k, v = k[0], v[0]
+    return cache._replace(k=k, v=v)
+
+
+def gather_layer_heads(pages: jax.Array, layer: jax.Array,
+                       block_table: jax.Array, kv: int) -> jax.Array:
+    """Logical per-head view of one layer of a stacked lane-dense pool (the
+    gather oracle of the paged flash kernels): (L, P, page, KV*D) x
+    (B, max_blocks) -> (B, max_blocks * page, KV, D)."""
+    g = pages[layer, block_table]             # (B, max_blocks, page, KV*D)
+    B, nb, page, w = g.shape
+    return g.reshape(B, nb * page, kv, w // kv)
+
+
+def _one_layer(pool: jax.Array) -> jax.Array:
+    """A per-head (P, page, KV, D) pool as the paged flash kernels read it:
+    a lane-dense stack of one layer, (1, P, page, KV*D)."""
+    return pool.reshape((1,) + pool.shape[:2] + (-1,))
+
+
 def logical_cpq(t: PagedCPQTensor, block_table: jax.Array) -> cpq_lib.CPQTensor:
     """Contiguous CPQTensor view of a paged CPQ arena (codes gathered through
     the block table; per-slot stats already contiguous). The chunked decode
@@ -452,14 +514,6 @@ def logical_cpq(t: PagedCPQTensor, block_table: jax.Array) -> cpq_lib.CPQTensor:
 
 
 # -------------------------------------------------------------- decode append
-
-
-def append_dense(cache: PagedDenseKVCache, rows: RowState,
-                 k_t: jax.Array, v_t: jax.Array) -> PagedDenseKVCache:
-    """k_t/v_t: (B, 1, KV, Dh) new token per row."""
-    return PagedDenseKVCache(
-        k=write_token_pages(cache.k, rows.block_table, rows.lengths, rows.active, k_t[:, 0]),
-        v=write_token_pages(cache.v, rows.block_table, rows.lengths, rows.active, v_t[:, 0]))
 
 
 def append_x(cache: PagedXCache, rows: RowState,
@@ -495,9 +549,10 @@ def append_cpq_tensor(t: PagedCPQTensor, rows: RowState, x_t: jax.Array,
 def pack_dense(cache: PagedDenseKVCache, src: kvc.DenseKVCache,
                block_row: jax.Array) -> PagedDenseKVCache:
     """Scatter a freshly prefilled contiguous B=1 cache into one slot's pages."""
+    S = src.k.shape[1]
     return PagedDenseKVCache(
-        k=write_prompt_pages(cache.k, block_row, src.k[0]),
-        v=write_prompt_pages(cache.v, block_row, src.v[0]))
+        k=write_prompt_pages(cache.k, block_row, src.k[0].reshape(S, -1)),
+        v=write_prompt_pages(cache.v, block_row, src.v[0].reshape(S, -1)))
 
 
 def pack_x(cache: PagedXCache, src: kvc.XCache, block_row: jax.Array) -> PagedXCache:
@@ -747,18 +802,21 @@ def chunk_attend_paged(
         return out, cache._replace(cpq=cpq)
 
     if isinstance(cache, PagedDenseKVCache):
-        cache = PagedDenseKVCache(
-            k=write_chunk_pages(cache.k, block_row, offset, valid, k_c[0]),
-            v=write_chunk_pages(cache.v, block_row, offset, valid, v_c[0]))
+        k, v, layer = _dense_stack(cache)
+        C, KV = k_c.shape[1], k_c.shape[2]
+        k = write_chunk_pages(k, block_row, offset, valid,
+                              k_c[0].reshape(C, -1), layer)
+        v = write_chunk_pages(v, block_row, offset, valid,
+                              v_c[0].reshape(C, -1), layer)
         if fused:
-            out = paged_flash_prefill_tpu(q, cache.k, cache.v, block_row,
-                                          offset, valid, scale)
+            out = paged_flash_prefill_tpu(q, k, v, block_row, offset, valid,
+                                          layer, scale)
         else:
             out = core_attn.dense_attention(
-                q, gather_pages(cache.k, block_row[None]),
-                gather_pages(cache.v, block_row[None]),
+                q, gather_layer_heads(k, layer, block_row[None], KV),
+                gather_layer_heads(v, layer, block_row[None], KV),
                 scale, causal=True, q_offset=offset, kv_length=total)
-        return out, cache
+        return out, _dense_like(cache, k, v)
 
     if isinstance(cache, PagedXCache):
         cache = PagedXCache(
@@ -820,8 +878,9 @@ def chunk_attend_paged(
         # prefill COMPUTE is dense (T3 gates decode reads only): K/V pages
         # hold raw payload, so the dense chunk kernels serve this tier too
         if fused:
-            out = paged_flash_prefill_tpu(q, cache.k, cache.v, block_row,
-                                          offset, valid, scale)
+            out = paged_flash_prefill_tpu(
+                q, _one_layer(cache.k), _one_layer(cache.v), block_row,
+                offset, valid, 0, scale)
         else:
             out = core_attn.dense_attention(
                 q, gather_pages(cache.k, block_row[None]),
@@ -859,7 +918,7 @@ def bytes_per_token(cache: PagedCache, page_size: int,
     if isinstance(cache, TieredPagedCache):  # base-tier accounting
         return bytes_per_token(cache.dense, page_size, cpq_cfg)
     if isinstance(cache, PagedDenseKVCache):
-        payload = 2.0 * cache.k.shape[2] * cache.k.shape[3] * cache.k.dtype.itemsize
+        payload = 2.0 * cache.k.shape[-1] * cache.k.dtype.itemsize
     elif isinstance(cache, PagedXCache):
         payload = (cache.x.shape[2] * cache.x.dtype.itemsize
                    + cache.k_rope.shape[2] * cache.k_rope.shape[3]
@@ -959,16 +1018,21 @@ def decode_attend_paged(
         return out, TieredPagedCache(dense, cpq)
 
     if isinstance(cache, PagedDenseKVCache):
-        cache = append_dense(cache, rows, k_t, v_t)
+        k, v, layer = _dense_stack(cache)
+        B, KV = k_t.shape[0], k_t.shape[2]
+        k = write_token_pages(k, rows.block_table, rows.lengths, rows.active,
+                              k_t[:, 0].reshape(B, -1), layer)
+        v = write_token_pages(v, rows.block_table, rows.lengths, rows.active,
+                              v_t[:, 0].reshape(B, -1), layer)
         if fused:
             out = paged_flash_decode_tpu(
-                q, cache.k, cache.v, rows.block_table, new_len, scale)
+                q, k, v, rows.block_table, new_len, layer, scale)
         else:
             out = core_attn.dense_attention(
-                q, gather_pages(cache.k, rows.block_table),
-                gather_pages(cache.v, rows.block_table),
+                q, gather_layer_heads(k, layer, rows.block_table, KV),
+                gather_layer_heads(v, layer, rows.block_table, KV),
                 scale, causal=False, kv_length=new_len)
-        return out, cache
+        return out, _dense_like(cache, k, v)
 
     if isinstance(cache, PagedXCache):
         cache = append_x(cache, rows, x_t, k_rope_t)

@@ -28,6 +28,7 @@ from repro.configs.base import CPQCfg
 from repro.core import cpq as C
 
 ARGMAX_GAP = 1e-4  # top-2 oracle gap below which greedy ties are ignored
+LAYERS = 3         # layers of the stacked dense arenas the flash kernels read
 
 
 def _pool_layout(rng, B, nb, page):
@@ -46,12 +47,27 @@ def _pool_layout(rng, B, nb, page):
     return num_pages, lengths, bt
 
 
-def _relabel(pools, bt, num_pages, rng):
-    """Apply a random physical-page relabeling (defrag analogue): outputs
-    must be bitwise invariant."""
+def _relabel(pools, bt, num_pages, rng, axis=0):
+    """Apply a random physical-page relabeling (defrag analogue) to the page
+    axis ``axis`` of each pool: outputs must be bitwise invariant."""
     perm = np.concatenate([[0], rng.permutation(np.arange(1, num_pages))])
     inv = np.argsort(perm)
-    return [np.asarray(p)[perm] for p in pools], inv[bt].astype(np.int32)
+    return ([np.take(np.asarray(p), perm, axis=axis) for p in pools],
+            inv[bt].astype(np.int32))
+
+
+def _stacked_heads(rng, num_pages, page, KV, D):
+    """Per-head pools (LAYERS, P, page, KV, D) with different contents in
+    every layer and the null page poisoned in each, so a kernel that reads
+    the wrong layer or the null page fails."""
+    heads = rng.normal(size=(LAYERS, num_pages, page, KV, D)).astype(np.float32)
+    heads[:, 0] = 1e3
+    return heads
+
+
+def _lane_dense(heads, dtype=jnp.float32):
+    """The serving arena's form of per-head pools: (L, P, page, KV*D)."""
+    return jnp.asarray(heads.reshape(heads.shape[:3] + (-1,)), dtype)
 
 
 def _argmax_where_resolvable(out, ref):
@@ -67,33 +83,39 @@ def _argmax_where_resolvable(out, ref):
 # ------------------------------------------------------------- dense / flash
 
 
-def check_paged_flash(seed, page, nb, B, KV, g, Dh, dtype=jnp.float32):
+def check_paged_flash(seed, page, nb, B, KV, g, Dh, dtype=jnp.float32,
+                      layer=0):
+    """The kernel reads layer ``layer`` of a stacked lane-dense arena; the
+    oracle reads that layer's per-head pools."""
     from repro.kernels.flash_attn.ops import paged_flash_decode_tpu
     from repro.kernels.flash_attn.ref import paged_flash_decode_ref
 
     rng = np.random.default_rng(seed)
     num_pages, lengths, bt = _pool_layout(rng, B, nb, page)
-    kp = rng.normal(size=(num_pages, page, KV, Dh)).astype(np.float32)
-    vp = rng.normal(size=(num_pages, page, KV, Dh)).astype(np.float32)
-    kp[0] = vp[0] = 1e3                               # poison the null page
-    q = rng.normal(size=(B, 1, KV * g, Dh)).astype(np.float32)
-    args = (jnp.asarray(q, dtype), jnp.asarray(kp, dtype),
-            jnp.asarray(vp, dtype), jnp.asarray(bt), jnp.asarray(lengths))
-    out = paged_flash_decode_tpu(*args, Dh ** -0.5)
-    ref = paged_flash_decode_ref(*args, Dh ** -0.5)
+    kh = _stacked_heads(rng, num_pages, page, KV, Dh)
+    vh = _stacked_heads(rng, num_pages, page, KV, Dh)
+    q = jnp.asarray(rng.normal(size=(B, 1, KV * g, Dh)).astype(np.float32),
+                    dtype)
+    out = paged_flash_decode_tpu(q, _lane_dense(kh, dtype),
+                                 _lane_dense(vh, dtype), jnp.asarray(bt),
+                                 jnp.asarray(lengths), layer, Dh ** -0.5)
+    ref = paged_flash_decode_ref(q, jnp.asarray(kh[layer], dtype),
+                                 jnp.asarray(vh[layer], dtype), jnp.asarray(bt),
+                                 jnp.asarray(lengths), Dh ** -0.5)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol)
     _argmax_where_resolvable(out, ref)
 
-    (kp2, vp2), bt2 = _relabel([kp, vp], bt, num_pages, rng)
-    out2 = paged_flash_decode_tpu(jnp.asarray(q, dtype), jnp.asarray(kp2, dtype),
-                                  jnp.asarray(vp2, dtype), jnp.asarray(bt2),
-                                  jnp.asarray(lengths), Dh ** -0.5)
+    (kh2, vh2), bt2 = _relabel([kh, vh], bt, num_pages, rng, axis=1)
+    out2 = paged_flash_decode_tpu(q, _lane_dense(kh2, dtype),
+                                  _lane_dense(vh2, dtype), jnp.asarray(bt2),
+                                  jnp.asarray(lengths), layer, Dh ** -0.5)
     np.testing.assert_array_equal(np.asarray(out, np.float32),
                                   np.asarray(out2, np.float32))
 
 
+@pytest.mark.parametrize("layer", [0, 1, LAYERS - 1])
 @pytest.mark.parametrize("seed,page,nb,B,KV,g,Dh,dtype", [
     (0, 4, 4, 3, 2, 2, 16, jnp.float32),
     (1, 1, 3, 2, 1, 4, 8, jnp.float32),   # page_size 1: one token per page
@@ -102,8 +124,8 @@ def check_paged_flash(seed, page, nb, B, KV, g, Dh, dtype=jnp.float32):
     (4, 4, 1, 1, 1, 1, 8, jnp.float32),   # single block
     (5, 4, 3, 2, 2, 2, 16, jnp.bfloat16),  # the engine's default cache dtype
 ])
-def test_paged_flash_sweep(seed, page, nb, B, KV, g, Dh, dtype):
-    check_paged_flash(seed, page, nb, B, KV, g, Dh, dtype)
+def test_paged_flash_sweep(seed, page, nb, B, KV, g, Dh, dtype, layer):
+    check_paged_flash(seed, page, nb, B, KV, g, Dh, dtype, layer)
 
 
 @hypothesis.given(
@@ -113,10 +135,11 @@ def test_paged_flash_sweep(seed, page, nb, B, KV, g, Dh, dtype):
     B=st.integers(1, 3),
     KV=st.sampled_from([1, 2]),
     g=st.sampled_from([1, 2, 4]),
+    layer=st.integers(0, LAYERS - 1),
 )
 @hypothesis.settings(max_examples=10, deadline=None)
-def test_paged_flash_property(seed, page, nb, B, KV, g):
-    check_paged_flash(seed, page, nb, B, KV, g, Dh=16)
+def test_paged_flash_property(seed, page, nb, B, KV, g, layer):
+    check_paged_flash(seed, page, nb, B, KV, g, Dh=16, layer=layer)
 
 
 # ------------------------------------------------------------------ T2 / CPQ
@@ -261,16 +284,16 @@ def test_paged_kernels_greedy_exact_vs_gather_f32():
     from repro.serving import paged_cache as pgc
 
     rng = np.random.default_rng(9)
-    B, KV, g, Dh, page, nb = 3, 2, 2, 16, 4, 4
+    B, KV, g, Dh, page, nb, layer = 3, 2, 2, 16, 4, 4, 1
     num_pages, lengths, bt = _pool_layout(rng, B, nb, page)
-    kp = jnp.asarray(rng.normal(size=(num_pages, page, KV, Dh)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(num_pages, page, KV, Dh)).astype(np.float32))
+    kp = _lane_dense(_stacked_heads(rng, num_pages, page, KV, Dh))
+    vp = _lane_dense(_stacked_heads(rng, num_pages, page, KV, Dh))
     q = jnp.asarray(rng.normal(size=(B, 1, KV * g, Dh)).astype(np.float32))
     out_k = paged_flash_decode_tpu(q, kp, vp, jnp.asarray(bt),
-                                   jnp.asarray(lengths), Dh ** -0.5)
+                                   jnp.asarray(lengths), layer, Dh ** -0.5)
     out_g = core_attn.dense_attention(
-        q, pgc.gather_pages(kp, jnp.asarray(bt)),
-        pgc.gather_pages(vp, jnp.asarray(bt)), Dh ** -0.5,
+        q, pgc.gather_layer_heads(kp, layer, jnp.asarray(bt), KV),
+        pgc.gather_layer_heads(vp, layer, jnp.asarray(bt), KV), Dh ** -0.5,
         causal=False, kv_length=jnp.asarray(lengths))
     live = lengths > 0
     np.testing.assert_allclose(np.asarray(out_k)[live], np.asarray(out_g)[live],
@@ -292,25 +315,28 @@ def _prefill_layout(rng, page, nb, offset, valid):
     return num_pages, row
 
 
+@pytest.mark.parametrize("layer", [0, 1, LAYERS - 1])
 @pytest.mark.parametrize("seed,page,nb,C,KV,g,offset,valid", [
     (0, 4, 6, 8, 2, 2, 0, 8),
     (1, 4, 6, 8, 2, 1, 8, 5),    # continuation chunk, jit padding
     (2, 2, 8, 4, 4, 1, 6, 4),
 ])
-def test_paged_flash_prefill_vs_ref(seed, page, nb, C, KV, g, offset, valid):
+def test_paged_flash_prefill_vs_ref(seed, page, nb, C, KV, g, offset, valid,
+                                    layer):
     from repro.kernels.flash_attn.kernel import paged_flash_prefill_fwd
     from repro.kernels.flash_attn.ref import paged_flash_prefill_ref
 
     rng = np.random.default_rng(seed)
     num_pages, row = _prefill_layout(rng, page, nb, offset, valid)
-    kp = rng.normal(size=(num_pages, page, KV, 16)).astype(np.float32)
-    vp = rng.normal(size=(num_pages, page, KV, 16)).astype(np.float32)
-    kp[0] = vp[0] = 1e3                               # poison the null page
-    q = rng.normal(size=(1, C, KV * g, 16)).astype(np.float32)
-    args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(row),
-            jnp.asarray(offset, jnp.int32), jnp.asarray(valid, jnp.int32))
-    out = paged_flash_prefill_fwd(*args, scale=0.25, interpret=True)
-    ref = paged_flash_prefill_ref(*args, 0.25)
+    kh = _stacked_heads(rng, num_pages, page, KV, 16)
+    vh = _stacked_heads(rng, num_pages, page, KV, 16)
+    q = jnp.asarray(rng.normal(size=(1, C, KV * g, 16)).astype(np.float32))
+    pos = (jnp.asarray(row), jnp.asarray(offset, jnp.int32),
+           jnp.asarray(valid, jnp.int32))
+    out = paged_flash_prefill_fwd(q, _lane_dense(kh), _lane_dense(vh), *pos,
+                                  layer, scale=0.25, interpret=True)
+    ref = paged_flash_prefill_ref(q, jnp.asarray(kh[layer]),
+                                  jnp.asarray(vh[layer]), *pos, 0.25)
     np.testing.assert_allclose(np.asarray(out)[0, :valid],
                                np.asarray(ref)[0, :valid], atol=2e-5)
 
@@ -390,9 +416,9 @@ def test_interpret_mode_follows_the_platform(monkeypatch):
     assert not hasattr(K, "INTERPRET")
     rng = np.random.default_rng(0)
     num_pages, lengths, bt = _pool_layout(rng, 2, 3, 4)
-    kp = jnp.asarray(rng.normal(size=(num_pages, 4, 2, 8)).astype(np.float32))
+    kp = _lane_dense(_stacked_heads(rng, num_pages, 4, 2, 8))
     q = jnp.asarray(rng.normal(size=(2, 1, 4, 8)).astype(np.float32))
-    args = (q, kp, kp, jnp.asarray(bt), jnp.asarray(lengths))
+    args = (q, kp, kp, jnp.asarray(bt), jnp.asarray(lengths), LAYERS - 1)
     assert q.devices().pop().platform == "cpu"
     hlo = paged_flash_decode_tpu.lower(*args, scale=0.3).compile().as_text()
     assert "tpu_custom_call" not in hlo

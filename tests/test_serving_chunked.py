@@ -325,8 +325,8 @@ def test_engine_chunked_pages_match_oneshot_pack():
         for c in jax.tree.leaves(caches, is_leaf=lambda x: isinstance(
                 x, pgc.PagedDenseKVCache)):
             if isinstance(c, pgc.PagedDenseKVCache):
-                k = c.k  # (P, page, KV, Dh) or stacked (nb, P, page, KV, Dh)
-                ks = k[None] if k.ndim == 4 else k
+                k = c.k  # (P, page, KV*Dh) or stacked (nb, P, page, KV*Dh)
+                ks = k[None] if k.ndim == 3 else k
                 for j in range(ks.shape[0]):
                     out.append(np.asarray(pgc.gather_pages(
                         ks[j], jnp.asarray(block_row[None])))[0, :S])
@@ -344,17 +344,21 @@ def test_engine_chunked_pages_match_oneshot_pack():
 # -------------------------------------------------- kernel-level oracles
 
 
-def _rand_paged_dense(rng, P, page, KV, Dh, Dv):
-    k = jnp.asarray(rng.normal(size=(P, page, KV, Dh)).astype(np.float32))
-    v = jnp.asarray(rng.normal(size=(P, page, KV, Dv)).astype(np.float32))
+def _rand_paged_dense(rng, P, page, KV, Dh, Dv, layers=3):
+    """Stacked lane-dense pools (layers, P, page, KV*D), every layer with
+    its own contents."""
+    k = jnp.asarray(rng.normal(size=(layers, P, page, KV * Dh)).astype(np.float32))
+    v = jnp.asarray(rng.normal(size=(layers, P, page, KV * Dv)).astype(np.float32))
     return k, v
 
 
+@pytest.mark.parametrize("layer", [0, 1, 2])
 @pytest.mark.parametrize("seed,offset,valid", [
     (0, 0, 8), (1, 8, 8), (2, 8, 3), (3, 4, 1), (4, 12, 5)])
-def test_paged_flash_prefill_kernel_vs_oracle(seed, offset, valid):
-    """Q-chunk>1 paged flash prefill == dense attention over the gathered
-    logical view with (q_offset, kv_length) masking, on permuted pages."""
+def test_paged_flash_prefill_kernel_vs_oracle(seed, offset, valid, layer):
+    """Q-chunk>1 paged flash prefill at ``layer`` of a stacked arena ==
+    dense attention over that layer's gathered logical view with
+    (q_offset, kv_length) masking, on permuted pages."""
     from repro.kernels.flash_attn.ops import paged_flash_prefill_tpu
 
     rng = np.random.default_rng(seed)
@@ -367,11 +371,11 @@ def test_paged_flash_prefill_kernel_vs_oracle(seed, offset, valid):
     q = jnp.asarray(rng.normal(size=(1, C, H, Dh)).astype(np.float32))
     out = paged_flash_prefill_tpu(q, k, v, block_row,
                                   jnp.asarray(offset, jnp.int32),
-                                  jnp.asarray(valid, jnp.int32), 0.35)
+                                  jnp.asarray(valid, jnp.int32), layer, 0.35)
     ref = core_attn.dense_attention(
-        q, pgc.gather_pages(k, block_row[None]),
-        pgc.gather_pages(v, block_row[None]), 0.35, causal=True,
-        q_offset=jnp.asarray(offset, jnp.int32),
+        q, pgc.gather_layer_heads(k, layer, block_row[None], KV),
+        pgc.gather_layer_heads(v, layer, block_row[None], KV), 0.35,
+        causal=True, q_offset=jnp.asarray(offset, jnp.int32),
         kv_length=jnp.asarray(offset + valid, jnp.int32))
     np.testing.assert_allclose(np.asarray(out)[0, :valid],
                                np.asarray(ref)[0, :valid], atol=2e-5)

@@ -60,7 +60,7 @@ def test_serve_paged_rules_shard_head_and_latent_axes():
                          max_blocks_per_slot=4)
     dense = paged_layer_cache_specs(cfg, cfg.attention, ("attn", "dense"),
                                     serving)
-    assert dense.k == P(None, None, "model", None)
+    assert dense.k == P(None, None, "model")      # lane-dense KV*Dh axis
     x = paged_layer_cache_specs(cfg, cfg.with_attention("decomposed").attention,
                                 ("attn", "dense"), serving)
     assert x.x == P(None, None, "model")          # latent feature axis

@@ -1,6 +1,7 @@
-"""Compile the serving path's paged Pallas kernels for a TPU v5e that is
-described, not attached, at Qwen1.5-0.5B widths (H = KV = 16, Dh = 64,
-d_model 1024, page 16, 8 slots, prompt chunks of 128).
+"""Compile the serving path's paged Pallas kernels, and the engine's
+donated decode and chunk steps, for a TPU v5e that is described, not
+attached, at Qwen1.5-0.5B widths (H = KV = 16, Dh = 64, d_model 1024, page
+16, 8 slots, prompt chunks of 128).
 
 Interpret-mode tests check semantics only; Mosaic's block-shape and VMEM
 rules show up only when a kernel is compiled for the chip, and a compile
@@ -10,6 +11,8 @@ every xdist worker collects the same tests, and only the worker that runs
 this file loads it. A compile that passes is not a chip run.
 """
 import os
+import re
+from functools import partial
 
 import pytest
 
@@ -18,6 +21,7 @@ import jax.numpy as jnp
 
 B, H, KV, DH, PAGE, NB, C, DM, R, L = 8, 16, 16, 64, 16, 34, 128, 1024, 32, 4
 P = B * NB + 1  # one slot's worth of pages per row, plus the null page
+LAYERS = 4      # layers of the stacked dense arena (the model's depth here)
 
 
 @pytest.fixture(scope="module")
@@ -55,16 +59,17 @@ def _cases(sharding):
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    kv_pool = S((P, PAGE, KV, DH), bf)
+    kv_pool = S((LAYERS, P, PAGE, KV * DH), bf)
     codes, levels = S((P, PAGE, KV, DH), i8), S((P, PAGE, KV), i32)
     table, lens = S((B, NB), i32), S((B,), i32)
     row, scalar = S((NB,), i32), S((), i32)
     return {
         "flash_decode": (fk.paged_flash_decode_fwd,
-                         [S((B, 1, H, DH), bf), kv_pool, kv_pool, table, lens]),
+                         [S((B, 1, H, DH), bf), kv_pool, kv_pool, table, lens,
+                          scalar]),
         "flash_prefill": (fk.paged_flash_prefill_fwd,
                           [S((1, C, H, DH), bf), kv_pool, kv_pool, row, scalar,
-                           scalar]),
+                           scalar, scalar]),
         "cpq_decode": (ck.paged_cpq_decode_fwd,
                        [S((B, KV, H // KV, DH), bf), codes, codes]
                        + [S((B, L, KV, DH), f32)] * 4
@@ -131,3 +136,113 @@ def test_engine_kernel_entry_compiles_mosaic_on_tpu(one_chip):
     hlo = paged_flash_decode_tpu.lower(*args, scale=DH ** -0.5).compile(
         ).as_text()
     assert "tpu_custom_call" in hlo
+
+
+# ------------------------------------------- the engine's donated steps
+
+# the engine's arena holds 4096 positions a slot, as a long-document cell's
+# does: an arena small enough for VMEM (128 MiB on a v5e) may be staged
+# through it whole by the compiler, which a served arena never is
+ARENA_NB = 256
+ARENA_P = B * ARENA_NB + 1
+
+
+def _engine(mode: str):
+    """A continuous engine at Qwen1.5-0.5B widths, LAYERS layers deep, whose
+    jitted steps are lowered here and never run (it holds no weights)."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.base import AttentionRuntime, CPQCfg, ServingCfg
+    from repro.serving.engine import ContinuousServeEngine
+
+    cfg = dataclasses.replace(get_config("qwen1.5-0.5b"), num_blocks=LAYERS)
+    rt = AttentionRuntime(mode=mode, cpq=CPQCfg() if mode == "cpq" else None)
+    serving = ServingCfg(num_slots=B, page_size=PAGE, num_pages=ARENA_P,
+                         max_blocks_per_slot=ARENA_NB, prefill_chunk=C)
+    return ContinuousServeEngine(cfg, None, rt, serving)
+
+
+def _compiled_step(eng, sharding, step: str):
+    """(compiled program, abstract caches) of the engine's jitted ``step``:
+    "decode" or a chunk, "chunk_first" / "chunk_next"."""
+    from repro.models import model as M
+    from repro.serving import paged_cache as pgc
+
+    def S(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+    params = jax.tree.map(S, jax.eval_shape(
+        lambda: M.init_params(eng.cfg, jax.random.key(0))))
+    caches = jax.tree.map(S, jax.eval_shape(partial(
+        M.init_paged_caches, eng.cfg, eng.rt, eng.serving, False)))
+    if step == "decode":
+        rows = pgc.RowState(lengths=i32(B), block_table=i32(B, ARENA_NB),
+                            active=jax.ShapeDtypeStruct((B,), jnp.bool_,
+                                                        sharding=sharding),
+                            tier=i32(B))
+        fn, args = eng._decode, (params, i32(B, 1), rows, caches)
+    else:
+        fn = eng._chunk_fn(0, step == "chunk_first")
+        args = (params, i32(1, C), i32(), i32(ARENA_NB), i32(), i32(), caches)
+    return fn.lower(*args).compile(), caches
+
+
+_MOVES = ("copy", "copy-start", "copy-done", "dynamic-slice",
+          "dynamic-update-slice")
+
+
+def _arena_moves(hlo: str, pages: int) -> list[str]:
+    """Instructions of a compiled program that copy, slice or allocate a
+    buffer whose shape holds the arena's page count."""
+    found = []
+    for ln in hlo.splitlines():
+        if " = " not in ln:
+            continue
+        rhs = ln.split(" = ", 1)[1]
+        if rhs.startswith("("):                    # tuple-shaped result
+            depth = 0
+            for i, ch in enumerate(rhs):
+                depth += {"(": 1, ")": -1}.get(ch, 0)
+                if depth == 0:
+                    break
+            shape, rest = rhs[:i + 1], rhs[i + 1:].lstrip()
+        else:
+            shape, _, rest = rhs.partition(" ")
+        op = rest.split("(", 1)[0]
+        moves = op in _MOVES or (op == "custom-call"
+                                 and 'custom_call_target="AllocateBuffer"' in ln)
+        if moves and re.search(rf"[\[,]{pages}[,\]]", shape):
+            found.append(ln.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk_first", "chunk_next"])
+def test_donated_steps_update_the_arena_in_place(one_chip, step):
+    """The dense arena rides in the layer scan's carry and the engine
+    donates it: the compiled step aliases the whole arena to its output,
+    needs less temporary memory than one layer's pool, and no op copies,
+    slices or allocates a buffer of the arena's page count — the two K/V
+    scatters and the kernels read and write it where it lies."""
+    eng = _engine("dense")
+    assert eng.donates
+    compiled, caches = _compiled_step(eng, one_chip, step)
+    arena = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(caches))
+    layer_pool = ARENA_P * PAGE * KV * DH * jnp.dtype(jnp.bfloat16).itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == arena
+    assert mem.temp_size_in_bytes < layer_pool, mem.temp_size_in_bytes
+    assert _arena_moves(compiled.as_text(), ARENA_P) == []
+
+
+def test_cpq_engine_steps_stay_undonated(one_chip):
+    """A CPQ arena is sliced per layer through the scan (it is not carried),
+    where donation would add whole-arena copies: its engine keeps undonated
+    steps, and the compiled decode step aliases nothing."""
+    eng = _engine("cpq")
+    assert not eng.donates
+    compiled, _ = _compiled_step(eng, one_chip, "decode")
+    assert compiled.memory_analysis().alias_size_in_bytes == 0
