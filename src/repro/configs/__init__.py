@@ -22,6 +22,7 @@ from repro.configs.base import (
     ServingCfg,
     ShapeCfg,
     XLSTMCfg,
+    YarnCfg,
     cell_supported,
 )
 
@@ -79,13 +80,15 @@ def smoke_config(cfg: ModelConfig) -> ModelConfig:
         num_patch_tokens=16 if cfg.num_patch_tokens else 0,
     )
     if cfg.moe is not None:
-        kw["moe"] = MoECfg(
+        kw["moe"] = dataclasses.replace(
+            cfg.moe,
             num_experts=8,
             num_shared=min(cfg.moe.num_shared, 1),
             top_k=2,
             d_ff_expert=32,
             capacity_factor=2.0,
         )
+        kw["experts_held"] = 0
     if cfg.mla is not None:
         kw["mla"] = MLACfg(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
     if cfg.mamba is not None:
@@ -109,6 +112,7 @@ __all__ = [
     "ServingCfg",
     "ShapeCfg",
     "XLSTMCfg",
+    "YarnCfg",
     "cell_supported",
     "get_config",
     "smoke_config",

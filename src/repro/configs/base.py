@@ -28,8 +28,23 @@ class MoECfg:
     num_shared: int = 2
     top_k: int = 6
     d_ff_expert: int = 1408
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25   # training dispatch only (serving drops none)
     router_jitter: float = 0.0
+    # gating: softmax over all experts, top_k of them; the chosen weights are
+    # renormalised to sum to 1 when ``norm_topk``
+    norm_topk: bool = True
+
+
+@dataclass(frozen=True)
+class YarnCfg:
+    """YaRN rope scaling (arXiv:2309.00071), as DeepSeek-V2 publishes it."""
+
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -359,6 +374,7 @@ class ModelConfig:
     qk_norm: bool = False
     pos_embedding: str = "rope"  # rope | absolute | none
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnCfg] = None
     tie_embeddings: bool = False
     embed_scale: bool = False  # gemma: scale embeddings by sqrt(d_model)
     logit_softcap: float = 0.0
@@ -366,6 +382,10 @@ class ModelConfig:
     num_patch_tokens: int = 0   # vlm: visual tokens per sample (stub frontend)
     # sub-configs
     moe: Optional[MoECfg] = None
+    # routed experts held on this chip, the first ``experts_held`` of
+    # ``moe.num_experts`` (expert parallelism: the router still scores all of
+    # them); 0 = all
+    experts_held: int = 0
     mla: Optional[MLACfg] = None
     mamba: Optional[MambaCfg] = None
     xlstm: Optional[XLSTMCfg] = None

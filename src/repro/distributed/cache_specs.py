@@ -150,6 +150,14 @@ def paged_container_specs(container, rules: dict | None = None):
         return pgc.PagedXCache(
             x=r("page_pool", "page", "latent"),
             k_rope=r("page_pool", "page", "kv_heads", "head_dim"))
+    if isinstance(c, pgc.PagedLatentCache):
+        # latent pages storage-sharded on their feature axis; the one
+        # shared rope head replicates
+        lat, rope = r("page_pool", "page", "latent"), P(None, None, None)
+        if c.layer is None:
+            return pgc.PagedLatentCache(c=lat, rope=rope)
+        return pgc.PagedLatentCache(c=P(None, *lat), rope=P(None, *rope),
+                                    layer=P())
     if isinstance(c, pgc.PagedCPQKVCache):
         t = _paged_cpq_specs(rules, latent=False)
         return pgc.PagedCPQKVCache(k=t, v=t)

@@ -354,3 +354,247 @@ def decomposed_decode_fwd(r: jax.Array, q_rope: jax.Array, x: jax.Array,
       q_rope if Rr else jnp.zeros((B, H, 1), r.dtype),
       x,
       k_rope if Rr else jnp.zeros((B, N + pad, 1), x.dtype))
+
+
+# ------------------------------------------------------- the MLA latent arena
+#
+# One grid step takes ``k`` pages of one row (``_pages_per_step``), each its
+# own block operand resolved through the block table, so the fixed cost of a
+# step is spread over k pages and every matmul is k pages wide. A page's rope
+# rows hold ``f`` tokens each (``PagedLatentCache``); segment j of a page is
+# its tokens [j*rows, (j+1)*rows), rope lanes [j*R, (j+1)*R). The step stacks
+# segment j of all k pages (rows of latent, rows of rope), scores it in one
+# matmul per operand, and runs one online-softmax update per segment.
+
+
+def _online_update(m_sc, l_sc, acc_sc, s, x):
+    """One online-softmax step: scores s (rows, n) over keys x (n, Dc)."""
+    m_prev = m_sc[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    corr = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    m_sc[...] = m_new
+    acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
+        p, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _nt(a, b):
+    """a (m, k) x b (n, k)^T -> (m, n), f32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _pages_per_step(nb: int) -> int:
+    """Pages one grid step reads: the most of 16, 8, 4, 2 that divides the
+    block table's width."""
+    return next(k for k in (16, 8, 4, 2, 1) if nb % k == 0)
+
+
+def _latent_step(r, qb, c_refs, kr_refs, m_sc, l_sc, acc_sc, ok, *,
+                 scale: float, page: int, f: int, first_pos):
+    """Both cascaded matmuls of the absorbed attention over the k pages of
+    one grid step (positions ``first_pos + i * page + t``). ``qb`` carries
+    the rope query in segment j's lanes for row block j, so one matmul
+    scores every segment's rope part without slicing lanes."""
+    rows = page // f
+    n_q = r.shape[0]
+    xs = [c[0, 0].astype(jnp.float32) for c in c_refs]     # k x (page, Dc)
+    kr = jnp.concatenate([k[0, 0] for k in kr_refs], axis=0)   # (k*rows, f*R)
+    sr = _nt(qb, kr.astype(jnp.float32))                    # (f*n_q, k*rows)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n_q, len(xs) * rows), 1)
+    for j in range(f):                                      # static
+        x = jnp.concatenate([x[j * rows:(j + 1) * rows] for x in xs], axis=0)
+        s = (_nt(r, x) + sr[j * n_q:(j + 1) * n_q]) * scale
+        pos = first_pos + (col // rows) * page + j * rows + col % rows
+        _online_update(m_sc, l_sc, acc_sc, jnp.where(ok(pos), s, NEG_INF), x)
+
+
+def _paged_latent_kernel(bt_ref, len_ref, lay_ref, r_ref, qb_ref, *refs,
+                         scale: float, page: int, nb: int, f: int, k: int):
+    """One (b, ib) step of the latent decode: pages bt[b, ib*k .. ib*k+k-1]
+    of layer lay[0], resolved by the BlockSpec index maps."""
+    c_refs, kr_refs = refs[:k], refs[k:2 * k]
+    p_ref, m_sc, l_sc, acc_sc = refs[2 * k:]
+    b = pl.program_id(0)
+    ib = pl.program_id(1)
+
+    @pl.when(ib == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    # steps whose pages all sit past the row's length read the null page:
+    # skip them
+    @pl.when(ib * k * page < len_ref[b])
+    def _compute():
+        _latent_step(r_ref[0].astype(jnp.float32), qb_ref[0].astype(jnp.float32),
+                     c_refs, kr_refs, m_sc, l_sc, acc_sc,
+                     lambda pos: pos < len_ref[b], scale=scale, page=page,
+                     f=f, first_pos=ib * k * page)
+
+    @pl.when(ib == nb // k - 1)
+    def _finish():
+        p_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(p_ref.dtype)
+
+
+def _paged_latent_prefill_kernel(bt_ref, lens_ref, lay_ref, r_ref, qb_ref,
+                                 *refs, scale: float, page: int, nb: int,
+                                 f: int, k: int, chunk: int):
+    """One ib step of the chunk's sweep over its slot's latent pages; query
+    rows head-major (row = h * C + i, token i at position lens[0] + i);
+    lens[1] = offset + valid masks the chunk's jit padding."""
+    c_refs, kr_refs = refs[:k], refs[k:2 * k]
+    p_ref, m_sc, l_sc, acc_sc = refs[2 * k:]
+    ib = pl.program_id(0)
+
+    @pl.when(ib == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    @pl.when(ib * k * page < lens_ref[1])
+    def _compute():
+        r = r_ref[0].astype(jnp.float32)                    # (H*C, Dc)
+        qtok = jax.lax.broadcasted_iota(
+            jnp.int32, (r.shape[0], k * page // f), 0) % chunk
+
+        def ok(pos):                                        # valid & causal
+            return (pos < lens_ref[1]) & (pos <= lens_ref[0] + qtok)
+
+        _latent_step(r, qb_ref[0].astype(jnp.float32), c_refs, kr_refs, m_sc,
+                     l_sc, acc_sc, ok, scale=scale, page=page, f=f,
+                     first_pos=ib * k * page)
+
+    @pl.when(ib == nb // k - 1)
+    def _finish():
+        p_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(p_ref.dtype)
+
+
+def _rope_blocks(q_rope: jax.Array, f: int) -> jax.Array:
+    """(n, R) rope queries -> (f*n, f*R): row block j holds them in lanes
+    [j*R, (j+1)*R), zeros elsewhere (``_latent_step``)."""
+    n, R = q_rope.shape[-2:]
+    eye = jnp.eye(f, dtype=q_rope.dtype)
+    out = eye[:, None, :, None] * q_rope[..., None, :, None, :]
+    return out.reshape(q_rope.shape[:-2] + (f * n, f * R))
+
+
+def _page_specs(k: int, page: int, dc: int, rows: int, fr: int, at):
+    """k block operands of the latent pool, then k of the rope pool: page
+    ``at(j, *grid_and_prefetch)`` of layer lay[0] for operand j."""
+    def spec(shape, j):
+        return pl.BlockSpec(shape, lambda *a, j=j: (a[-1][0], at(j, *a), 0, 0))
+    return ([spec((1, 1, page, dc), j) for j in range(k)]
+            + [spec((1, 1, rows, fr), j) for j in range(k)])
+
+
+def paged_latent_decode_fwd(r: jax.Array, q_rope: jax.Array,
+                            c_pages: jax.Array, rope_pages: jax.Array,
+                            block_table: jax.Array, lengths: jax.Array,
+                            layer: jax.Array, *, scale: float,
+                            interpret: bool = True) -> jax.Array:
+    """Paged MLA decode over a stacked latent arena: row b's query sweeps the
+    pages of layer ``layer`` that its block-table row maps, each DMA'd into
+    VMEM once for both cascaded matmuls.
+
+    r: (B, H, Dc) = q_nope W_UK; q_rope: (B, H, R); c_pages: (L, P, page,
+    Dc); rope_pages: (L, P, page // f, f * R) (``PagedLatentCache``);
+    block_table: (B, max_blocks) int32 (0 = null page); lengths: (B,);
+    layer: () int32. Returns P: (B, H, Dc) — the caller applies W_UV;
+    lengths[b] == 0 rows return zeros."""
+    B, H, Dc = r.shape
+    page = c_pages.shape[2]
+    rows, fR = rope_pages.shape[2:]
+    f = page // rows
+    nb = block_table.shape[1]
+    k = _pages_per_step(nb)
+    qb = _rope_blocks(q_rope, f)                            # (B, f*H, f*R)
+    kern = functools.partial(_paged_latent_kernel, scale=scale, page=page,
+                             nb=nb, f=f, k=k)
+    pages = _page_specs(k, page, Dc, rows, fR,
+                        lambda j, b, ib, bt, ln, ly: bt[b, ib * k + j])
+    c_ops = [c_pages] * k + [rope_pages] * k
+    return pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # block_table, lengths, layer
+            grid=(B, nb // k),      # innermost axis sweeps block-table entries
+            in_specs=[
+                pl.BlockSpec((1, H, Dc), lambda b, ib, bt, ln, ly: (b, 0, 0)),
+                pl.BlockSpec((1, f * H, fR), lambda b, ib, bt, ln, ly: (b, 0, 0)),
+            ] + pages,
+            out_specs=pl.BlockSpec((1, H, Dc), lambda b, ib, bt, ln, ly: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, Dc), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, Dc), c_pages.dtype),
+        name="paged_mla_decode",
+        interpret=interpret,
+    )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), r, qb, *c_ops)
+
+
+def paged_latent_prefill_fwd(r: jax.Array, q_rope: jax.Array,
+                             c_pages: jax.Array, rope_pages: jax.Array,
+                             block_row: jax.Array, offset: jax.Array,
+                             valid: jax.Array, layer: jax.Array, *,
+                             scale: float, interpret: bool = True) -> jax.Array:
+    """Chunked paged MLA prefill for one slot over a stacked latent arena:
+    the chunk's C queries sweep the slot's pages of layer ``layer`` in
+    [0, offset + valid), the chunk's own latent rows already written there.
+
+    r: (C, H, Dc); q_rope: (C, H, R); pools as ``paged_latent_decode_fwd``;
+    block_row: (max_blocks,) int32; offset/valid/layer: () int32. Returns
+    P: (C, H, Dc); rows past ``valid`` are jit-padding garbage."""
+    C, H, Dc = r.shape
+    page = c_pages.shape[2]
+    rows, fR = rope_pages.shape[2:]
+    f = page // rows
+    nb = block_row.shape[0]
+    k = _pages_per_step(nb)
+    HC = H * C
+    r2 = r.transpose(1, 0, 2).reshape(1, HC, Dc)            # head-major rows
+    qb = _rope_blocks(q_rope.transpose(1, 0, 2).reshape(1, HC, -1), f)
+    lens = jnp.stack([offset, offset + valid]).astype(jnp.int32)
+    kern = functools.partial(_paged_latent_prefill_kernel, scale=scale,
+                             page=page, nb=nb, f=f, k=k, chunk=C)
+    pages = _page_specs(k, page, Dc, rows, fR,
+                        lambda j, ib, bt, ln, ly: bt[ib * k + j])
+    itemsize = c_pages.dtype.itemsize
+    # resident per query row: the f32 accumulator and one f32 matmul result,
+    # the f32 copy of r, double-buffered r and output, the rope-query
+    # blocks, the lane-padded softmax statistics, and the score tiles
+    width = max(k * page // f, 128)
+    need = HC * (3 * 4 * Dc + 4 * Dc * itemsize + 2 * f * 128 * itemsize
+                 + 2 * 4 * 128 + (4 + f) * 4 * width)
+    out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # block_row, (offset, total), layer
+            grid=(nb // k,),
+            in_specs=[
+                pl.BlockSpec((1, HC, Dc), lambda ib, bt, ln, ly: (0, 0, 0)),
+                pl.BlockSpec((1, f * HC, fR), lambda ib, bt, ln, ly: (0, 0, 0)),
+            ] + pages,
+            out_specs=pl.BlockSpec((1, HC, Dc), lambda ib, bt, ln, ly: (0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((HC, 1), jnp.float32),
+                pltpu.VMEM((HC, 1), jnp.float32),
+                pltpu.VMEM((HC, Dc), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((1, HC, Dc), c_pages.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(16 << 20, need + (8 << 20))),
+        name="paged_mla_prefill",
+        interpret=interpret,
+    )(block_row.astype(jnp.int32), lens,
+      jnp.reshape(layer, (1,)).astype(jnp.int32), r2, qb,
+      *([c_pages] * k + [rope_pages] * k))
+    return out.reshape(H, C, Dc).transpose(1, 0, 2)

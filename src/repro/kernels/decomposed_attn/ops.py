@@ -14,7 +14,9 @@ import jax.numpy as jnp
 from repro import kernels as K
 from repro.kernels.decomposed_attn.kernel import (decomposed_decode_fwd,
                                                   paged_decomposed_decode_fwd,
-                                                  paged_decomposed_prefill_fwd)
+                                                  paged_decomposed_prefill_fwd,
+                                                  paged_latent_decode_fwd,
+                                                  paged_latent_prefill_fwd)
 
 
 @partial(jax.jit, static_argnames=("scale", "block_n", "interpret"))
@@ -111,3 +113,39 @@ def paged_decomposed_decode_tpu(q_nope, q_rope, x_pages, kr_pages,
     # out = P W_V  (second tiny dense MatMul)
     pg = p.reshape(B, KV, g, Dm)
     return jnp.einsum("bkgm,mkd->bkgd", pg, w_v).reshape(B, 1, H, Dv)
+
+
+@partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_latent_decode_tpu(q_nope, q_rope, c_pages, rope_pages, block_table,
+                            lengths, layer, w_uk, w_uv, scale: float,
+                            interpret: bool | None = None):
+    """Paged MLA decode (the absorbed form) over layer ``layer`` of a stacked
+    latent arena (``PagedLatentCache``). q_nope: (B, 1, H, Dn); q_rope:
+    (B, 1, H, R); w_uk: (H, Dc, Dn); w_uv: (H, Dc, Dv); block_table:
+    (B, max_blocks) int32; lengths: (B,) int32; layer: () int32.
+    Returns (B, 1, H, Dv)."""
+    B, _, H, _ = q_nope.shape
+    dt = c_pages.dtype
+    r = jnp.einsum("bhd,hmd->bhm", q_nope[:, 0], w_uk)     # q_nope W_UK^T
+    p = K.platform_call(paged_latent_decode_fwd, r.astype(dt),
+                        q_rope[:, 0].astype(dt), c_pages, rope_pages,
+                        block_table, lengths, layer, scale=scale,
+                        interpret=interpret)
+    return jnp.einsum("bhm,hmd->bhd", p, w_uv).reshape(B, 1, H, -1)
+
+
+@partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_latent_prefill_tpu(q_nope, q_rope, c_pages, rope_pages, block_row,
+                             offset, valid, layer, w_uk, w_uv, scale: float,
+                             interpret: bool | None = None):
+    """Chunked paged MLA prefill for one slot over layer ``layer`` of a
+    stacked latent arena. q_nope: (1, C, H, Dn); q_rope: (1, C, H, R);
+    block_row: (max_blocks,) int32; offset/valid/layer: () int32.
+    Returns (1, C, H, Dv); rows past ``valid`` are jit-padding garbage."""
+    _, C, H, _ = q_nope.shape
+    dt = c_pages.dtype
+    r = jnp.einsum("chd,hmd->chm", q_nope[0], w_uk)
+    p = K.platform_call(paged_latent_prefill_fwd, r.astype(dt),
+                        q_rope[0].astype(dt), c_pages, rope_pages, block_row,
+                        offset, valid, layer, scale=scale, interpret=interpret)
+    return jnp.einsum("chm,hmd->chd", p, w_uv)[None]

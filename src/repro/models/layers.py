@@ -47,12 +47,49 @@ def rms_norm_vec(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array
 # --------------------------------------------------------------------- rope
 
 
-def rope_tables(positions: jax.Array, dim: int, theta: float) -> tuple[jax.Array, jax.Array]:
-    """cos/sin tables. positions: (T,) int32 -> (T, dim/2) each, f32."""
-    assert dim % 2 == 0, dim
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor, ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, yarn) -> tuple[int, int]:
+    """First and last rotary pair index of YaRN's ramp between the
+    extrapolated (fast, ``beta_fast`` rotations over the original context)
+    and the interpolated (slow, ``beta_slow``) frequencies."""
+    def pair(rotations):
+        n = yarn.original_max_position_embeddings / (rotations * 2 * math.pi)
+        return dim * math.log(n) / (2 * math.log(theta))
+
+    return (max(math.floor(pair(yarn.beta_fast)), 0),
+            min(math.ceil(pair(yarn.beta_slow)), dim - 1))
+
+
+def rope_inv_freq(dim: int, theta: float, yarn=None) -> jax.Array:
+    """(dim/2,) f32 rotary frequencies ``theta^(-2i/dim)``; with YaRN,
+    ``f * m + f / factor * (1 - m)``, where the mask ``m`` is 1 below the
+    correction range, 0 above it, and linear across it."""
     inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if yarn is None:
+        return inv
+    low, high = yarn_correction_range(dim, theta, yarn)
+    ramp = (jnp.arange(dim // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3)
+    m = 1.0 - jnp.clip(ramp, 0.0, 1.0)
+    return inv / yarn.factor * (1.0 - m) + inv * m
+
+
+def rope_tables(positions: jax.Array, dim: int, theta: float,
+                yarn=None) -> tuple[jax.Array, jax.Array]:
+    """cos/sin tables. positions: (T,) or (B,) int32 -> (T, dim/2) each, f32.
+    With YaRN (``yarn``, a ``YarnCfg``) the frequencies are rescaled and
+    cos/sin carry ``mscale(mscale) / mscale(mscale_all_dim)``."""
+    assert dim % 2 == 0, dim
+    inv = rope_inv_freq(dim, theta, yarn)
     ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
-    return jnp.cos(ang), jnp.sin(ang)
+    if yarn is None:
+        return jnp.cos(ang), jnp.sin(ang)
+    mag = (yarn_mscale(yarn.factor, yarn.mscale)
+           / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+    return jnp.cos(ang) * mag, jnp.sin(ang) * mag
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

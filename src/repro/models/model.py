@@ -225,7 +225,7 @@ def init_paged_caches(cfg: ModelConfig, rt: AttentionRuntime, serving,
         init = functools.partial(tfm.layer_paged_cache_init, cfg, rt, kind,
                                  serving, tiered)
         shapes = jax.eval_shape(init)
-        if isinstance(shapes, pgc.PagedDenseKVCache):
+        if isinstance(shapes, CARRIED):
             # the carried arena: each pool one zeros at its stacked shape
             return jax.tree.map(
                 lambda a: jnp.zeros((cfg.num_blocks,) + a.shape, a.dtype), shapes)
@@ -236,23 +236,29 @@ def init_paged_caches(cfg: ModelConfig, rt: AttentionRuntime, serving,
     return {"prefix": prefix, "blocks": blocks}
 
 
+# Arenas that ride in the layer scan's carry, read and written in place at
+# the layer index: the dense K/V arena and the MLA latent arena. T1, CPQ,
+# retrieval, tiered and recurrent caches are sliced through xs/ys.
+CARRIED = (pgc.PagedDenseKVCache, pgc.PagedLatentCache)
+
+
 def carries_arenas(caches) -> bool:
-    """Whether every scanned cache is a dense arena that rides in the layer
+    """Whether every scanned cache is an arena that rides in the layer
     scan's carry (``_scan_layers``). Only then may a step donate its caches:
     a donated cache that went through the scan's xs/ys would cost whole-arena
     copies instead of saving them."""
-    return all(isinstance(c, pgc.PagedDenseKVCache) for c in caches["blocks"])
+    return all(isinstance(c, CARRIED) for c in caches["blocks"])
 
 
 def _scan_layers(cfg: ModelConfig, layer_fn, x, block_params, block_caches):
     """Run the scanned blocks: ``layer_fn(kind, p, x, cache) -> (x, cache)``.
 
-    A dense paged arena (``PagedDenseKVCache``) rides in the carry as its
-    whole stacked (L, P, page, KV*Dh) pools next to a layer counter; the
-    layer reads and writes it in place at that index, so no per-layer slice
-    of it is copied out or back. Every other cache is sliced per layer
-    through xs/ys, as is every weight. Returns (x, new block caches)."""
-    carried = [isinstance(c, pgc.PagedDenseKVCache) for c in block_caches]
+    A carried arena (``CARRIED``) rides in the carry as its whole stacked
+    (L, P, ...) pools next to a layer counter; the layer reads and writes it
+    in place at that index, so no per-layer slice of it is copied out or
+    back. Every other cache is sliced per layer through xs/ys, as is every
+    weight. Returns (x, new block caches)."""
+    carried = [isinstance(c, CARRIED) for c in block_caches]
     held0 = tuple(c for c, k in zip(block_caches, carried) if k)
     sliced0 = tuple(c for c, k in zip(block_caches, carried) if not k)
 
@@ -286,14 +292,16 @@ def decode_step_rows(cfg: ModelConfig, rt: AttentionRuntime, params,
     otherwise caches are gathered through the block table in jnp.
     tokens: (B, 1) int32. Returns (logits (B, V), caches).
 
-    Dense arenas ride in the layer scan's carry and are updated in place
-    (``_scan_layers``); a caller that donates ``caches`` must not read them
-    again.
+    Dense and MLA latent arenas ride in the layer scan's carry and are
+    updated in place (``_scan_layers``); a caller that donates ``caches``
+    must not read them again.
 
     Profiler scopes (metadata only): ``embed``; ``layers`` around the layer
     stack, whose own ops are the scan's per-layer slices of the weights (and
     of any cache that is not carried); per layer ``norm``, ``attn.qkv``,
-    ``kv_write``, ``attn.kernel``, ``attn.out``, ``mlp``; then ``lm_head``."""
+    ``kv_write``, ``attn.kernel``, ``attn.out``, then ``mlp`` or the expert
+    layer's ``moe.router``, ``moe.dispatch``, ``moe.experts``,
+    ``moe.shared``; then ``lm_head``."""
     with jax.named_scope("embed"):
         x = embed_inputs(cfg, params["embed"], {"tokens": tokens},
                          rows.lengths[:, None])

@@ -1,11 +1,21 @@
-"""Mixture-of-Experts layer: shared + routed experts, top-k routing, fixed
-capacity with sort-based dispatch (memory-optimal: no (S,E,C) one-hot tensor;
-scatters route tokens into per-expert buffers that XLA SPMD shards over the
-``model`` mesh axis => expert parallelism with compiler-inserted all_to_alls).
+"""Mixture-of-Experts layer: shared + routed experts, softmax top-k routing.
 
 DeepSeek-style fine-grained MoE: ``num_shared`` always-on experts (fused into
 one dense GLU of width num_shared*d_ff_expert) + ``num_experts`` routed,
-``top_k`` active. Aux load-balance loss (switch-style) returned for training.
+``top_k`` active; whether the top-k weights are renormalised is the
+config's (``MoECfg.norm_topk``).
+
+Two dispatches over the same weights:
+
+- ``serve_moe`` (every serving path): dropless and independent of the batch.
+  The router scores all ``num_experts``; this chip computes only the experts
+  it holds (``ModelConfig.experts_held``, expert parallelism), for exactly
+  the (token, expert) pairs routed to them: pairs sorted by expert, one
+  grouped matmul per weight (``lax.ragged_dot``), each held expert's weights
+  read once per call. A token's output does not depend on the other tokens
+  of the call, so chunked prefill, decode and one-shot prefill agree.
+- ``apply_moe`` (training): GShard capacity dispatch with the switch-style
+  load-balance loss; it drops tokens at capacity and needs every expert.
 """
 from __future__ import annotations
 
@@ -17,12 +27,19 @@ from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain
 
 
+def held_experts(cfg: ModelConfig) -> int:
+    """Routed experts whose weights live here (the first of them)."""
+    return cfg.experts_held or cfg.moe.num_experts
+
+
 def moe_defs(cfg: ModelConfig):
     m = cfg.moe
     d, dt = cfg.d_model, cfg.param_dtype
-    E, F = m.num_experts, m.d_ff_expert
+    E, F = held_experts(cfg), m.d_ff_expert
     p = {
-        "router": ParamDef((d, E), jnp.float32, ("embed", None), init="fan_in"),
+        # (experts, d_model), as DeepSeek publishes its gate weight
+        "router": ParamDef((m.num_experts, d), jnp.float32, (None, "embed"),
+                           init="fan_in", fan_in_dim=1),
         "w_gate": ParamDef((E, d, F), dt, ("experts", "embed", "expert_mlp"), init="fan_in"),
         "w_up": ParamDef((E, d, F), dt, ("experts", "embed", "expert_mlp"), init="fan_in"),
         "w_down": ParamDef((E, F, d), dt, ("experts", "expert_mlp", "embed"), init="fan_in"),
@@ -42,6 +59,68 @@ def _glu(x, wg, wu, wd):
     return h @ wd
 
 
+def route(cfg: ModelConfig, p, xf: jax.Array):
+    """Softmax gate over all ``num_experts``: xf (S, D) -> (probs (S, E) f32,
+    top-k weights (S, K) f32, top-k expert ids (S, K) int32). The router's
+    matmul runs at full f32 precision (it is tiny, and a near-tie decides
+    which experts a token reaches)."""
+    m = cfg.moe
+    logits = jnp.matmul(xf.astype(jnp.float32), p["router"].T,
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_w, top_e = jax.lax.top_k(probs, m.top_k)
+    if m.norm_topk:
+        top_w = top_w / jnp.maximum(jnp.sum(top_w, -1, keepdims=True), 1e-9)
+    return probs, top_w, top_e
+
+
+def serve_moe(cfg: ModelConfig, p, x: jax.Array) -> jax.Array:
+    """Dropless expert layer for serving. x: (B, T, D) -> (B, T, D).
+
+    ``p`` holds routed experts ``0 .. n - 1`` (n = the leading axis of its
+    expert weights) and the shared experts; the output is this share's part
+    of the routed sum plus the shared experts. What experts held
+    elsewhere add is left out: summed over every share, with the shared
+    experts counted once, the parts give the whole layer.
+
+    Profiler scopes: ``moe.router``, ``moe.dispatch`` (sort, gather and
+    un-sort), ``moe.experts`` (the grouped matmuls), ``moe.shared``."""
+    m = cfg.moe
+    B, T, D = x.shape
+    S, K = B * T, m.top_k
+    n = p["w_gate"].shape[0]
+    xf = x.reshape(S, D)
+    with jax.named_scope("moe.router"):
+        _, top_w, top_e = route(cfg, p, xf)
+    with jax.named_scope("moe.dispatch"):
+        expert = top_e.reshape(-1)                        # (S*K,) pair -> expert
+        here = expert < n
+        key = jnp.where(here, expert, n)                  # pairs held elsewhere last
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.sum(key[None, :] == jnp.arange(n, dtype=key.dtype)[:, None],
+                        axis=1, dtype=jnp.int32)
+        xs = jnp.take(xf, order // K, axis=0)             # (S*K, D), by expert
+    with jax.named_scope("moe.experts"):
+        f32 = jnp.float32
+        g = jax.lax.ragged_dot(xs, p["w_gate"], sizes, preferred_element_type=f32)
+        u = jax.lax.ragged_dot(xs, p["w_up"], sizes, preferred_element_type=f32)
+        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        y = jax.lax.ragged_dot(h, p["w_down"], sizes, preferred_element_type=f32)
+    with jax.named_scope("moe.dispatch"):
+        # rows past the held pairs belong to no group: select, never
+        # multiply, so whatever they hold cannot reach the sum
+        y = jnp.where(jnp.take(here, order)[:, None], y, 0.0)
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(S * K, dtype=order.dtype))
+        y = jnp.take(y, back, axis=0).reshape(S, K, D)    # un-sort: (token, choice)
+        out = jnp.sum(y * top_w[:, :, None], axis=1)
+    if m.num_shared > 0:
+        with jax.named_scope("moe.shared"):
+            sp = p["shared"]
+            out = out + _glu(xf, sp["w_gate"], sp["w_up"], sp["w_down"]).astype(f32)
+    return out.astype(x.dtype).reshape(B, T, D)
+
+
 GROUP = 1024  # tokens per dispatch group (GShard-style); groups ride the batch sharding
 
 
@@ -58,6 +137,9 @@ def apply_moe(cfg: ModelConfig, p, x: jax.Array) -> tuple[jax.Array, jax.Array]:
     B, T, D = x.shape
     S = B * T
     E, K = m.num_experts, m.top_k
+    if held_experts(cfg) != E:
+        raise ValueError("the training dispatch needs every routed expert; "
+                         f"this config holds {held_experts(cfg)} of {E}")
 
     g = min(GROUP, S)
     pad = (-S) % g
@@ -70,10 +152,8 @@ def apply_moe(cfg: ModelConfig, p, x: jax.Array) -> tuple[jax.Array, jax.Array]:
     C = max(int(m.capacity_factor * g * K / E), 4)
     C = min(C, g)
 
-    logits = xg.astype(jnp.float32) @ p["router"]          # (G, g, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(probs, K)                 # (G, g, K)
-    top_w = top_w / jnp.maximum(jnp.sum(top_w, -1, keepdims=True), 1e-9)
+    probs, top_w, top_e = route(cfg, p, xg.reshape(-1, D))
+    probs, top_w, top_e = (a.reshape(G, g, -1) for a in (probs, top_w, top_e))
 
     # switch-style load-balance aux loss
     oh_all = jax.nn.one_hot(top_e, E, dtype=jnp.float32)   # (G, g, K, E)

@@ -59,15 +59,21 @@ def stacked_block_defs(cfg: ModelConfig):
 # -------------------------------------------------------------------- train
 
 
-def _apply_mlp_part(cfg: ModelConfig, mlp: str, p, x):
+def _apply_mlp_part(cfg: ModelConfig, mlp: str, p, x, serve: bool = True):
+    """The MLP sublayer. Serving runs the dropless expert layer (its own
+    ``moe.*`` scopes); training (``serve=False``) the capacity dispatch with
+    its load-balance loss."""
     if mlp == "none":
         return x, jnp.zeros((), jnp.float32)
     with jax.named_scope("norm"):
         h = apply_norm(cfg, p["norm2"], x)
-    with jax.named_scope("mlp"):
-        if mlp == "moe":
+    if mlp == "moe":
+        if serve:
+            return x + moe_lib.serve_moe(cfg, p["mlp"], h), jnp.zeros((), jnp.float32)
+        with jax.named_scope("mlp"):
             y, aux = moe_lib.apply_moe(cfg, p["mlp"], h)
-            return x + y, aux
+        return x + y, aux
+    with jax.named_scope("mlp"):
         return x + apply_mlp(cfg, p["mlp"], h), jnp.zeros((), jnp.float32)
 
 
@@ -90,7 +96,7 @@ def layer_train(cfg: ModelConfig, kind: tuple[str, str], p, x: jax.Array,
     elif mixer == "slstm":
         y, _ = xlstm_lib.slstm_forward(cfg, p["mixer"], h)
         x = x + y
-    return _apply_mlp_part(cfg, mlp, p, x)
+    return _apply_mlp_part(cfg, mlp, p, x, serve=False)
 
 
 # ------------------------------------------------------------------ serving
@@ -151,7 +157,8 @@ def layer_decode_rows(cfg: ModelConfig, rt: AttentionRuntime, kind: tuple[str, s
         y, cache = xlstm_lib.mlstm_decode(cfg, p["mixer"], h, cache)
     elif mixer == "slstm":
         y, cache = xlstm_lib.slstm_decode(cfg, p["mixer"], h, cache)
-    with jax.named_scope("attn.out") if mixer == "attn" else contextlib.nullcontext():
+    with (jax.named_scope("attn.out") if mixer in ("attn", "mla")
+          else contextlib.nullcontext()):
         x_t = x_t + y
     x_t, _ = _apply_mlp_part(cfg, mlp, p, x_t)
     return x_t, cache
@@ -180,7 +187,8 @@ def layer_prefill_chunk(cfg: ModelConfig, rt: AttentionRuntime, tier: int,
     else:
         raise ValueError(f"chunked prefill has no {mixer!r} path "
                          "(engine falls back to one-shot admission)")
-    with jax.named_scope("attn.out") if mixer == "attn" else contextlib.nullcontext():
+    with (jax.named_scope("attn.out") if mixer in ("attn", "mla")
+          else contextlib.nullcontext()):
         x = x + y
     x, _ = _apply_mlp_part(cfg, mlp, p, x)
     return x, cache

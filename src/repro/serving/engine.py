@@ -342,14 +342,11 @@ class ContinuousServeEngine:
                                   donate=self.donates)
         self._prefills: dict[str, object] = {}   # one-shot oracle path only
         self._chunk_fns: dict[tuple[int, bool], object] = {}
-        # two layer families keep the exact one-shot admission: recurrent
-        # mixers integrate every token into O(1) state that cannot be cut at
-        # page boundaries, and capacity-factor MoE routing makes prefill a
-        # function of the token GROUP (chunking the group changes the drop
-        # pattern). Everything else streams chunks into the arena.
-        self._group_routed = any(mlp == "moe" for _, mlp in cfg.layer_kinds)
-        self.chunked = (bool(serving.prefill_chunk) and not self._exact_prefill
-                        and not self._group_routed)
+        # recurrent mixers keep the exact one-shot admission: they integrate
+        # every token into O(1) state that cannot be cut at page boundaries.
+        # Everything else streams chunks into the arena (the serving expert
+        # layer is dropless and batch-independent, so MoE stacks chunk too).
+        self.chunked = bool(serving.prefill_chunk) and not self._exact_prefill
         # prefix sharing + copy-on-write: chunked admissions only (the tail
         # streams from a mid-context offset), and only for modes whose BASE
         # arena payload is purely positional — dense, decomposed (T1), MLA
@@ -1427,8 +1424,8 @@ class ContinuousServeEngine:
 
     def _latent_gather_bytes_per_step(self, caches) -> float:
         """Interconnect bytes ONE model invocation moves re-assembling the
-        storage-sharded latent pools (PagedXCache.x all-gather inside the
-        shard_map, serving/sharded.py): each device ships its feature shard
+        storage-sharded latent pools (the PagedXCache.x / PagedLatentCache.c
+        all-gather inside the shard_map, serving/sharded.py): each device ships its feature shard
         to the mp-1 others, per latent cache layer. Zero when unsharded.
         This dwarfs the per-head output concat — the price of latent
         HBM-capacity sharding paid on every step (gathering only mapped
@@ -1438,8 +1435,10 @@ class ContinuousServeEngine:
             return 0.0
         total = 0
         for c in caches["prefix"] + caches["blocks"]:
-            if isinstance(c, pgc.PagedXCache) and c.x.shape[-1] % mp == 0:
-                total += c.x.size * c.x.dtype.itemsize  # stacked axis included
+            x = (c.x if isinstance(c, pgc.PagedXCache)
+                 else c.c if isinstance(c, pgc.PagedLatentCache) else None)
+            if x is not None and x.shape[-1] % mp == 0:
+                total += x.size * x.dtype.itemsize  # stacked axis included
         return total * (mp - 1) / mp
 
     def _per_device_arena_bytes(self, caches, total_bytes: int) -> float:

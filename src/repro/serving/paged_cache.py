@@ -28,10 +28,14 @@ Layout invariants (shared by every paged container):
     paged flash kernels DMA pages from directly. Inside the layer scan the
     stack rides in the carry and is read and written in place at the layer
     index (``PagedDenseKVCache.layer``); no per-layer slice of it exists.
+  * The MLA latent arena (``PagedLatentCache``) follows the same pattern:
+    latent pages ``(L, P, page, Dc)`` and the shared roped key packed to
+    whole 128-lane rows, stacked, carried and written in place.
 
 Mode -> paged container (mirrors core/kv_cache.py):
   dense      PagedDenseKVCache   K,V pages, lane-dense (heads merged)
   decomposed PagedXCache         X pages (+ roped key pages)     (T1)
+             PagedLatentCache    MLA latent + shared roped key pages
   cpq        PagedCPQKVCache     CPQ code/level pages, slot stats (T2)
   retrieval  PagedRetrievalCache K,V,proxy pages, slot calibration (T3)
   cpq+decomp PagedCPQXCache      CPQ(X) pages (+ roped key pages) (T1+T2)
@@ -316,6 +320,9 @@ def permute_pool(cache: "PagedCache", perm: jax.Array) -> "PagedCache":
     if isinstance(cache, PagedXCache):
         return PagedXCache(x=jnp.take(cache.x, perm, axis=0),
                            k_rope=jnp.take(cache.k_rope, perm, axis=0))
+    if isinstance(cache, PagedLatentCache):
+        return cache._replace(c=jnp.take(cache.c, perm, axis=0),
+                              rope=jnp.take(cache.rope, perm, axis=0))
     if isinstance(cache, PagedCPQKVCache):
         return PagedCPQKVCache(k=pcpq(cache.k), v=pcpq(cache.v))
     if isinstance(cache, PagedRetrievalCache):
@@ -346,6 +353,8 @@ def copy_page(cache: "PagedCache", src: jax.Array, dst: jax.Array) -> "PagedCach
         return PagedDenseKVCache(k=cp(cache.k), v=cp(cache.v))
     if isinstance(cache, PagedXCache):
         return PagedXCache(x=cp(cache.x), k_rope=cp(cache.k_rope))
+    if isinstance(cache, PagedLatentCache):
+        return cache._replace(c=cp(cache.c), rope=cp(cache.rope))
     raise TypeError(f"copy-on-write is undefined for {type(cache).__name__}")
 
 
@@ -366,6 +375,21 @@ class PagedDenseKVCache(NamedTuple):
 class PagedXCache(NamedTuple):
     x: jax.Array       # (P, page, Dm)
     k_rope: jax.Array  # (P, page, KV, R)
+
+
+class PagedLatentCache(NamedTuple):
+    """The MLA latent arena, lane-dense. ``c``: the normed latent,
+    (P, page, Dc). ``rope``: the one roped key all heads share, ``f``
+    tokens to a row so that a row fills 128 lanes (``rope_pack``):
+    (P, page // f, f * R), token t of a page at row ``t % (page // f)``,
+    lanes ``[(t // (page // f)) * R, ...+R)``. The scanned layers' pools are
+    stacked (L, P, ...); ``layer`` is set only inside the layer scan, where
+    the container carries the whole stack and the index of the layer being
+    run (``models/model.py``), as ``PagedDenseKVCache`` does."""
+
+    c: jax.Array
+    rope: jax.Array
+    layer: Optional[jax.Array] = None
 
 
 class PagedCPQTensor(NamedTuple):
@@ -405,8 +429,9 @@ class TieredPagedCache(NamedTuple):
     cpq: PagedCPQKVCache
 
 
-PagedCache = (PagedDenseKVCache | PagedXCache | PagedCPQKVCache
-              | PagedRetrievalCache | PagedCPQXCache | TieredPagedCache)
+PagedCache = (PagedDenseKVCache | PagedXCache | PagedLatentCache
+              | PagedCPQKVCache | PagedRetrievalCache | PagedCPQXCache
+              | TieredPagedCache)
 
 
 # ------------------------------------------------------------- constructors
@@ -425,6 +450,21 @@ def init_paged_x(num_pages: int, page_size: int, dm: int, kv: int, rope_dims: in
     return PagedXCache(
         x=jnp.zeros((num_pages, page_size, dm), dtype),
         k_rope=jnp.zeros((num_pages, page_size, kv, rope_dims), dtype))
+
+
+def rope_pack(page_size: int, rope_dims: int) -> int:
+    """Tokens per row of a latent arena's rope pool: as many as fill 128
+    lanes, where they divide the page; else 1."""
+    f = 128 // rope_dims if 0 < rope_dims <= 128 and 128 % rope_dims == 0 else 1
+    return f if page_size % f == 0 else 1
+
+
+def init_paged_latent(num_pages: int, page_size: int, dc: int, rope_dims: int,
+                      dtype=jnp.bfloat16) -> PagedLatentCache:
+    f = rope_pack(page_size, rope_dims)
+    return PagedLatentCache(
+        c=jnp.zeros((num_pages, page_size, dc), dtype),
+        rope=jnp.zeros((num_pages, page_size // f, f * rope_dims), dtype))
 
 
 def _init_paged_cpq_tensor(num_pages: int, page_size: int, num_slots: int,
@@ -502,6 +542,93 @@ def _one_layer(pool: jax.Array) -> jax.Array:
     return pool.reshape((1,) + pool.shape[:2] + (-1,))
 
 
+def latent_stack(cache: PagedLatentCache):
+    """(c, rope, layer) that the latent read and write paths index: the
+    carried stack and its layer inside the layer scan, else a single
+    layer's pools read as a stack of one, at layer 0."""
+    if cache.layer is None:
+        return cache.c[None], cache.rope[None], jnp.zeros((), jnp.int32)
+    return cache.c, cache.rope, cache.layer
+
+
+def _latent_like(cache: PagedLatentCache, c: jax.Array,
+                 rope: jax.Array) -> PagedLatentCache:
+    """Put stacked pools back in the form ``cache`` came in."""
+    if cache.layer is None:
+        c, rope = c[0], rope[0]
+    return cache._replace(c=c, rope=rope)
+
+
+@_kv_write
+def _write_rope(pool: jax.Array, page_idx: jax.Array, off: jax.Array,
+                val: jax.Array, layer: Optional[jax.Array] = None) -> jax.Array:
+    """Write roped keys val (N, R) at (page_idx, off) (N,) into a packed
+    rope pool (P, rows, f*R), or a stacked one at ``layer``. Whole 128-lane
+    rows are written, as the dense K/V writes are: each token's row is read,
+    and every segment that a token of this call lands in is replaced, so
+    tokens that share a row write the same row."""
+    N, R = val.shape
+    rows = pool.shape[-2]
+    f = pool.shape[-1] // R
+    row, seg = off % rows, off // rows
+    at = (page_idx, row) if layer is None else (layer, page_idx, row)
+    old = pool[at]                                          # (N, f*R)
+    same = (page_idx[:, None] == page_idx[None]) & (row[:, None] == row[None])
+    parts = []
+    for j in range(f):
+        m = same & (seg[None, :] == j)      # token k fills segment j of i's row
+        src = jnp.take(val, jnp.argmax(m, axis=1), axis=0).astype(pool.dtype)
+        parts.append(jnp.where(m.any(1)[:, None], src, old[:, j * R:(j + 1) * R]))
+    return pool.at[at].set(jnp.concatenate(parts, axis=-1))
+
+
+def append_latent(cache: PagedLatentCache, rows: RowState, c_t: jax.Array,
+                  rope_t: jax.Array) -> PagedLatentCache:
+    """Write one token per row (c_t (B, 1, Dc), rope_t (B, 1, 1, R)) at
+    ``rows.lengths``; inactive rows write the null page."""
+    c, rope, layer = latent_stack(cache)
+    page = c.shape[2]
+    c = write_token_pages(c, rows.block_table, rows.lengths, rows.active,
+                          c_t[:, 0], layer)
+    blk = jnp.clip(rows.lengths // page, 0, rows.block_table.shape[1] - 1)
+    pidx = jnp.take_along_axis(rows.block_table, blk[:, None], axis=1)[:, 0]
+    pidx = jnp.where(rows.active, pidx, NULL_PAGE)
+    rope = _write_rope(rope, pidx, rows.lengths % page, rope_t[:, 0, 0], layer)
+    return _latent_like(cache, c, rope)
+
+
+def write_latent_chunk(cache: PagedLatentCache, block_row: jax.Array,
+                       offset: jax.Array, valid: jax.Array, c_c: jax.Array,
+                       rope_c: jax.Array) -> PagedLatentCache:
+    """Write one prompt chunk (c_c (C, Dc), rope_c (C, R)) into one slot's
+    pages at positions ``offset ..``; padding and unmapped positions land on
+    the null page (``write_chunk_pages``)."""
+    c, rope, layer = latent_stack(cache)
+    page = c.shape[2]
+    c = write_chunk_pages(c, block_row, offset, valid, c_c, layer)
+    idx = jnp.arange(c_c.shape[0], dtype=jnp.int32)
+    pos = offset + idx
+    blk = pos // page
+    ok = (idx < valid) & (blk < block_row.shape[0])
+    pidx = jnp.where(ok, block_row[jnp.clip(blk, 0, block_row.shape[0] - 1)],
+                     NULL_PAGE)
+    rope = _write_rope(rope, pidx, pos % page, rope_c, layer)
+    return _latent_like(cache, c, rope)
+
+
+def gather_latent(cache: PagedLatentCache, block_table: jax.Array):
+    """Logical views of a latent arena's layer (the gather oracle of the
+    paged MLA kernels): (B, max_blocks) -> c (B, N, Dc), rope (B, N, 1, R)."""
+    c, rope, layer = latent_stack(cache)
+    g = c[layer, block_table]                     # (B, nb, page, Dc)
+    B, nb, page, dc = g.shape
+    r = rope[layer, block_table]                  # (B, nb, rows, f*R)
+    rows = r.shape[2]
+    f = page // rows
+    r = r.reshape(B, nb, rows, f, -1).transpose(0, 1, 3, 2, 4)
+    return g.reshape(B, nb * page, dc), r.reshape(B, nb * page, 1, -1)
+
+
 def logical_cpq(t: PagedCPQTensor, block_table: jax.Array) -> cpq_lib.CPQTensor:
     """Contiguous CPQTensor view of a paged CPQ arena (codes gathered through
     the block table; per-slot stats already contiguous). The chunked decode
@@ -561,6 +688,15 @@ def pack_x(cache: PagedXCache, src: kvc.XCache, block_row: jax.Array) -> PagedXC
         k_rope=write_prompt_pages(cache.k_rope, block_row, src.k_rope[0]))
 
 
+def pack_latent(cache: PagedLatentCache, src: kvc.XCache,
+                block_row: jax.Array) -> PagedLatentCache:
+    """Scatter a one-shot prefilled B=1 latent cache into one slot's pages."""
+    S = src.x.shape[1]
+    return write_latent_chunk(cache, block_row, jnp.zeros((), jnp.int32),
+                              jnp.asarray(S, jnp.int32), src.x[0],
+                              src.k_rope[0, :, 0])
+
+
 def pack_cpq_tensor(t: PagedCPQTensor, src: cpq_lib.CPQTensor, block_row: jax.Array,
                     slot: jax.Array) -> PagedCPQTensor:
     return PagedCPQTensor(
@@ -606,6 +742,8 @@ def pack_into(rt_mode: str, cache, src, block_row: jax.Array, slot: jax.Array):
         return pack_dense(cache, src, block_row)
     if isinstance(cache, PagedXCache):
         return pack_x(cache, src, block_row)
+    if isinstance(cache, PagedLatentCache):
+        return pack_latent(cache, src, block_row)
     if isinstance(cache, PagedCPQKVCache):
         return pack_cpq(cache, src, block_row, slot)
     if isinstance(cache, PagedRetrievalCache):
@@ -923,6 +1061,10 @@ def bytes_per_token(cache: PagedCache, page_size: int,
         payload = (cache.x.shape[2] * cache.x.dtype.itemsize
                    + cache.k_rope.shape[2] * cache.k_rope.shape[3]
                    * cache.k_rope.dtype.itemsize)
+    elif isinstance(cache, PagedLatentCache):
+        page = cache.c.shape[-2]
+        payload = (cache.c.shape[-1] + cache.rope.shape[-2]
+                   * cache.rope.shape[-1] / page) * cache.c.dtype.itemsize
     elif isinstance(cache, PagedCPQKVCache):
         cfg = cpq_cfg or CPQCfg()
         payload = 2.0 * cpq_lib.cpq_bytes_per_token(

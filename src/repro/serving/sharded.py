@@ -93,6 +93,11 @@ _ARG_SPECS = {
     "q_rope": P(None, None, MODEL_AXIS, None),
     "w_k_nope": P(None, MODEL_AXIS, None),
     "w_v": P(None, MODEL_AXIS, None),
+    # the MLA latent arena's operands
+    "c": P(None, None, MODEL_AXIS),
+    "k_rope": P(),
+    "w_uk": P(MODEL_AXIS, None, None),
+    "w_uv": P(MODEL_AXIS, None, None),
 }
 
 
@@ -125,9 +130,9 @@ def container_specs(cache, mesh):
                         is_leaf=lambda x: isinstance(x, P))
 
 
-def _x_is_sharded(cspec) -> bool:
-    """Whether the latent pool's feature axis actually sharded (fit kept it)."""
-    return tuple(cspec.x) and tuple(cspec.x)[-1] is not None
+def _feature_sharded(spec) -> bool:
+    """Whether a latent pool's feature axis actually sharded (fit kept it)."""
+    return bool(tuple(spec)) and tuple(spec)[-1] is not None
 
 
 def _gather_latent(x_local: jax.Array) -> jax.Array:
@@ -170,7 +175,7 @@ def decode_attend_sharded(
     cspecs = container_specs(cache, mesh)
     rspecs = jax.tree.map(lambda _: P(), rows)
     latent = isinstance(cache, pgc.PagedXCache)
-    gather_x = latent and _x_is_sharded(cspecs)
+    gather_x = latent and _feature_sharded(cspecs.x)
     kw = dict(q=q, k_t=k_t, v_t=v_t, x_t=x_t, k_rope_t=k_rope_t,
               q_nope=q_nope, q_rope=q_rope, w_k_nope=w_k_nope, w_v=w_v)
     present, pspecs = _split(kw, mesh)
@@ -229,7 +234,7 @@ def chunk_attend_sharded(
     rt_local = dataclasses.replace(rt, mesh=None)
     cspecs = container_specs(cache, mesh)
     latent = isinstance(cache, pgc.PagedXCache)
-    gather_x = latent and _x_is_sharded(cspecs)
+    gather_x = latent and _feature_sharded(cspecs.x)
     kw = dict(q=q, k_c=k_c, v_c=v_c, x_c=x_c, k_rope_c=k_rope_c,
               q_nope=q_nope, q_rope=q_rope, w_k_nope=w_k_nope, w_v=w_v)
     present, pspecs = _split(kw, mesh)
@@ -272,6 +277,83 @@ def chunk_attend_sharded(
     out, cache = _shard_map(
         body, mesh,
         in_specs=(cspecs, sspecs, pspecs),
+        out_specs=(P(None, None, MODEL_AXIS, None), cspecs),
+    )(cache, scalars, present)
+    return _gather_heads(mesh, out), cache
+
+
+def _latent_layer(cache, gather: bool):
+    """The layer a latent attend reads, as a stack of one (layer 0): its
+    local latent pages, all-gathered to every feature when they are
+    storage-sharded, and its (replicated) rope pages."""
+    from repro.serving import paged_cache as pgc
+
+    c, rope, layer = pgc.latent_stack(cache)
+    c = jax.lax.dynamic_index_in_dim(c, layer, 0)
+    rope = jax.lax.dynamic_index_in_dim(rope, layer, 0)
+    return pgc.PagedLatentCache(_gather_latent(c) if gather else c, rope,
+                                jnp.zeros((), jnp.int32))
+
+
+def latent_decode_sharded(rt, cache, rows, *, c_t, k_rope_t, q_nope, q_rope,
+                          w_uk, w_uv, scale: float):
+    """shard_map'd MLA decode over a ``PagedLatentCache``: each device
+    writes its feature shard of the new latent, reassembles the layer's
+    latent pages and attends its local query heads. Returns (out
+    (B,1,H,Dv) replicated, new_cache)."""
+    from repro.models.mla import latent_decode_read
+    from repro.serving import paged_cache as pgc
+
+    mesh = rt.mesh
+    rt_local = dataclasses.replace(rt, mesh=None)
+    cspecs = container_specs(cache, mesh)
+    gather = _feature_sharded(cspecs.c)
+    present, pspecs = _split(dict(c=c_t, k_rope=k_rope_t, q_nope=q_nope,
+                                  q_rope=q_rope, w_uk=w_uk, w_uv=w_uv), mesh)
+
+    def body(cache, rows, a):
+        cache = pgc.append_latent(cache, rows, a["c"], a["k_rope"])
+        out = latent_decode_read(
+            rt_local, _latent_layer(cache, gather), rows.block_table,
+            rows.lengths + rows.active.astype(jnp.int32), a["q_nope"],
+            a["q_rope"], a["w_uk"], a["w_uv"], scale)
+        return out, cache
+
+    out, cache = _shard_map(
+        body, mesh, in_specs=(cspecs, jax.tree.map(lambda _: P(), rows), pspecs),
+        out_specs=(P(None, None, MODEL_AXIS, None), cspecs),
+    )(cache, rows, present)
+    return _gather_heads(mesh, out), cache
+
+
+def latent_chunk_sharded(rt, cache, *, block_row, offset, valid, c, k_rope,
+                         q_nope, q_rope, w_uk, w_uv, scale: float):
+    """shard_map'd chunked MLA prefill over a ``PagedLatentCache`` (prompt
+    and speculative verify chunks alike). Returns (out (1,C,H,Dv)
+    replicated, new_cache)."""
+    from repro.models.mla import latent_chunk_read
+    from repro.serving import paged_cache as pgc
+
+    mesh = rt.mesh
+    rt_local = dataclasses.replace(rt, mesh=None)
+    cspecs = container_specs(cache, mesh)
+    gather = _feature_sharded(cspecs.c)
+    present, pspecs = _split(dict(c=c, k_rope=k_rope, q_nope=q_nope,
+                                  q_rope=q_rope, w_uk=w_uk, w_uv=w_uv), mesh)
+    scalars = (block_row, offset, valid)
+
+    def body(cache, scalars, a):
+        block_row, offset, valid = scalars
+        cache = pgc.write_latent_chunk(cache, block_row, offset, valid,
+                                       a["c"][0], a["k_rope"][0, :, 0])
+        out = latent_chunk_read(rt_local, _latent_layer(cache, gather),
+                                block_row, offset, valid, a["q_nope"],
+                                a["q_rope"], a["w_uk"], a["w_uv"], scale)
+        return out, cache
+
+    out, cache = _shard_map(
+        body, mesh,
+        in_specs=(cspecs, jax.tree.map(lambda _: P(), scalars), pspecs),
         out_specs=(P(None, None, MODEL_AXIS, None), cspecs),
     )(cache, scalars, present)
     return _gather_heads(mesh, out), cache

@@ -398,6 +398,114 @@ def test_paged_decomposed_prefill_vs_ref(seed, page, nb, C, H, kv_r, Rr,
                                np.asarray(ref)[:valid], atol=2e-4)
 
 
+# ------------------------------------------------------- MLA latent arena
+
+
+def _latent_arena(rng, num_pages, page, Dc, R):
+    """A stacked latent arena (LAYERS, ...) in the packed rope layout of
+    ``PagedLatentCache``, every layer different and the null page poisoned,
+    and the same rope pages unpacked to (LAYERS, P, page, 1, R) for the
+    oracle."""
+    from repro.serving.paged_cache import rope_pack
+
+    f = rope_pack(page, R)
+    c = rng.normal(size=(LAYERS, num_pages, page, Dc)).astype(np.float32)
+    kr = rng.normal(size=(LAYERS, num_pages, page, 1, R)).astype(np.float32)
+    c[:, 0] = kr[:, 0] = 1e3
+    packed = kr[:, :, :, 0].reshape(LAYERS, num_pages, f, page // f, R)
+    packed = packed.transpose(0, 1, 3, 2, 4).reshape(LAYERS, num_pages,
+                                                      page // f, f * R)
+    return c, packed, kr
+
+
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
+@pytest.mark.parametrize("seed,page,nb,B,H,Dc,R,dtype", [
+    (0, 16, 3, 2, 4, 32, 64, jnp.float32),    # the published rope: 2 per row
+    (1, 16, 2, 3, 4, 16, 16, jnp.float32),    # 8 tokens per row
+    (2, 4, 4, 2, 2, 16, 8, jnp.float32),      # page too short to pack: 1
+    (3, 16, 3, 2, 4, 32, 64, jnp.bfloat16),   # the served dtype
+    (4, 16, 32, 2, 4, 32, 64, jnp.float32),   # 16 pages a grid step
+])
+def test_paged_latent_decode_vs_ref(seed, page, nb, B, H, Dc, R, dtype, layer):
+    from repro.kernels.decomposed_attn.kernel import paged_latent_decode_fwd
+    from repro.kernels.decomposed_attn.ref import paged_decomposed_decode_ref
+
+    rng = np.random.default_rng(seed)
+    num_pages, lengths, bt = _pool_layout(rng, B, nb, page)
+    c, packed, kr = _latent_arena(rng, num_pages, page, Dc, R)
+    r = jnp.asarray(rng.normal(size=(B, H, Dc)), dtype)
+    qr = jnp.asarray(rng.normal(size=(B, H, R)), dtype)
+    out = paged_latent_decode_fwd(
+        r, qr, jnp.asarray(c, dtype), jnp.asarray(packed, dtype),
+        jnp.asarray(bt), jnp.asarray(lengths), jnp.asarray(layer, jnp.int32),
+        scale=0.2, interpret=True)
+    ref = paged_decomposed_decode_ref(
+        r, qr, jnp.asarray(c[layer], dtype), jnp.asarray(kr[layer], dtype),
+        jnp.asarray(bt), jnp.asarray(lengths), 0.2)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=tol)
+
+
+@pytest.mark.parametrize("seed,page,nb,C,H,R,offset,valid", [
+    (0, 16, 4, 16, 4, 64, 0, 16),    # first chunk, two tokens per rope row
+    (1, 16, 4, 16, 2, 64, 16, 11),   # continuation chunk, jit padding
+    (2, 4, 6, 8, 4, 8, 8, 6),        # unpacked rope
+    (3, 16, 32, 16, 2, 64, 272, 13),  # 16 pages a grid step, 18 of them live
+])
+def test_paged_latent_prefill_vs_ref(seed, page, nb, C, H, R, offset, valid):
+    from repro.kernels.decomposed_attn.kernel import paged_latent_prefill_fwd
+    from repro.kernels.decomposed_attn.ref import paged_decomposed_prefill_ref
+
+    rng = np.random.default_rng(seed)
+    num_pages, row = _prefill_layout(rng, page, nb, offset, valid)
+    c, packed, kr = _latent_arena(rng, num_pages, page, 16, R)
+    r = jnp.asarray(rng.normal(size=(C, H, 16)).astype(np.float32))
+    qr = jnp.asarray(rng.normal(size=(C, H, R)).astype(np.float32))
+    layer = LAYERS - 1
+    out = paged_latent_prefill_fwd(
+        r, qr, jnp.asarray(c), jnp.asarray(packed), jnp.asarray(row),
+        jnp.asarray(offset, jnp.int32), jnp.asarray(valid, jnp.int32),
+        jnp.asarray(layer, jnp.int32), scale=0.2, interpret=True)
+    ref = paged_decomposed_prefill_ref(
+        r, qr, jnp.asarray(c[layer]), jnp.asarray(kr[layer]), jnp.asarray(row),
+        jnp.asarray(offset, jnp.int32), jnp.asarray(valid, jnp.int32), 0.2)
+    np.testing.assert_allclose(np.asarray(out)[:valid],
+                               np.asarray(ref)[:valid], atol=2e-4)
+
+
+@pytest.mark.parametrize("page,R,offset,n", [(16, 64, 0, 16), (16, 64, 5, 13),
+                                             (16, 16, 3, 30), (4, 8, 1, 6)])
+def test_latent_writes_land_where_the_gather_reads(page, R, offset, n):
+    """Chunk and decode writes into the packed rope rows: every position
+    written reads back through ``gather_latent``, tokens that share a row
+    (in one chunk) included, and positions not written keep their
+    contents."""
+    from repro.serving import paged_cache as pgc
+
+    rng = np.random.default_rng(page + R + offset)
+    nb, Dc = 4, 8
+    cache = pgc.init_paged_latent(1 + nb, page, Dc, R, jnp.float32)
+    cache = cache._replace(c=cache.c + 7.0, rope=cache.rope + 7.0)
+    row = jnp.asarray(rng.permutation(np.arange(1, 1 + nb)), jnp.int32)
+    cv = rng.normal(size=(n, Dc)).astype(np.float32)
+    rv = rng.normal(size=(n, R)).astype(np.float32)
+    cache = pgc.write_latent_chunk(cache, row, jnp.asarray(offset, jnp.int32),
+                                   jnp.asarray(n - 1, jnp.int32), jnp.asarray(cv),
+                                   jnp.asarray(rv))
+    rows = pgc.RowState(lengths=jnp.asarray([offset + n - 1], jnp.int32),
+                        block_table=row[None], active=jnp.asarray([True]),
+                        tier=jnp.zeros((1,), jnp.int32))
+    cache = pgc.append_latent(cache, rows, jnp.asarray(cv[None, -1:]),
+                              jnp.asarray(rv[None, -1:, None]))
+    cg, rg = pgc.gather_latent(cache, row[None])
+    cg, rg = np.asarray(cg[0]), np.asarray(rg[0, :, 0])
+    np.testing.assert_array_equal(cg[offset:offset + n], cv)
+    np.testing.assert_array_equal(rg[offset:offset + n], rv)
+    np.testing.assert_array_equal(rg[:offset], 7.0)
+    np.testing.assert_array_equal(rg[offset + n:], 7.0)
+
+
 # ------------------------------------------------- interpret mode by platform
 
 

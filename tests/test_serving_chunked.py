@@ -24,11 +24,9 @@ from repro.serving import paged_cache as pgc
 from repro.serving.engine import ContinuousServeEngine, GenerationConfig
 from repro.serving.scheduler import Request
 
-# pure-MLA stack (dense MLPs): the MLA chunked-parity target. The published
-# MLA arch (deepseek-v2-lite) pairs MLA with capacity-factor MoE, whose drop
-# pattern depends on the token GROUP — chunking the group changes routing, so
-# MoE stacks keep one-shot admission (asserted below) and MLA parity is
-# tested on this synthetic stack.
+# pure-MLA stack (dense MLPs): the MLA chunked-parity target without the
+# expert layer (deepseek-v2-lite, MLA with MoE, is covered below and in
+# tests/test_mla_moe.py).
 MLA_DENSE = ModelConfig(
     name="mla-dense-test", family="dense", d_model=32, num_heads=4,
     num_kv_heads=4, head_dim=8, d_ff=64, vocab_size=256,
@@ -158,15 +156,18 @@ def test_chunked_tiered_matches_oneshot_and_escalates():
 
 
 def test_group_routed_and_recurrent_archs_fall_back_to_oneshot():
-    """Capacity-factor MoE routes per token GROUP (chunking changes drops)
-    and recurrent state cannot be cut at page boundaries: both keep the
-    exact one-shot admission even when prefill_chunk is set."""
-    for arch in ("deepseek-v2-lite-16b", "xlstm-125m"):
+    """Recurrent state cannot be cut at page boundaries: those stacks keep
+    the exact one-shot admission even when prefill_chunk is set. MoE stacks
+    no longer route per token GROUP (the serving expert layer is dropless
+    and batch-independent), so they stream chunks, token-exact against
+    one-shot admission at f32."""
+    for arch, chunks in (("deepseek-v2-lite-16b", True), ("xlstm-125m", False)):
         cfg, params = _mk(arch)
         prompts = _prompts(cfg, sizes=(5, 9), seed=4)
         one, _, e0 = _serve(cfg, params, prompts, prefill_chunk=0, max_new=4)
         fb, sfb, e1 = _serve(cfg, params, prompts, prefill_chunk=16, max_new=4)
-        assert not e1.chunked and sfb["prefill_chunks"] == 0
+        assert e1.chunked == chunks
+        assert (sfb["prefill_chunks"] > 0) == chunks
         for i in one:
             np.testing.assert_array_equal(one[i], fb[i])
 

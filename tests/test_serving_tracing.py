@@ -12,7 +12,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.configs import ARCHS, ServingCfg, smoke_config
+from repro.configs import ARCHS, ServingCfg, get_config, smoke_config
 from repro.models import model as M
 from repro.serving.engine import (ContinuousServeEngine, GenerationConfig,
                                   ServeEngine)
@@ -210,3 +210,31 @@ def test_static_engine_steps_keep_their_function_names(engine):
     static = ServeEngine(engine.cfg, engine.params, max_len=64)
     assert (static._prefill.__name__, static._decode.__name__) == (
         "prefill", "decode_step")
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_mla_and_expert_scopes_reach_the_lowered_steps(step):
+    """The smoke DeepSeek-V2-Lite engine's decode and chunk programs carry
+    the attention scopes around MLA and the expert layer's own scopes (the
+    names the trace's ``tf_op`` paths are built from)."""
+    cfg = smoke_config(get_config("deepseek-v2-lite-16b"))
+    serving = ServingCfg(num_slots=2, page_size=8, num_pages=17,
+                         max_blocks_per_slot=8, prefill_chunk=8)
+    eng = ContinuousServeEngine(cfg, M.init_params(cfg, jax.random.PRNGKey(0)),
+                                serving=serving)
+    assert eng.chunked and eng.donates
+    eng.reset()
+    st = eng._st
+    if step == "decode":
+        low = eng._decode.lower(eng.params, jnp.asarray(st.last_tok[:, None]),
+                                eng._row_state(st.sched), st.caches)
+    else:
+        i32 = lambda v: jnp.asarray(v, jnp.int32)      # noqa: E731
+        low = eng._chunk_fn(0, True).lower(
+            eng.params, jnp.zeros((1, 8), jnp.int32), i32(0),
+            jnp.zeros((8,), jnp.int32), i32(0), i32(1), st.caches)
+    text = low.as_text(debug_info=True)
+    for scope in ("attn.qkv", "kv_write", "attn.kernel", "attn.out",
+                  "moe.router", "moe.dispatch", "moe.experts", "moe.shared"):
+        assert re.search(rf'["/]{re.escape(scope)}[/"]', text), scope
+    eng.release()

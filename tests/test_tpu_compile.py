@@ -1,7 +1,9 @@
 """Compile the serving path's paged Pallas kernels, and the engine's
 donated decode and chunk steps, for a TPU v5e that is described, not
 attached, at Qwen1.5-0.5B widths (H = KV = 16, Dh = 64, d_model 1024, page
-16, 8 slots, prompt chunks of 128).
+16, 8 slots, prompt chunks of 128), and the MLA latent kernels and steps at
+DeepSeek-V2-Lite widths (latent 512, rope 64, 16 heads) with the
+``dsv2lite-longdoc`` cell's arena (32 slots x 4096 positions).
 
 Interpret-mode tests check semantics only; Mosaic's block-shape and VMEM
 rules show up only when a kernel is compiled for the chip, and a compile
@@ -22,6 +24,7 @@ import jax.numpy as jnp
 B, H, KV, DH, PAGE, NB, C, DM, R, L = 8, 16, 16, 64, 16, 34, 128, 1024, 32, 4
 P = B * NB + 1  # one slot's worth of pages per row, plus the null page
 LAYERS = 4      # layers of the stacked dense arena (the model's depth here)
+DC, DR, MLA_H = 512, 64, 16   # MLA latent, shared rope key, heads
 
 
 @pytest.fixture(scope="module")
@@ -60,6 +63,8 @@ def _cases(sharding):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     kv_pool = S((LAYERS, P, PAGE, KV * DH), bf)
+    latent = S((LAYERS, P, PAGE, DC), bf)
+    rope = S((LAYERS, P, PAGE // 2, 2 * DR), bf)   # two tokens per 128 lanes
     codes, levels = S((P, PAGE, KV, DH), i8), S((P, PAGE, KV), i32)
     table, lens = S((B, NB), i32), S((B,), i32)
     row, scalar = S((NB,), i32), S((), i32)
@@ -87,12 +92,19 @@ def _cases(sharding):
                                [S((C, H, DM), bf), S((C, H, R), bf),
                                 S((P, PAGE, DM), bf), S((P, PAGE, KV, R), bf),
                                 row, scalar, scalar]),
+        "latent_decode": (dk.paged_latent_decode_fwd,
+                          [S((B, MLA_H, DC), bf), S((B, MLA_H, DR), bf),
+                           latent, rope, table, lens, scalar]),
+        "latent_prefill": (dk.paged_latent_prefill_fwd,
+                           [S((C, MLA_H, DC), bf), S((C, MLA_H, DR), bf),
+                            latent, rope, row, scalar, scalar, scalar]),
     }
 
 
 @pytest.mark.parametrize("name", ["flash_decode", "flash_prefill",
                                   "cpq_decode", "cpq_prefill",
-                                  "decomposed_decode", "decomposed_prefill"])
+                                  "decomposed_decode", "decomposed_prefill",
+                                  "latent_decode", "latent_prefill"])
 def test_paged_kernel_compiles_for_v5e(one_chip, name):
     fwd, args = _cases(one_chip)[name]
     compiled = jax.jit(
@@ -107,7 +119,9 @@ KERNEL_NAMES = {"flash_decode": "paged_flash_decode",
                 "cpq_decode": "paged_cpq_decode",
                 "cpq_prefill": "paged_cpq_prefill",
                 "decomposed_decode": "paged_t1_decode",
-                "decomposed_prefill": "paged_t1_prefill"}
+                "decomposed_prefill": "paged_t1_prefill",
+                "latent_decode": "paged_mla_decode",
+                "latent_prefill": "paged_mla_prefill"}
 
 
 @pytest.mark.parametrize("name", list(KERNEL_NAMES))
@@ -179,15 +193,17 @@ def _compiled_step(eng, sharding, step: str):
         lambda: M.init_params(eng.cfg, jax.random.key(0))))
     caches = jax.tree.map(S, jax.eval_shape(partial(
         M.init_paged_caches, eng.cfg, eng.rt, eng.serving, False)))
+    b, nb = eng.serving.num_slots, eng.serving.max_blocks_per_slot
     if step == "decode":
-        rows = pgc.RowState(lengths=i32(B), block_table=i32(B, ARENA_NB),
-                            active=jax.ShapeDtypeStruct((B,), jnp.bool_,
+        rows = pgc.RowState(lengths=i32(b), block_table=i32(b, nb),
+                            active=jax.ShapeDtypeStruct((b,), jnp.bool_,
                                                         sharding=sharding),
-                            tier=i32(B))
-        fn, args = eng._decode, (params, i32(B, 1), rows, caches)
+                            tier=i32(b))
+        fn, args = eng._decode, (params, i32(b, 1), rows, caches)
     else:
         fn = eng._chunk_fn(0, step == "chunk_first")
-        args = (params, i32(1, C), i32(), i32(ARENA_NB), i32(), i32(), caches)
+        args = (params, i32(1, eng.serving.prefill_chunk), i32(), i32(nb),
+                i32(), i32(), caches)
     return fn.lower(*args).compile(), caches
 
 
@@ -246,3 +262,61 @@ def test_cpq_engine_steps_stay_undonated(one_chip):
     assert not eng.donates
     compiled, _ = _compiled_step(eng, one_chip, "decode")
     assert compiled.memory_analysis().alias_size_in_bytes == 0
+
+
+# ------------------------------------------- the MLA latent arena, donated
+
+MLA_SLOTS, MLA_NB = 32, 256            # the dsv2lite-longdoc cell's arena
+MLA_P = MLA_SLOTS * MLA_NB + 1
+
+
+def _mla_engine():
+    """A continuous engine at DeepSeek-V2-Lite widths, 4 layers deep (the
+    dense first layer and 3 expert layers, 8 of 64 experts held), with the
+    cell's arena; its steps are lowered here and never run."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.base import AttentionRuntime, ServingCfg
+    from repro.serving.engine import ContinuousServeEngine
+
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b"),
+                              num_blocks=LAYERS - 1, experts_held=8)
+    serving = ServingCfg(num_slots=MLA_SLOTS, page_size=PAGE, num_pages=MLA_P,
+                         max_blocks_per_slot=MLA_NB, prefill_chunk=C)
+    return ContinuousServeEngine(cfg, None, AttentionRuntime(mode="decomposed"),
+                                 serving)
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk_first", "chunk_next"])
+def test_mla_donated_steps_update_the_latent_arena_in_place(one_chip, step):
+    """The stacked latent arena rides in the layer scan's carry (and the
+    first layer's pools beside it) and the engine donates it: the compiled
+    step aliases the whole arena, needs far less temporary memory than the
+    arena, and no op copies, slices or allocates a buffer of its page
+    count."""
+    eng = _mla_engine()
+    assert eng.donates and eng.chunked
+    compiled, caches = _compiled_step(eng, one_chip, step)
+    arena = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(caches))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == arena
+    assert mem.temp_size_in_bytes < arena // 2, mem.temp_size_in_bytes
+    assert _arena_moves(compiled.as_text(), MLA_P) == []
+
+
+def test_latent_arena_holds_31104_bytes_a_token(one_chip):
+    """Lane-dense on the chip: the arena's buffers, as the v5e compile lays
+    them out in its tiles, hold (512 + 64) x 2 B per token and layer, 31 104
+    B over V2-Lite's 27 layers, within one page's worth of padding."""
+    from repro.models import model as M
+
+    eng = _mla_engine()
+    caches = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(partial(M.init_paged_caches, eng.cfg, eng.rt,
+                               eng.serving, False)))
+    compiled = jax.jit(lambda c: c, donate_argnums=0).lower(caches).compile()
+    tokens = MLA_P * PAGE
+    per_token = 27 * compiled.memory_analysis().argument_size_in_bytes / LAYERS / tokens
+    assert 31104 <= per_token <= 31104 * (tokens + PAGE) / tokens
