@@ -23,7 +23,10 @@ The ``paged_*`` kernels take ``(pages, block_table, lengths)`` directly: the
 block table is a scalar-prefetch operand, so each grid step's BlockSpec index
 map resolves ``block_table[b, ib]`` and DMAs that PHYSICAL page, every kv
 head of it, from the arena into VMEM — the contiguous logical view the jnp
-gather path materializes never exists. Masking convention (shared with
+gather path materializes never exists. The dense and MLA kernels take k
+consecutive entries a grid step (``decomposed_attn.kernel._pages_per_step``),
+one block operand each, except the dense decode kernel, which DMAs its
+pages itself a live step ahead. Masking convention (shared with
 serving/paged_cache.py): block-table entry 0 is the reserved null page whose
 contents are garbage by design; every position >= lengths[b] — all slots of
 an unmapped/null page and the tail of a partial last page — is masked to

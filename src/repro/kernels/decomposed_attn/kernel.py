@@ -483,8 +483,9 @@ def _rope_blocks(q_rope: jax.Array, f: int) -> jax.Array:
 
 
 def _page_specs(k: int, page: int, dc: int, rows: int, fr: int, at):
-    """k block operands of the latent pool, then k of the rope pool: page
-    ``at(j, *grid_and_prefetch)`` of layer lay[0] for operand j."""
+    """k block operands of the latent (or K) pool, then k of the rope (or
+    V) pool: page ``at(j, *grid_and_prefetch)`` of layer lay[0] for operand
+    j."""
     def spec(shape, j):
         return pl.BlockSpec(shape, lambda *a, j=j: (a[-1][0], at(j, *a), 0, 0))
     return ([spec((1, 1, page, dc), j) for j in range(k)]

@@ -9,6 +9,20 @@ lane axis is ideal; 64 also maps cleanly on v5e).
 Causal blocks that are fully masked are skipped with pl.when (no MXU work).
 GQA: the kv-head index for query head h is h // (H // KV), computed in the
 BlockSpec index_map so K/V tiles are fetched per kv head.
+
+Paged kernels (``paged_flash_decode`` / ``paged_flash_prefill``) read one
+layer of a stacked lane-dense arena (L, P, page, KV*Dh) through a
+scalar-prefetched block table. A grid step takes k pages
+(``_pages_per_step``: the most of 16, 8, 4, 2, 1 dividing the table's
+width) as one (k*page, KV*Dh) tile in VMEM. Decode DMAs them itself, a
+live step ahead into a double buffer, and skips steps past a row's length
+outright; it scores every head in one NT matmul against a block-diagonal
+(H, KV*Dh) query (row h holds head h in the lanes of kv head h // g) and
+weights V in one (H, k*page) x (k*page, KV*Dv) matmul, whatever the group
+size g. Prefill takes each page as its own block operand, stacked in VMEM,
+and loops over kv heads on the k-page-wide tile. q, K and V reach the MXU
+in the arena's dtype; scores, softmax state, p and the accumulator are
+f32.
 """
 from __future__ import annotations
 
@@ -19,6 +33,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.decomposed_attn.kernel import (_nt, _online_update,
+                                                  _page_specs, _pages_per_step)
 
 NEG_INF = -1e30
 
@@ -84,22 +101,63 @@ def online_update(s, v, m_sc, l_sc, acc_sc, h):
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
-                         o_ref, m_sc, l_sc, acc_sc, *, scale: float,
-                         page_size: int, nb: int, kv_heads: int, head_dim: int,
-                         v_dim: int):
-    """One (b, ib) step: the K/V tile IS physical page bt[b, ib] of layer
-    ``layer`` in the stacked lane-dense arena (block (1, page, KV*Dh): the
-    last two block dims span whole array dims, which Mosaic's (8, 128)
-    tiling accepts at any head count) — the BlockSpec index map resolved the
-    layer and the block table before the body ran, so the page was DMA'd
-    straight from the arena into VMEM. Head h is lanes [h*Dh, (h+1)*Dh).
+def _stacked(refs):
+    """The k page blocks of one grid step stacked in VMEM into one
+    (k*page, lanes) tile: row i is position ``ib*k*page + i`` of the row."""
+    return jnp.concatenate([r[0, 0] for r in refs], axis=0)
 
-    One sweep serves both attention matmuls of every head per page (scores
-    AND weighted-V accumulate while the page sits in VMEM); softmax state is
-    carried online in f32 scratch across the block-table sweep."""
+
+def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, k_buf, v_buf, sem, done, m_sc, l_sc, acc_sc,
+                         *, scale: float, page: int, nb: int, k: int,
+                         group: int, v_dim: int):
+    """One (b, ib) step: pages bt[b, ib*k .. ib*k+k-1] of layer ``layer``
+    of the stacked lane-dense arena, DMA'd by the kernel itself from the
+    arena (left in HBM) into one (k*page, KV*Dh) tile of a double buffer,
+    page j at rows [j*page, (j+1)*page).
+
+    Only live steps (first position < the row's length) fetch and compute,
+    in grid order, so a step past the row's end costs nothing. Each live
+    step starts the DMAs of the next live step, (b, ib+1) or else (b+1, 0),
+    into the other buffer before it waits on its own; ``done`` counts the
+    live steps so far, and its parity names the buffer. A step nobody
+    prefetched (the first live step of a row after an empty row, or of row
+    0) starts its own DMAs.
+
+    The query arrives block-diagonal, (H, KV*Dh): row h holds query head h
+    in the lanes of its kv head h // group, zeros elsewhere. One NT matmul
+    then scores every head over the k pages, (H, k*page) lane-dense, and one
+    matmul weights the values, (H, KV*Dv); head h keeps the lanes of its kv
+    head in the finish step. Softmax state is carried online in f32 scratch
+    across the block-table sweep."""
     b = pl.program_id(0)
     ib = pl.program_id(1)
+    n_rows = pl.num_programs(0)
+    span = k * page
+    first = ib * span
+
+    def copies(row, step, slot):
+        """The 2k DMAs of step ``step`` of row ``row`` into buffer ``slot``:
+        its K and V pages of the layer."""
+        out = []
+        for j in range(k):
+            pg = bt_ref[row, step * k + j]
+            dst = pl.ds(j * page, page)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[layer_ref[0], pg], k_buf.at[slot, dst],
+                sem.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[layer_ref[0], pg], v_buf.at[slot, dst],
+                sem.at[1, slot]))
+        return out
+
+    def start(row, step, slot):
+        for c in copies(row, step, slot):
+            c.start()
+
+    @pl.when((b == 0) & (ib == 0))
+    def _first():
+        done[0] = 0
 
     @pl.when(ib == 0)
     def _init():
@@ -107,45 +165,68 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    # pages wholly past the row's length are unmapped (null page 0, garbage
-    # contents by convention) — skip them entirely: no MXU work
-    @pl.when(ib * page_size < len_ref[b])
+    # steps whose pages all sit past the row's length would read the null
+    # page (garbage contents by convention): no DMA, no MXU work
+    @pl.when(first < len_ref[b])
     def _compute():
-        for h in range(kv_heads):                      # static: per kv head
-            q = q_ref[0, h].astype(jnp.float32)        # (G, Dh)
-            k = k_ref[0, :, h * head_dim:(h + 1) * head_dim].astype(
-                jnp.float32)                           # (page, Dh)
-            v = v_ref[0, :, h * v_dim:(h + 1) * v_dim].astype(
-                jnp.float32)                           # (page, Dv)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale      # (G, page)
-            # null-page / partial-last-page masking: position vs row length
-            pos = ib * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(pos < len_ref[b], s, NEG_INF)
-            online_update(s, v, m_sc, l_sc, acc_sc, h)
+        slot = done[0] % 2
+        prev = jnp.maximum(b - 1, 0)
+        prefetched = (ib > 0) | ((b > 0) & (len_ref[prev] > 0))
+        # row b goes on (and the table does: no entry past it is read)
+        more = (first + span < len_ref[b]) & (ib + 1 < nb // k)
+        nxt = jnp.minimum(b + 1, n_rows - 1)
+        next_row = (jnp.logical_not(more) & (b + 1 < n_rows)
+                    & (len_ref[nxt] > 0))
 
-    @pl.when(ib == nb - 1)
+        @pl.when(jnp.logical_not(prefetched))
+        def _own():
+            start(b, ib, slot)
+
+        @pl.when(more)
+        def _next_step():
+            start(b, ib + 1, 1 - slot)
+
+        @pl.when(next_row)
+        def _next_row():
+            start(nxt, 0, 1 - slot)
+
+        for c in copies(b, ib, slot):
+            c.wait()
+        done[0] += 1
+
+        q = q_ref[0]                                       # (H, KV*Dh)
+        s = _nt(q, k_buf[slot].astype(q.dtype)) * scale    # (H, k*page)
+        # null-page / partial-last-page masking: position vs row length
+        pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        _online_update(m_sc, l_sc, acc_sc,
+                       jnp.where(pos < len_ref[b], s, NEG_INF),
+                       v_buf[slot].astype(jnp.float32))
+
+    @pl.when(ib == nb // k - 1)
     def _finish():
-        o_ref[0] = (
-            acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(o_ref.dtype)
+        o = acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)    # (H, KV*Dv)
+        for h in range(o.shape[0] // group):               # static: kv head
+            rows = slice(h * group, (h + 1) * group)
+            o_ref[0, rows] = o[rows, h * v_dim:(h + 1) * v_dim].astype(
+                o_ref.dtype)
 
 
-def _paged_prefill_kernel(bt_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref,
-                          o_ref, m_sc, l_sc, acc_sc, *, scale: float,
-                          page_size: int, nb: int, group: int, kv_heads: int,
-                          head_dim: int, v_dim: int):
+def _paged_prefill_kernel(bt_ref, lens_ref, layer_ref, q_ref, *refs,
+                          scale: float, page: int, nb: int, k: int,
+                          group: int, head_dim: int, v_dim: int):
     """One ib step of the Q-chunk>1 paged prefill sweep: queries are the
-    admission chunk's C tokens (flattened (C*G) rows per kv head), the K/V
-    tile IS physical page bt[ib] of layer ``layer`` in the stacked
-    lane-dense arena, all kv heads at once (head h: lanes
-    [h*Dh, (h+1)*Dh)). lens holds (offset, total): ``offset`` tokens
-    preceded this chunk, ``total`` = offset + valid masks the chunk's jit
-    padding. Causal masking
-    is per query ROW: row r is chunk token r // G at absolute position
+    admission chunk's C tokens (flattened (C*G) rows per kv head); pages
+    bt[ib*k .. ib*k+k-1] of layer ``layer`` of the stacked lane-dense arena
+    arrive one block operand each and are stacked into one (k*page, KV*Dh)
+    tile, whose lanes [h*Dh, (h+1)*Dh) are kv head h's keys. lens holds
+    (offset, total): ``offset`` tokens preceded this chunk, ``total`` =
+    offset + valid masks the chunk's jit padding. Causal masking is per
+    query ROW: row r is chunk token r // G at absolute position
     offset + r // G."""
+    k_refs, v_refs = refs[:k], refs[k:2 * k]
+    o_ref, m_sc, l_sc, acc_sc = refs[2 * k:]
     ib = pl.program_id(0)
+    first = ib * k * page
 
     @pl.when(ib == 0)
     def _init():
@@ -153,26 +234,23 @@ def _paged_prefill_kernel(bt_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    # pages wholly past the row's post-chunk length are unmapped: skip
-    @pl.when(ib * page_size < lens_ref[1])
+    # steps whose pages all sit past the row's post-chunk length: skip
+    @pl.when(first < lens_ref[1])
     def _compute():
-        for h in range(kv_heads):                      # static: per kv head
-            q = q_ref[h].astype(jnp.float32)           # (C*G, Dh)
-            k = k_ref[0, :, h * head_dim:(h + 1) * head_dim].astype(
-                jnp.float32)                           # (page, Dh)
-            v = v_ref[0, :, h * v_dim:(h + 1) * v_dim].astype(
-                jnp.float32)                           # (page, Dv)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale      # (C*G, page)
-            pos = ib * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            qtok = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-            ok = (pos < lens_ref[1]) & (pos <= lens_ref[0] + qtok)  # causal
-            s = jnp.where(ok, s, NEG_INF)
-            online_update(s, v, m_sc, l_sc, acc_sc, h)
+        keys, vals = _stacked(k_refs), _stacked(v_refs)
+        shape = (q_ref.shape[1], keys.shape[0])            # (C*G, k*page)
+        pos = first + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        qtok = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // group
+        ok = (pos < lens_ref[1]) & (pos <= lens_ref[0] + qtok)  # causal
+        for h in range(m_sc.shape[0]):                     # static: kv head
+            q = q_ref[h]                                   # (C*G, Dh)
+            s = _nt(q, keys[:, h * head_dim:(h + 1) * head_dim].astype(
+                q.dtype)) * scale
+            online_update(jnp.where(ok, s, NEG_INF),
+                          vals[:, h * v_dim:(h + 1) * v_dim].astype(
+                              jnp.float32), m_sc, l_sc, acc_sc, h)
 
-    @pl.when(ib == nb - 1)
+    @pl.when(ib == nb // k - 1)
     def _finish():
         o_ref[...] = (
             acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(o_ref.dtype)
@@ -191,8 +269,9 @@ def paged_flash_prefill_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array
     """Chunked paged prefill attention for ONE request slot: the chunk's C
     queries attend over the slot's pages [0, offset + valid) — the chunk's own
     K/V were just written into those pages, so no contiguous scratch cache
-    exists. Same scalar-prefetch construction as the decode kernel, with a
-    per-query-row causal mask (query i sits at absolute position offset + i).
+    exists. Same scalar-prefetch construction as the decode kernel, k pages
+    a grid step (``_pages_per_step``), with a per-query-row causal mask
+    (query i sits at absolute position offset + i).
 
     q: (1, C, H, Dh); k_pages/v_pages: (L, P, page, KV*Dh|KV*Dv) stacked
     lane-dense pools (head h at lanes [h*Dh, (h+1)*Dh)), read at ``layer``;
@@ -206,25 +285,26 @@ def paged_flash_prefill_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array
     Dv = v_pages.shape[3] // KV
     g = H // KV
     nb = block_row.shape[0]
+    k = _pages_per_step(nb)
     # (KV, C*G, Dh), token-major rows within each kv head: row r = token r // g
     qg = q[0].reshape(C, KV, g, Dh).transpose(1, 0, 2, 3).reshape(KV, C * g, Dh)
+    qg = qg.astype(jnp.promote_types(q.dtype, k_pages.dtype))
     lens = jnp.stack([offset, offset + valid]).astype(jnp.int32)
 
-    kern = functools.partial(_paged_prefill_kernel, scale=scale,
-                             page_size=page, nb=nb, group=g, kv_heads=KV,
-                             head_dim=Dh, v_dim=Dv)
+    kern = functools.partial(_paged_prefill_kernel, scale=scale, page=page,
+                             nb=nb, k=k, group=g, head_dim=Dh, v_dim=Dv)
+    # the default scoped VMEM (16 MiB) of a one-page step, plus the k - 1
+    # more double-buffered K and V pages a k-page step holds
+    wider = 2 * (k - 1) * page * KV * (Dh + Dv) * k_pages.dtype.itemsize
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # block_row, (offset, total), layer
-            grid=(nb,),             # sweeps the slot's block-table entries
+            grid=(nb // k,),        # sweeps the slot's block-table entries
             in_specs=[
                 pl.BlockSpec((KV, C * g, Dh), lambda ib, bt, ln, ly: (0, 0, 0)),
-                pl.BlockSpec((pl.squeezed, 1, page, KV * Dh),
-                             lambda ib, bt, ln, ly: (ly[0], bt[ib], 0, 0)),
-                pl.BlockSpec((pl.squeezed, 1, page, KV * Dv),
-                             lambda ib, bt, ln, ly: (ly[0], bt[ib], 0, 0)),
-            ],
+            ] + _page_specs(k, page, KV * Dh, page, KV * Dv,
+                            lambda j, ib, bt, ln, ly: bt[ib * k + j]),
             out_specs=pl.BlockSpec((KV, C * g, Dv),
                                    lambda ib, bt, ln, ly: (0, 0, 0)),
             scratch_shapes=[
@@ -234,10 +314,12 @@ def paged_flash_prefill_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((KV, C * g, Dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=(16 << 20) + wider),
         name="paged_flash_prefill",
         interpret=interpret,
-    )(block_row.astype(jnp.int32), lens, _layer_index(layer), qg, k_pages,
-      v_pages)
+    )(block_row.astype(jnp.int32), lens, _layer_index(layer), qg,
+      *([k_pages] * k + [v_pages] * k))
     return out.reshape(KV, C, g, Dv).transpose(1, 0, 2, 3).reshape(1, C, H, Dv)
 
 
@@ -245,11 +327,13 @@ def paged_flash_decode_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                            block_table: jax.Array, lengths: jax.Array,
                            layer: jax.Array, *, scale: float,
                            interpret: bool = True) -> jax.Array:
-    """Paged single-token flash decode: grid iterates block-table entries and
-    DMAs each mapped page of layer ``layer`` from the stacked arena into VMEM
-    via the BlockSpec index map (scalar-prefetched block table and layer) —
-    neither the layer's pool nor the contiguous logical K/V view is ever
-    materialized.
+    """Paged single-token flash decode: grid step (b, ib) reads the k pages
+    bt[b, ib*k .. ib*k+k-1] of layer ``layer`` (``_pages_per_step``), which
+    the kernel DMAs from the stacked arena into VMEM one step ahead
+    (scalar-prefetched block table and layer) — neither the layer's pool nor
+    the contiguous logical K/V view is ever materialized — and scores every
+    head over them in one matmul against the block-diagonal query
+    (``_paged_decode_kernel``).
 
     q: (B, 1, H, Dh); k_pages/v_pages: (L, P, page, KV*Dh|KV*Dv) stacked
     lane-dense pools (head h at lanes [h*Dh, (h+1)*Dh)); block_table:
@@ -266,37 +350,45 @@ def paged_flash_decode_fwd(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     Dv = v_pages.shape[3] // KV
     g = H // KV
     nb = block_table.shape[1]
-    qg = q[:, 0].reshape(B, KV, g, Dh)
+    k = _pages_per_step(nb)
+    # block-diagonal query (B, H, KV*Dh): head h in kv head h // g's lanes
+    eye = jnp.eye(KV, dtype=jnp.promote_types(q.dtype, k_pages.dtype))
+    qb = (eye[None, :, None, :, None]
+          * q[:, 0].reshape(B, KV, g, 1, Dh)).reshape(B, H, KV * Dh)
 
-    kern = functools.partial(_paged_decode_kernel, scale=scale,
-                             page_size=page, nb=nb, kv_heads=KV, head_dim=Dh,
-                             v_dim=Dv)
+    kern = functools.partial(_paged_decode_kernel, scale=scale, page=page,
+                             nb=nb, k=k, group=g, v_dim=Dv)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # block_table, lengths, layer
-            grid=(B, nb),           # innermost axis sweeps block-table entries
+            grid=(B, nb // k),      # innermost axis sweeps block-table entries
             in_specs=[
-                pl.BlockSpec((1, KV, g, Dh),
-                             lambda b, ib, bt, ln, ly: (b, 0, 0, 0)),
-                pl.BlockSpec((pl.squeezed, 1, page, KV * Dh),
-                             lambda b, ib, bt, ln, ly: (ly[0], bt[b, ib], 0, 0)),
-                pl.BlockSpec((pl.squeezed, 1, page, KV * Dv),
-                             lambda b, ib, bt, ln, ly: (ly[0], bt[b, ib], 0, 0)),
+                pl.BlockSpec((1, H, KV * Dh),
+                             lambda b, ib, bt, ln, ly: (b, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),   # the arena stays in
+                pl.BlockSpec(memory_space=pl.ANY),   # HBM: DMA'd by hand
             ],
-            out_specs=pl.BlockSpec((1, KV, g, Dv),
-                                   lambda b, ib, bt, ln, ly: (b, 0, 0, 0)),
+            out_specs=pl.BlockSpec((1, H, Dv),
+                                   lambda b, ib, bt, ln, ly: (b, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((KV, g, 1), jnp.float32),
-                pltpu.VMEM((KV, g, 1), jnp.float32),
-                pltpu.VMEM((KV, g, Dv), jnp.float32),
+                pltpu.VMEM((2, k * page, KV * Dh), k_pages.dtype),
+                pltpu.VMEM((2, k * page, KV * Dv), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),   # (K | V, buffer)
+                pltpu.SMEM((1,), jnp.int32),       # live steps so far
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, KV * Dv), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, KV, g, Dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Dv), q.dtype),
+        # a step prefetches the next row's first step: the grid runs in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         name="paged_flash_decode",
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      _layer_index(layer), qg, k_pages, v_pages)
+      _layer_index(layer), qb, k_pages, v_pages)
     return out.reshape(B, 1, H, Dv)
 
 

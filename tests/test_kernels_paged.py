@@ -123,9 +123,52 @@ def check_paged_flash(seed, page, nb, B, KV, g, Dh, dtype=jnp.float32,
     (3, 5, 4, 4, 2, 3, 16, jnp.float32),  # odd page size, partial last pages
     (4, 4, 1, 1, 1, 1, 8, jnp.float32),   # single block
     (5, 4, 3, 2, 2, 2, 16, jnp.bfloat16),  # the engine's default cache dtype
+    # k pages a grid step (k = 16, 16, 16, 8, 2, 16); each layout holds an
+    # empty row and a row that ends inside a k-page step
+    (6, 2, 32, 3, 2, 1, 16, jnp.float32),   # MHA, two steps: lengths 34, 0, 22
+    (12, 2, 32, 3, 2, 1, 16, jnp.bfloat16),  # MHA: 0, 63, 61
+    (8, 2, 16, 3, 2, 4, 16, jnp.float32),   # GQA, one step: 10, 0, 32
+    (15, 4, 8, 3, 1, 8, 16, jnp.bfloat16),  # GQA, g 8: 0, 23, 26
+    (7, 4, 6, 4, 2, 2, 16, jnp.float32),    # k 2, three steps: 15, 17, 22, 0
+    (16, 1, 48, 3, 4, 2, 8, jnp.bfloat16),  # one token a page: 27, 0, 21
+    # two empty rows in a row, so no step prefetches row 3: 24, 0, 0, 14, 31
+    (40, 2, 16, 5, 2, 1, 16, jnp.float32),
 ])
 def test_paged_flash_sweep(seed, page, nb, B, KV, g, Dh, dtype, layer):
     check_paged_flash(seed, page, nb, B, KV, g, Dh, dtype, layer)
+
+
+@pytest.mark.parametrize("seed,page,nb,B,KV,g", [
+    (6, 2, 32, 3, 2, 1),    # two steps a row, an empty row between
+    (15, 4, 8, 3, 1, 8),    # an empty first row
+    (7, 4, 6, 4, 2, 2),     # three steps a row
+    (40, 2, 16, 5, 2, 1),   # two empty rows in a row
+])
+def test_paged_flash_decode_under_simulated_dmas(seed, page, nb, B, KV, g):
+    """The decode kernel DMAs its pages itself, a live step ahead. The TPU
+    interpreter runs a DMA only when it is waited on and fills scratch with
+    NaN, so a step that reads a buffer before its own pages are in fails."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.flash_attn.kernel import paged_flash_decode_fwd
+    from repro.kernels.flash_attn.ref import paged_flash_decode_ref
+
+    rng = np.random.default_rng(seed)
+    num_pages, lengths, bt = _pool_layout(rng, B, nb, page)
+    kh = _stacked_heads(rng, num_pages, page, KV, 16)
+    vh = _stacked_heads(rng, num_pages, page, KV, 16)
+    q = jnp.asarray(rng.normal(size=(B, 1, KV * g, 16)).astype(np.float32))
+    params = pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                   uninitialized_memory="nan")
+    try:
+        out = paged_flash_decode_fwd(
+            q, _lane_dense(kh), _lane_dense(vh), jnp.asarray(bt),
+            jnp.asarray(lengths), LAYERS - 1, scale=0.25, interpret=params)
+    finally:
+        pltpu.reset_tpu_interpret_mode_state()
+    ref = paged_flash_decode_ref(q, jnp.asarray(kh[-1]), jnp.asarray(vh[-1]),
+                                 jnp.asarray(bt), jnp.asarray(lengths), 0.25)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
 @hypothesis.given(
@@ -315,14 +358,25 @@ def _prefill_layout(rng, page, nb, offset, valid):
     return num_pages, row
 
 
+def _f32_case(*case):
+    """A float32 case, under the id it had before the dtype was a field."""
+    return pytest.param(*case, jnp.float32, id="-".join(map(str, case)))
+
+
 @pytest.mark.parametrize("layer", [0, 1, LAYERS - 1])
-@pytest.mark.parametrize("seed,page,nb,C,KV,g,offset,valid", [
-    (0, 4, 6, 8, 2, 2, 0, 8),
-    (1, 4, 6, 8, 2, 1, 8, 5),    # continuation chunk, jit padding
-    (2, 2, 8, 4, 4, 1, 6, 4),
+@pytest.mark.parametrize("seed,page,nb,C,KV,g,offset,valid,dtype", [
+    _f32_case(0, 4, 6, 8, 2, 2, 0, 8),
+    _f32_case(1, 4, 6, 8, 2, 1, 8, 5),    # continuation chunk, jit padding
+    _f32_case(2, 2, 8, 4, 4, 1, 6, 4),
+    # k pages a grid step (k = 16, 16, 8, 2, 16)
+    (3, 2, 16, 8, 2, 1, 13, 8, jnp.float32),    # MHA, offset mid-step
+    (4, 2, 32, 16, 2, 1, 40, 12, jnp.bfloat16),  # second step, jit padding
+    (5, 4, 8, 8, 2, 2, 0, 8, jnp.float32),      # GQA, first chunk
+    (6, 2, 6, 4, 1, 4, 5, 3, jnp.bfloat16),     # GQA, g 4, three steps
+    (7, 1, 48, 8, 2, 2, 21, 8, jnp.float32),    # one token a page
 ])
 def test_paged_flash_prefill_vs_ref(seed, page, nb, C, KV, g, offset, valid,
-                                    layer):
+                                    dtype, layer):
     from repro.kernels.flash_attn.kernel import paged_flash_prefill_fwd
     from repro.kernels.flash_attn.ref import paged_flash_prefill_ref
 
@@ -330,15 +384,18 @@ def test_paged_flash_prefill_vs_ref(seed, page, nb, C, KV, g, offset, valid,
     num_pages, row = _prefill_layout(rng, page, nb, offset, valid)
     kh = _stacked_heads(rng, num_pages, page, KV, 16)
     vh = _stacked_heads(rng, num_pages, page, KV, 16)
-    q = jnp.asarray(rng.normal(size=(1, C, KV * g, 16)).astype(np.float32))
+    q = jnp.asarray(rng.normal(size=(1, C, KV * g, 16)).astype(np.float32),
+                    dtype)
     pos = (jnp.asarray(row), jnp.asarray(offset, jnp.int32),
            jnp.asarray(valid, jnp.int32))
-    out = paged_flash_prefill_fwd(q, _lane_dense(kh), _lane_dense(vh), *pos,
-                                  layer, scale=0.25, interpret=True)
-    ref = paged_flash_prefill_ref(q, jnp.asarray(kh[layer]),
-                                  jnp.asarray(vh[layer]), *pos, 0.25)
-    np.testing.assert_allclose(np.asarray(out)[0, :valid],
-                               np.asarray(ref)[0, :valid], atol=2e-5)
+    out = paged_flash_prefill_fwd(q, _lane_dense(kh, dtype),
+                                  _lane_dense(vh, dtype), *pos, layer,
+                                  scale=0.25, interpret=True)
+    ref = paged_flash_prefill_ref(q, jnp.asarray(kh[layer], dtype),
+                                  jnp.asarray(vh[layer], dtype), *pos, 0.25)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32)[0, :valid],
+                               np.asarray(ref, np.float32)[0, :valid], atol=tol)
 
 
 @pytest.mark.parametrize("seed,page,nb,C,KV,g,offset,valid", [

@@ -1,8 +1,10 @@
 """Compile the serving path's paged Pallas kernels, and the engine's
 donated decode and chunk steps, for a TPU v5e that is described, not
 attached, at Qwen1.5-0.5B widths (H = KV = 16, Dh = 64, d_model 1024, page
-16, 8 slots, prompt chunks of 128), and the MLA latent kernels and steps at
-DeepSeek-V2-Lite widths (latent 512, rope 64, 16 heads) with the
+16, 8 slots, prompt chunks of 128; the flash kernels also at the Qwen
+cells' block tables, 8 x 256 and 16 x 64 pages, and 512-token chunks, and
+the chunk prefill at OPT-6.7B widths), and the MLA latent kernels and
+steps at DeepSeek-V2-Lite widths (latent 512, rope 64, 16 heads) with the
 ``dsv2lite-longdoc`` cell's arena (32 slots x 4096 positions).
 
 Interpret-mode tests check semantics only; Mosaic's block-shape and VMEM
@@ -52,6 +54,19 @@ def one_chip(topo):
     jax.config.update("jax_enable_compilation_cache", was)
 
 
+# the Qwen cells' arenas, (rows, block-table width): qwen05b-longdoc's 8 x
+# 256 pages and qwen05b-chat's 16 x 64, with their 512-token chunks; the
+# flash kernels take 16 pages a grid step there (NB = 34 above takes 2)
+CELL_TABLES = ((8, 256), (16, 64))
+CELL_CHUNK = 512
+# and the chunk prefill at OPT-6.7B widths (32 heads of 128) with 256-token
+# chunks, 16 pages a step, inside its scoped VMEM (512-token chunks are not)
+OPT_H, OPT_DH, OPT_CHUNK, OPT_NB = 32, 128, 256, 128
+SHAPE_CASES = {f"flash_{kind}_nb{nb}": f"flash_{kind}"
+               for _, nb in CELL_TABLES for kind in ("decode", "prefill")}
+SHAPE_CASES["flash_prefill_opt"] = "flash_prefill"
+
+
 def _cases(sharding):
     from repro.kernels.cpq_dequant_attn import kernel as ck
     from repro.kernels.decomposed_attn import kernel as dk
@@ -68,7 +83,7 @@ def _cases(sharding):
     codes, levels = S((P, PAGE, KV, DH), i8), S((P, PAGE, KV), i32)
     table, lens = S((B, NB), i32), S((B,), i32)
     row, scalar = S((NB,), i32), S((), i32)
-    return {
+    cases = {
         "flash_decode": (fk.paged_flash_decode_fwd,
                          [S((B, 1, H, DH), bf), kv_pool, kv_pool, table, lens,
                           scalar]),
@@ -99,12 +114,29 @@ def _cases(sharding):
                            [S((C, MLA_H, DC), bf), S((C, MLA_H, DR), bf),
                             latent, rope, row, scalar, scalar, scalar]),
     }
+    for rows, nb in CELL_TABLES:
+        pool = S((LAYERS, rows * nb + 1, PAGE, KV * DH), bf)
+        cases[f"flash_decode_nb{nb}"] = (
+            fk.paged_flash_decode_fwd,
+            [S((rows, 1, H, DH), bf), pool, pool, S((rows, nb), i32),
+             S((rows,), i32), scalar])
+        cases[f"flash_prefill_nb{nb}"] = (
+            fk.paged_flash_prefill_fwd,
+            [S((1, CELL_CHUNK, H, DH), bf), pool, pool, S((nb,), i32), scalar,
+             scalar, scalar])
+    wide = S((LAYERS, OPT_NB + 1, PAGE, OPT_H * OPT_DH), bf)
+    cases["flash_prefill_opt"] = (
+        fk.paged_flash_prefill_fwd,
+        [S((1, OPT_CHUNK, OPT_H, OPT_DH), bf), wide, wide, S((OPT_NB,), i32),
+         scalar, scalar, scalar])
+    return cases
 
 
 @pytest.mark.parametrize("name", ["flash_decode", "flash_prefill",
                                   "cpq_decode", "cpq_prefill",
                                   "decomposed_decode", "decomposed_prefill",
-                                  "latent_decode", "latent_prefill"])
+                                  "latent_decode", "latent_prefill"]
+                         + list(SHAPE_CASES))
 def test_paged_kernel_compiles_for_v5e(one_chip, name):
     fwd, args = _cases(one_chip)[name]
     compiled = jax.jit(
@@ -124,7 +156,7 @@ KERNEL_NAMES = {"flash_decode": "paged_flash_decode",
                 "latent_prefill": "paged_mla_prefill"}
 
 
-@pytest.mark.parametrize("name", list(KERNEL_NAMES))
+@pytest.mark.parametrize("name", list(KERNEL_NAMES) + list(SHAPE_CASES))
 def test_paged_kernel_carries_its_name(one_chip, name):
     """Each compiled kernel is named by its ``pallas_call``'s ``name``, so
     a profiler trace tells the kernels apart (the op is named after it and
@@ -134,7 +166,7 @@ def test_paged_kernel_carries_its_name(one_chip, name):
         *args).compile().as_text()
     (kernel,) = [ln for ln in hlo.splitlines()
                  if 'custom_call_target="tpu_custom_call"' in ln]
-    want = KERNEL_NAMES[name]
+    want = KERNEL_NAMES[SHAPE_CASES.get(name, name)]
     assert kernel.split(" = ")[0].split()[-1].startswith(f"%{want}")
     assert f'/{want}/pallas_call"' in kernel
     assert len(set(KERNEL_NAMES.values())) == len(KERNEL_NAMES)
