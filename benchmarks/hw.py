@@ -20,7 +20,7 @@ class Device:
     power_w: float           # board power (throughput/W comparisons)
     # static/leakage board power burned regardless of slot occupancy —
     # what a serving step pays for its IDLE rows (charged against the
-    # measured slot-utilization trace in bench_e2e_energy). Rough
+    # recorded slot utilization in bench_e2e_energy). Rough
     # ~30% -of-board figures for the accelerators (clock gating leaves
     # leakage + HBM refresh + interconnect idle), lower for the FPGA/CIM
     # parts whose static share is small by construction.

@@ -14,8 +14,7 @@ def emit(name: str, us: float, derived: str = ""):
 
 def main() -> None:
     from benchmarks import (bench_cpq, bench_decomposition, bench_e2e_energy,
-                            bench_pipeline, bench_retrieval, bench_serving,
-                            roofline)
+                            bench_pipeline, bench_retrieval, roofline)
 
     modules = [
         ("bench_decomposition", bench_decomposition),   # paper §III / Fig. 2
@@ -23,7 +22,6 @@ def main() -> None:
         ("bench_cpq", bench_cpq),                       # paper §IV Fig. 4-5
         ("bench_retrieval", bench_retrieval),           # paper §V
         ("bench_e2e_energy", bench_e2e_energy),         # paper §IV table
-        ("bench_serving", bench_serving),               # continuous batching
         ("roofline", roofline),                         # deliverable (g)
     ]
     print("name,us_per_call,derived")

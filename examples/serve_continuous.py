@@ -45,7 +45,7 @@ def main():
           f"arena util mean {stats['arena_utilization_mean']:.2f})")
     print(f"[continuous] chunked prefill: {stats['prefill_chunks']} chunks "
           f"streamed into arena pages ({stats['prefill_tokens']} prompt "
-          f"tokens, {stats['prefill_write_bytes'] / 1e3:.1f} KB arena writes)")
+          "tokens)")
     for i in sorted(res):
         r = res[i]
         print(f"  req {i}: arrival {r['arrival']:5.1f} admitted {r['admitted_step']:3d} "

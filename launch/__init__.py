@@ -1,7 +1,7 @@
-"""Repo-root launcher shims: ``python -m launch.tune`` / ``launch.serve``.
+"""Repo-root launcher shim: ``python -m launch.serve``.
 
 Makes the ``src/repro/launch`` entry points runnable from the repository
-root without exporting PYTHONPATH — each submodule here adds ``src`` to
+root without exporting PYTHONPATH — the submodule here adds ``src`` to
 ``sys.path`` and delegates to the real ``repro.launch`` module.
 """
 import os

@@ -101,7 +101,7 @@ class AttentionRuntime:
     retrieval: Optional[RetrievalCfg] = None
     # paged serving decode: fuse the block-table gather into the Pallas
     # kernels (dense/CPQ/X-MLA tiers) instead of materializing logical views.
-    # False falls back to the jnp gather path (oracle / benchmark foil).
+    # False falls back to the jnp gather path (the tests' oracle).
     paged_kernels: bool = True
     # serving device mesh (jax.sharding.Mesh with a "model" axis, or None =
     # single device). When set, decode_attend_paged / chunk_attend_paged
@@ -157,9 +157,6 @@ class ServingCfg:
     # prefill cache, no monolithic admission stall. 0 restores the one-shot
     # B=1 prefill + pack path (the construction-exact admission oracle).
     prefill_chunk: int = 16
-    # fused paged-attention decode kernels: None defers to the engine's
-    # AttentionRuntime.paged_kernels (default on); True/False overrides it
-    use_paged_kernels: Optional[bool] = None
     # prefix sharing + copy-on-write pages: admission mounts a request's
     # longest indexed page-aligned prefix as refcount bumps on already-
     # resident pages (zero arena writes) and chunked prefill streams only
@@ -236,8 +233,7 @@ class ServingCfg:
         (ranges, page alignment of the prefill chunk, watermark ordering) —
         this is what ``__post_init__`` runs, so an invalid combination can
         never be constructed. ``strict=True`` (the default; called at
-        ``ContinuousServeEngine`` construction and by the auto-tuner after
-        ``validate_and_repair``) additionally rejects config-level
+        ``ContinuousServeEngine`` construction) additionally rejects config-level
         cross-knob inconsistencies: knobs that REQUEST a feature the rest of
         the config gates off (speculative decoding without chunked
         admission) and capacity settings no request could ever run under.
@@ -312,38 +308,6 @@ class ServingCfg:
     def max_len(self) -> int:
         """Per-request logical context ceiling (tokens)."""
         return self.page_size * self.max_blocks_per_slot
-
-    @classmethod
-    def preset_path(cls) -> str:
-        """Packaged presets file written by ``launch/tune.py`` (the
-        materialized Pareto frontier of the serving auto-tuner)."""
-        import os
-        return os.path.join(os.path.dirname(__file__), "serving_presets.json")
-
-    @classmethod
-    def list_presets(cls, path: Optional[str] = None) -> list[str]:
-        import json
-        with open(path or cls.preset_path()) as f:
-            return sorted(json.load(f)["presets"])
-
-    @classmethod
-    def from_preset(cls, name: str, path: Optional[str] = None,
-                    **overrides) -> "ServingCfg":
-        """Load a named operating point from the tuner-materialized presets
-        file (``latency`` / ``throughput`` / ``energy`` / ``default``, see
-        ``docs/tuning.md``). ``overrides`` replace preset fields — the serve
-        CLI uses this to re-derive arena capacity for its own context
-        ceiling while keeping the tuned knobs."""
-        import json
-        with open(path or cls.preset_path()) as f:
-            data = json.load(f)
-        if name not in data["presets"]:
-            raise ValueError(
-                f"unknown serving preset {name!r}; available: "
-                f"{sorted(data['presets'])}")
-        kwargs = dict(data["presets"][name]["serving"])
-        kwargs.update(overrides)
-        return cls(**kwargs).validate()
 
 
 # ------------------------------------------------------------------- model

@@ -41,16 +41,6 @@ def main(argv=None):
     ap.add_argument("--continuous", action="store_true",
                     help="continuous-batching engine over paged arenas "
                          "(token prompts only)")
-    ap.add_argument("--preset", default=None, metavar="NAME",
-                    help="serving preset from the auto-tuner's materialized "
-                         "Pareto frontier (latency | throughput | energy | "
-                         "default; src/repro/configs/serving_presets.json, "
-                         "see docs/tuning.md). Supplies the tuned knobs "
-                         "(policy, page_size, prefill_chunk, num_slots, "
-                         "watermarks, speculation); arena capacity is "
-                         "re-derived for --prompt/--new. Requires "
-                         "--continuous; conflicts with explicit --policy/"
-                         "--prefill-chunk/--speculate")
     ap.add_argument("--prefill-chunk", type=int, default=16,
                     help="chunked paged prefill: prompts stream into arena "
                          "pages in chunks of this many tokens, interleaved "
@@ -142,16 +132,6 @@ def main(argv=None):
     if args.speculate and args.prefill_chunk == 0:
         ap.error("--speculate requires chunked admission (--prefill-chunk "
                  "> 0): the verify pass is a spec_len+1 wide prefill chunk")
-    if args.preset:
-        if not args.continuous:
-            ap.error("--preset requires --continuous (presets are tuned "
-                     "continuous-serving operating points)")
-        for flag, dest in (("--policy", "policy"),
-                           ("--prefill-chunk", "prefill_chunk"),
-                           ("--speculate", "speculate")):
-            if getattr(args, dest) != ap.get_default(dest):
-                ap.error(f"--preset sets {flag}; drop the explicit flag "
-                         "(or drop --preset to hand-tune)")
     if args.deadline_scale and not args.continuous:
         ap.error("--deadline-scale requires --continuous (tick deadlines "
                  "are enforced by the continuous scheduler)")
@@ -177,33 +157,14 @@ def main(argv=None):
         from repro.serving.paged_cache import pages_needed
 
         n_max = args.prompt + args.new
-        if args.preset:
-            # tuned knobs from the materialized frontier; capacity re-derived
-            # for THIS context ceiling (the tuner sized its arena for the
-            # smoke trace, not for --prompt/--new)
-            base = ServingCfg.from_preset(args.preset)
-            serving = ServingCfg.from_preset(
-                args.preset,
-                num_pages=base.num_slots * pages_needed(n_max, base.page_size) + 1,
-                max_blocks_per_slot=pages_needed(n_max, base.page_size),
-                prefill_bucket=base.prefill_chunk or base.page_size,
-                probe_interval=args.probe_interval,
-                auto_drain=args.auto_drain or args.inject_faults is not None,
-                deadline_scale=args.deadline_scale)
-            print(f"[serve] preset={args.preset}: policy={serving.policy} "
-                  f"page_size={serving.page_size} "
-                  f"prefill_chunk={serving.prefill_chunk} "
-                  f"num_slots={serving.num_slots} "
-                  f"spec_len={serving.spec_len}")
-        else:
-            serving = ServingCfg(
-                num_slots=args.batch, page_size=16,
-                num_pages=args.batch * pages_needed(n_max, 16) + 1,
-                max_blocks_per_slot=pages_needed(n_max, 16), prefill_bucket=16,
-                prefill_chunk=args.prefill_chunk, policy=args.policy,
-                probe_interval=args.probe_interval,
-                auto_drain=args.auto_drain or args.inject_faults is not None,
-                deadline_scale=args.deadline_scale, spec_len=args.speculate)
+        serving = ServingCfg(
+            num_slots=args.batch, page_size=16,
+            num_pages=args.batch * pages_needed(n_max, 16) + 1,
+            max_blocks_per_slot=pages_needed(n_max, 16), prefill_bucket=16,
+            prefill_chunk=args.prefill_chunk, policy=args.policy,
+            probe_interval=args.probe_interval,
+            auto_drain=args.auto_drain or args.inject_faults is not None,
+            deadline_scale=args.deadline_scale, spec_len=args.speculate)
         if args.replicas > 1:
             from repro.serving import ReplicaRouter
 
@@ -270,9 +231,7 @@ def main(argv=None):
     if args.continuous and mesh is not None:
         print(f"[serve] arena: {stats['arena_bytes_per_device'] / 2**20:.2f} "
               f"MiB/device of {stats['arena_bytes_total'] / 2**20:.2f} MiB "
-              f"total; interconnect "
-              f"{stats['interconnect_bytes_per_token']:.1f} B/token "
-              "(per-head partial concat + latent pool gathers)")
+              "total")
     if args.replicas > 1:
         rows = ", ".join(
             f"r{p['replica']}: {p['generated_tokens']} tok @ "

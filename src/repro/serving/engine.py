@@ -1,7 +1,7 @@
 """Serving engines: the paper's end-to-end inference path.
 
 ``ServeEngine`` — the original static-batch engine (kept as the back-compat
-baseline and as the benchmark foil): one right-padded batch runs prefill then
+baseline and as the tests' reference): one right-padded batch runs prefill then
 a jitted decode loop to completion; every row owns a contiguous
 ``(n_max, ...)`` arena slice for the whole run.
 
@@ -239,10 +239,11 @@ class _ServeState:
         self.step_outputs: list[RequestOutput] = []  # this tick's events
         self.next_rid = 0
         self.step = 0                 # model-invocation tick clock
+        # ticks a one-shot admission charges per quantum of padded prompt
+        self.quantum = eng.serving.prefill_chunk or eng.serving.prefill_bucket
         self.decode_steps = self.live_steps = self.prefill_chunks = 0
         self.prefill_tokens = self.generated = 0
         self.nonfinite_rows = 0       # sampled rows whose logits had NaN/Inf
-        self.traffic = self.prefill_write_bytes = self.interconnect = 0.0
         self.util_peak = self.util_sum = 0.0
         self.util_n = 0
         self.defrag_mark = 0          # retirements at the last compaction
@@ -259,8 +260,8 @@ class ContinuousServeEngine:
     ``serve(requests, gen)`` wraps it batch-style — it resets the session,
     submits everything, and drains. The decode clock is the simulation time
     base: a request with ``arrival=t`` becomes admissible after t decode
-    steps (Poisson-arrival benchmarks feed arrivals in these units; online
-    use passes 0.0). ``policy`` (object, or via ``ServingCfg.policy`` name)
+    steps (tests feed simulated arrivals in these units; online use passes
+    0.0). ``policy`` (object, or via ``ServingCfg.policy`` name)
     selects the scheduling policy; the default FIFO policy plus greedy
     sampling reproduces the pre-request-API engine token-exactly.
     """
@@ -284,11 +285,6 @@ class ContinuousServeEngine:
                     "argument disagree — set one or make them equal")
             rt = dataclasses.replace(rt, mesh=mesh)
         self.mesh = getattr(rt, "mesh", None)
-        if (serving.use_paged_kernels is not None
-                and rt.paged_kernels != serving.use_paged_kernels):
-            # explicit serving-config override of the decode-kernel choice
-            # (fused paged kernels vs the jnp gather path); None defers to rt
-            rt = dataclasses.replace(rt, paged_kernels=serving.use_paged_kernels)
         self.tiered = bool(serving.enable_escalation and rt.mode == "dense")
         if self.tiered and rt.cpq is None:
             rt = dataclasses.replace(rt, cpq=CPQCfg())
@@ -365,8 +361,6 @@ class ContinuousServeEngine:
         self._verify_fns: dict[int, object] = {}
         self._copy_page = _named_jit(M.copy_page_caches, cfg, rt,
                                      donate=self.donates)
-        # cache-bearing layer count for the traffic model
-        self._n_cache_layers = sum(1 for m, _ in cfg.layer_kinds if m in ("attn", "mla"))
         self.policy = policy          # object/str override of serving.policy
         self._sample_rows = jax.jit(sample_token_rows)
         self._greedy_rows = jax.jit(greedy_token_rows)
@@ -560,23 +554,6 @@ class ContinuousServeEngine:
             alt_block_table=(jnp.asarray(sched.alt_block_tables)
                              if sched.tiered else None))
 
-    def _tier_bpt(self, caches) -> tuple[float, float]:
-        """(base, escalated) per-token decode traffic per cache-bearing layer."""
-        n_prefix = len(self.cfg.prefix_pattern)
-        entries = list(zip(self.cfg.prefix_pattern + self.cfg.block_pattern,
-                           caches["prefix"] + caches["blocks"]))
-        for i, (kind, c) in enumerate(entries):
-            if kind[0] not in ("attn", "mla"):
-                continue
-            c0 = jax.tree.map(lambda a: a[0], c) if i >= n_prefix else c
-            ps = self.serving.page_size
-            if isinstance(c0, pgc.TieredPagedCache):
-                return (pgc.bytes_per_token(c0.dense, ps),
-                        pgc.bytes_per_token(c0.cpq, ps, self.rt.cpq))
-            b = pgc.bytes_per_token(c0, ps, self.rt.cpq)
-            return b, b
-        return 0.0, 0.0
-
     # ------------------------------------------------- request-centric API
 
     def reset(self, gen: GenerationConfig = GenerationConfig()) -> None:
@@ -587,38 +564,8 @@ class ContinuousServeEngine:
         ``serve.reset`` span carrying device 0's memory once the new arenas
         are placed."""
         with _span("serve.reset") as span:
-            self._st = self._new_session(gen)
+            self._st = _ServeState(self, gen)
             span.set_metadata(**_device_memory())
-
-    def _new_session(self, gen: GenerationConfig) -> _ServeState:
-        st = _ServeState(self, gen)
-        st.bpt0, st.bpt1 = self._tier_bpt(st.caches)
-        st.quantum = self.serving.prefill_chunk or self.serving.prefill_bucket
-        # interconnect accounting under model sharding: each device emits its
-        # per-head output partial and receives the others' — the paper's
-        # "only small per-head partials cross the interconnect" measured as
-        # (mp-1)/mp of the concatenated head outputs, per token per layer
-        mp = self.model_shards
-        dv = (self.cfg.mla.v_head_dim if self.cfg.mla is not None
-              else self.cfg.head_dim)
-        # layers whose arenas are head-sharded pay the per-head output
-        # concat: exact for the shard_map'd tiers, a LOWER BOUND for T3
-        # retrieval (GSPMD chooses its own collectives there). The CPQ-X
-        # tiers replicate their code pools and are not charged — their
-        # residual k_rope movement is unmodeled.
-        n_concat = sum(
-            1 for m, _ in self.cfg.layer_kinds
-            if (m == "attn" and (self.tiered or self.rt.mode in
-                                 ("dense", "cpq", "decomposed", "retrieval")))
-            or (m == "mla" and self.rt.mode != "cpq"))
-        st.concat_bpt = (0.0 if mp <= 1 else
-                         (mp - 1) / mp * self.cfg.num_heads * dv
-                         * self.cfg.param_dtype.itemsize * n_concat)
-        # ...plus, for storage-sharded latent tiers (T1 X / MLA c_kv), the
-        # per-invocation pool all-gather — charged per model invocation, not
-        # per token (zero for head-sharded tiers and unsharded engines)
-        st.gather_bps = self._latent_gather_bytes_per_step(st.caches)
-        return st
 
     def _ensure_state(self) -> _ServeState:
         if self._st is None:
@@ -1056,11 +1003,9 @@ class ContinuousServeEngine:
             jnp.asarray(req.slot, jnp.int32), jnp.asarray(row),
             jnp.asarray(L, jnp.int32), jnp.asarray(valid, jnp.int32),
             st.caches)
-        # clock + traffic: one model invocation reading L+valid positions
+        # clock: one model invocation
         st.decode_steps += 1
         st.live_steps += 1
-        st.traffic += float(L + valid) * st.bpt0 * self._n_cache_layers
-        st.interconnect += valid * st.concat_bpt + st.gather_bps
         util = sched.dense_alloc.utilization
         st.util_peak = max(st.util_peak, util)
         st.util_sum += util
@@ -1149,15 +1094,7 @@ class ContinuousServeEngine:
                     continue  # pump below interleaves one chunk per tick
                 tok, padded = self._admit(req, st)
                 st.step += -(-padded // st.quantum)  # monolithic prefill stall
-                # no interconnect charge: the one-shot prefill runs as a
-                # replicated global jit (no shard_map), so under a mesh it
-                # pays mp-fold redundant FLOPs instead of concat traffic;
-                # the pack then writes each device's arena slice from the
-                # locally-present replicated payload
                 st.prefill_tokens += req.length
-                st.prefill_write_bytes += (req.length
-                                           * (st.bpt1 if req.tier else st.bpt0)
-                                           * self._n_cache_layers)
                 self._emit_token(st, req, tok, st.step)  # ready after the stall
             if admitted:
                 # space-separated: the profiler splits span args at commas
@@ -1303,11 +1240,6 @@ class ContinuousServeEngine:
             st.nonfinite_rows += int((active & ~finite).sum())
             st.decode_steps += 1
             st.live_steps += n_active
-            tier_arr = sched.tiers
-            st.traffic += float(sum(
-                (sched.lengths[s] + 1.0) * (st.bpt1 if tier_arr[s] else st.bpt0)
-                for s in range(B) if active[s])) * self._n_cache_layers
-            st.interconnect += n_active * st.concat_bpt + st.gather_bps
             util = sched.dense_alloc.utilization
             st.util_peak = max(st.util_peak, util)
             st.util_sum += util
@@ -1342,9 +1274,6 @@ class ContinuousServeEngine:
             span.set_metadata(tokens=valid)
             st.prefill_chunks += 1
             st.prefill_tokens += valid
-            st.prefill_write_bytes += (valid * (st.bpt1 if req.tier else st.bpt0)
-                                       * self._n_cache_layers)
-            st.interconnect += valid * st.concat_bpt + st.gather_bps
             # every page the chunk just FILLED is immutable from here on
             # (later chunks write strictly past req.length), so register
             # eagerly — concurrent admissions can mount a prefix that is
@@ -1360,7 +1289,7 @@ class ContinuousServeEngine:
 
     def stats(self) -> dict:
         """Session counters in the same shape ``serve`` has always returned
-        (throughput, latency inputs, traffic accounting, allocator surface),
+        (throughput, latency inputs, allocator surface),
         plus the policy name."""
         st = self._ensure_state()
         sched = st.sched
@@ -1380,8 +1309,6 @@ class ContinuousServeEngine:
             "model_shards": self.model_shards,
             "arena_bytes_total": total_bytes,
             "arena_bytes_per_device": device_bytes,
-            "interconnect_bytes": st.interconnect,
-            "interconnect_bytes_per_token": st.interconnect / max(st.generated, 1),
             "decode_steps": st.decode_steps,
             "prefill_chunks": st.prefill_chunks,
             "prefill_tokens": st.prefill_tokens,
@@ -1391,18 +1318,14 @@ class ContinuousServeEngine:
             "slot_utilization": st.live_steps / max(st.decode_steps * B, 1),
             "arena_utilization_mean": st.util_sum / max(st.util_n, 1),
             "arena_utilization_peak": st.util_peak,
-            "decode_traffic_bytes": st.traffic,
-            "prefill_write_bytes": st.prefill_write_bytes,
-            "bytes_per_token_layer": st.bpt0,
             "wall_time_s": wall,
             "tokens_per_s": st.generated / max(wall, 1e-9),
             # invariant: every page freed once all requests retired
             "dense_pages_leaked": sched.dense_alloc.num_used,
             "cpq_pages_leaked": sched.cpq_alloc.num_used if sched.cpq_alloc else 0,
             **sched.stats,
-            # public allocator surface (utilization + defrag counts): what
-            # bench_serving and the sharded watermark read instead of the
-            # private dense_alloc/cpq_alloc state
+            # public allocator surface (utilization + defrag counts), in
+            # place of the private dense_alloc/cpq_alloc state
             **sched.arena_stats(),
         }
 
@@ -1421,25 +1344,6 @@ class ContinuousServeEngine:
         while st.sched.has_work():
             self.step()
         return dict(st.results), self.stats()
-
-    def _latent_gather_bytes_per_step(self, caches) -> float:
-        """Interconnect bytes ONE model invocation moves re-assembling the
-        storage-sharded latent pools (the PagedXCache.x / PagedLatentCache.c
-        all-gather inside the shard_map, serving/sharded.py): each device ships its feature shard
-        to the mp-1 others, per latent cache layer. Zero when unsharded.
-        This dwarfs the per-head output concat — the price of latent
-        HBM-capacity sharding paid on every step (gathering only mapped
-        pages is the open optimization, see ROADMAP)."""
-        mp = self.model_shards
-        if mp <= 1:
-            return 0.0
-        total = 0
-        for c in caches["prefix"] + caches["blocks"]:
-            x = (c.x if isinstance(c, pgc.PagedXCache)
-                 else c.c if isinstance(c, pgc.PagedLatentCache) else None)
-            if x is not None and x.shape[-1] % mp == 0:
-                total += x.size * x.dtype.itemsize  # stacked axis included
-        return total * (mp - 1) / mp
 
     def _per_device_arena_bytes(self, caches, total_bytes: int) -> float:
         """Physical arena bytes each device holds (sharded leaves shrink,

@@ -1050,8 +1050,8 @@ def bytes_per_token(cache: PagedCache, page_size: int,
                     cpq_cfg: Optional[CPQCfg] = None) -> float:
     """Per-token decode traffic of the paged arena: the contiguous payload
     accounting (kv_cache.bytes_per_token / cpq accounting) plus the amortized
-    block-table overhead (one int32 entry per page). Hooked by
-    benchmarks/bench_e2e_energy.py and the scheduler's watermark policy."""
+    block-table overhead (one int32 entry per page). An input of the
+    analytic model in benchmarks/bench_e2e_energy.py."""
     overhead = 4.0 / page_size
     if isinstance(cache, TieredPagedCache):  # base-tier accounting
         return bytes_per_token(cache.dense, page_size, cpq_cfg)

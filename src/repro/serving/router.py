@@ -497,13 +497,12 @@ class ReplicaRouter:
 
     _SUM_KEYS = ("generated_tokens", "prefill_tokens", "prefill_chunks",
                  "decode_steps", "arena_bytes_total", "arena_bytes_per_device",
-                 "interconnect_bytes", "decode_traffic_bytes",
-                 "prefill_write_bytes", "defrags", "preemptions",
+                 "defrags", "preemptions",
                  "escalations", "deescalations", "admitted", "retired",
                  "timeouts", "dense_pages_leaked", "cpq_pages_leaked")
     _REPLICA_KEYS = ("tokens_per_step", "generated_tokens", "decode_steps",
                      "prefill_tokens", "arena_bytes_total",
-                     "interconnect_bytes", "defrags", "preemptions",
+                     "defrags", "preemptions",
                      "escalations", "deescalations", "timeouts",
                      "slot_utilization", "dense_arena_utilization", "policy")
 
@@ -553,8 +552,6 @@ class ReplicaRouter:
                       default=0)
         agg["decode_steps_max"] = busiest
         agg["tokens_per_step"] = agg["generated_tokens"] / max(busiest, 1)
-        agg["interconnect_bytes_per_token"] = (
-            agg["interconnect_bytes"] / max(agg["generated_tokens"], 1))
         agg["wall_time_s"] = max((s.get("wall_time_s", 0.0)
                                   for _, s in per_replica), default=0.0)
         agg["tokens_per_s"] = agg["generated_tokens"] / max(

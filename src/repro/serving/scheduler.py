@@ -196,8 +196,8 @@ class Scheduler:
 
     def arena_stats(self) -> dict:
         """Public allocator/defrag counters (the engine folds these into its
-        serve() stats; bench_serving and the sharded watermark read them here
-        instead of reaching into ``dense_alloc`` / ``cpq_alloc``). All counts
+        serve() stats and its health probes, instead of reaching into
+        ``dense_alloc`` / ``cpq_alloc``). All counts
         are LOGICAL pages — under a model-sharded mesh every logical page is
         one per-device slice, so fractions (and the watermark thresholds
         derived from them) are mesh-invariant."""

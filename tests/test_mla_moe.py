@@ -132,9 +132,9 @@ def _served_logits(cfg, params, prompt, fused: bool, max_new: int):
     """Greedy logits the engine produced for one request, in order: the
     last chunk's, then each decode step's."""
     serving = ServingCfg(num_slots=1, page_size=16, num_pages=9,
-                         max_blocks_per_slot=8, prefill_chunk=16,
-                         use_paged_kernels=fused)
-    eng = ContinuousServeEngine(cfg, params, serving=serving)
+                         max_blocks_per_slot=8, prefill_chunk=16)
+    rt = dataclasses.replace(cfg.attention, paged_kernels=fused)
+    eng = ContinuousServeEngine(cfg, params, rt=rt, serving=serving)
     assert eng.chunked and eng.donates
     seen, orig = [], eng._greedy_rows
 

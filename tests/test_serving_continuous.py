@@ -1,9 +1,8 @@
 """Continuous-batching engine + scheduler behaviour tests: page-leak
 invariants, admission/retirement/resume correctness, preemption recompute,
-watermark tier escalation, and the throughput acceptance bar vs the static
-engine."""
-import os
-import sys
+watermark tier escalation, and ``ServingCfg.validate``'s named-knob errors,
+at construction and at engine construction."""
+import dataclasses
 
 import numpy as np
 import pytest
@@ -15,8 +14,6 @@ from repro.configs import ARCHS, ServingCfg, smoke_config
 from repro.models import model as M
 from repro.serving.engine import ContinuousServeEngine, GenerationConfig, ServeEngine
 from repro.serving.scheduler import Request, Scheduler, SchedulerConfigError
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))  # benchmarks/
 
 
 @pytest.fixture(scope="module")
@@ -223,9 +220,11 @@ def test_admitted_request_resumes_at_correct_position(model):
         refs.append(out[0])
     serving = ServingCfg(num_slots=2, page_size=4, num_pages=33,
                          max_blocks_per_slot=8, prefill_bucket=4,
-                         prefill_chunk=0,  # one-shot oracle: shares static ops
-                         use_paged_kernels=False)  # gather path == static ops
-    eng = ContinuousServeEngine(cfg, params, serving=serving)
+                         prefill_chunk=0)  # one-shot oracle: shares static ops
+    # gather path == static ops
+    eng = ContinuousServeEngine(
+        cfg, params, serving=serving,
+        rt=dataclasses.replace(cfg.attention, paged_kernels=False))
     res, stats = eng.serve(reqs, gen)
     for i, ref in enumerate(refs):
         np.testing.assert_array_equal(res[i]["tokens"], ref)
@@ -247,9 +246,11 @@ def test_preemption_recompute_is_exact(model):
         refs[r.rid] = static.generate({"tokens": jnp.asarray(r.prompt[None])}, gen)[0][0]
     serving = ServingCfg(num_slots=3, page_size=4, num_pages=10,  # too small
                          max_blocks_per_slot=8, prefill_bucket=4,
-                         prefill_chunk=0,  # one-shot oracle: shares static ops
-                         use_paged_kernels=False)  # gather path == static ops
-    eng = ContinuousServeEngine(cfg, params, serving=serving)
+                         prefill_chunk=0)  # one-shot oracle: shares static ops
+    # gather path == static ops
+    eng = ContinuousServeEngine(
+        cfg, params, serving=serving,
+        rt=dataclasses.replace(cfg.attention, paged_kernels=False))
     res, stats = eng.serve(reqs_small, gen)
     assert stats["preemptions"] >= 1
     for rid, ref in refs.items():
@@ -331,20 +332,6 @@ def test_static_engine_eos_masking(model):
     assert stats["generated_tokens"] == live
 
 
-def test_throughput_vs_static_acceptance():
-    """Acceptance bar: >= 1.5x token throughput over the static engine on a
-    mixed-length Poisson workload at equal arena bytes."""
-    from benchmarks.bench_serving import compare
-
-    cfg = smoke_config(ARCHS["qwen1.5-0.5b"])
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    st, ct = compare(cfg, params, rate=1.0, n_requests=12, num_slots=4)
-    ratio = ct["tokens_per_step"] / st["tokens_per_step"]
-    assert ratio >= 1.5, (st, ct)
-    assert ct["arena_utilization"] > st["arena_utilization"]
-    assert ct["latency_mean"] < st["latency_mean"]
-
-
 def test_nonfinite_logit_rows_are_counted(model):
     """The engine counts sampled rows whose logits hold NaN/Inf (first
     tokens and decode rows alike): zero on a healthy model, every emitted
@@ -361,3 +348,66 @@ def test_nonfinite_logit_rows_are_counted(model):
     eng = ContinuousServeEngine(cfg, bad, serving=serving)
     _, stats = eng.serve(reqs(), GenerationConfig(max_new_tokens=3))
     assert stats["nonfinite_logit_rows"] == stats["generated_tokens"] == 6
+
+
+# ------------------------------------------------------- ServingCfg.validate
+
+# One case per rule of ``ServingCfg.validate``: (fields, strict, match).
+# ``strict`` rules pass construction and fail only ``validate()``.
+_INVALID = [
+    pytest.param(dict(num_pages=1), False, "num_pages", id="pages"),
+    pytest.param(dict(num_slots=0), False, "num_slots", id="slot_minimums"),
+    pytest.param(dict(critical_watermark=0.5, low_watermark=0.25), False,
+                 "critical_watermark", id="critical_le_low"),
+    pytest.param(dict(low_watermark=0.6, high_watermark=0.4), False,
+                 "high_watermark", id="low_le_high"),
+    pytest.param(dict(policy="lifo"), False, "policy", id="policy"),
+    pytest.param(dict(prefill_bucket=0), False, "prefill_bucket",
+                 id="prefill_bucket"),
+    pytest.param(dict(prefill_chunk=-16), False, "prefill_chunk=-16",
+                 id="negative_prefill_chunk"),
+    pytest.param(dict(defrag_every=-1), False, "defrag_every",
+                 id="defrag_every"),
+    pytest.param(dict(probe_interval=-1), False, "probe_interval",
+                 id="probe_interval"),
+    pytest.param(dict(probe_backoff=0), False, "probe_backoff",
+                 id="probe_failures_backoff"),
+    pytest.param(dict(probe_exhaust_frac=1.5), False, "probe_exhaust_frac",
+                 id="probe_exhaust_frac"),
+    pytest.param(dict(deadline_scale=-1.0), False, "deadline_scale",
+                 id="deadline_scale"),
+    pytest.param(dict(max_backlog=-1), False, "max_backlog",
+                 id="max_backlog"),
+    pytest.param(dict(spec_len=-1), False, "spec_len", id="spec_len"),
+    pytest.param(dict(spec_ngram=0), False, "spec_ngram", id="spec_ngram"),
+    pytest.param(dict(page_size=8, prefill_chunk=12), False, "page-aligned",
+                 id="page_alignment"),
+    pytest.param(dict(spec_len=2, prefill_chunk=0), True, "chunked admission",
+                 id="strict_spec_needs_chunk"),
+    pytest.param(dict(page_size=1, max_blocks_per_slot=1), True, "max_len",
+                 id="strict_max_len"),
+]
+
+
+@pytest.mark.parametrize("fields,strict,match", _INVALID + [
+    pytest.param({}, True, None, id="default")])
+def test_validate_clear_errors(fields, strict, match):
+    if match is None:
+        ok = ServingCfg(**fields)
+        assert ok.validate() is ok               # chainable: returns self
+        return
+    if not strict:
+        with pytest.raises(ValueError, match=match):
+            ServingCfg(**fields)
+        return
+    cfg = ServingCfg(**fields)                   # constructs fine
+    with pytest.raises(ValueError, match=match):
+        cfg.validate()
+
+
+def test_engine_construction_raises_scheduler_config_error():
+    cfg = smoke_config(ARCHS["qwen1.5-0.5b"])
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    bad = ServingCfg(spec_len=2, prefill_chunk=0)
+    with pytest.raises(SchedulerConfigError, match="spec_len"):
+        ContinuousServeEngine(cfg, params, serving=bad)

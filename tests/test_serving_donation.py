@@ -6,7 +6,7 @@ chunks, speculative verify, one-shot pack, copy-on-write page copies and
 defrag. The CPU backend honours donation and raises on any read of a deleted
 buffer, so a whole session that runs through shows that no caller reads
 caches it donated; its tokens must match the gather path
-(``use_paged_kernels=False``), which indexes the same stacked arena.
+(``paged_kernels=False``), which indexes the same stacked arena.
 """
 import dataclasses
 
@@ -56,8 +56,9 @@ ADMISSIONS = {
 def _session(cfg, params, admission: str, fused: bool):
     sv = ServingCfg(num_slots=2, page_size=4, num_pages=33,
                     max_blocks_per_slot=12, prefill_bucket=4,
-                    use_paged_kernels=fused, **ADMISSIONS[admission])
-    eng = ContinuousServeEngine(cfg, params, serving=sv)
+                    **ADMISSIONS[admission])
+    rt = dataclasses.replace(cfg.attention, paged_kernels=fused)
+    eng = ContinuousServeEngine(cfg, params, rt=rt, serving=sv)
     assert eng.donates
     eng.reset(GenerationConfig(max_new_tokens=max(MAX_NEW)))
     first = eng._st.caches["blocks"][0].k

@@ -1,6 +1,8 @@
 """Paged KV-cache arena tests: allocator/defrag invariants (deterministic +
 property-based), page-plumbing round trips, paged-vs-contiguous decode
 equivalence across cache modes, and fused paged-kernel engine parity."""
+import dataclasses
+
 from _hypothesis_compat import hypothesis, st  # optional dep; see pyproject test extra
 import numpy as np
 import pytest
@@ -212,10 +214,15 @@ def _prompts(cfg, seed=0):
             for s in _PROMPT_LENS]
 
 
+def _gather(cfg):
+    """The jnp gather path in place of the fused paged kernels."""
+    return dataclasses.replace(cfg.attention, paged_kernels=False)
+
+
 def test_paged_dense_greedy_equals_contiguous():
     """The PR-1 equivalence: mixed prompt lengths, greedy, paged continuous
     decode == contiguous dense decode, token for token. Pinned to the jnp
-    gather path (``use_paged_kernels=False``), which shares every op with the
+    gather path (``paged_kernels=False``), which shares every op with the
     static engine — construction-exact at any dtype. Fused-kernel parity is
     covered by test_paged_kernel_engine_parity (f32) below."""
     cfg, params = _mk()
@@ -224,9 +231,8 @@ def test_paged_dense_greedy_equals_contiguous():
     refs = _static_refs(cfg, params, prompts, gen)
     serving = ServingCfg(num_slots=4, page_size=4, num_pages=41,
                          max_blocks_per_slot=8, prefill_bucket=4,
-                         prefill_chunk=0,  # one-shot oracle: shares static ops
-                         use_paged_kernels=False)
-    eng = ContinuousServeEngine(cfg, params, serving=serving)
+                         prefill_chunk=0)  # one-shot oracle: shares static ops
+    eng = ContinuousServeEngine(cfg, params, rt=_gather(cfg), serving=serving)
     res, stats = eng.serve(
         [Request(rid=i, prompt=p, max_new_tokens=6) for i, p in enumerate(prompts)],
         gen)
@@ -250,9 +256,9 @@ def test_paged_modes_match_contiguous(arch, mode):
     refs = _static_refs(cfg, params, prompts, gen)
     serving = ServingCfg(num_slots=4, page_size=4, num_pages=65,
                          max_blocks_per_slot=8, prefill_bucket=4,
-                         prefill_chunk=0,  # one-shot oracle: shares static ops
-                         use_paged_kernels=False)  # gather path == static ops
-    eng = ContinuousServeEngine(cfg, params, serving=serving)
+                         prefill_chunk=0)  # one-shot oracle: shares static ops
+    # gather path == static ops
+    eng = ContinuousServeEngine(cfg, params, rt=_gather(cfg), serving=serving)
     res, _ = eng.serve(
         [Request(rid=i, prompt=p, max_new_tokens=5) for i, p in enumerate(prompts)],
         gen)
@@ -271,9 +277,9 @@ def test_paged_cpq_modes_match_with_unbucketed_prefill(mode):
     refs = _static_refs(cfg, params, prompts, gen)
     serving = ServingCfg(num_slots=4, page_size=4, num_pages=65,
                          max_blocks_per_slot=8, prefill_bucket=1,
-                         prefill_chunk=0,  # one-shot oracle: shares static ops
-                         use_paged_kernels=False)  # gather path == static ops
-    eng = ContinuousServeEngine(cfg, params, serving=serving)
+                         prefill_chunk=0)  # one-shot oracle: shares static ops
+    # gather path == static ops
+    eng = ContinuousServeEngine(cfg, params, rt=_gather(cfg), serving=serving)
     res, _ = eng.serve(
         [Request(rid=i, prompt=p, max_new_tokens=5) for i, p in enumerate(prompts)],
         gen)
@@ -284,14 +290,15 @@ def test_paged_cpq_modes_match_with_unbucketed_prefill(mode):
 # -------------------------------------------- fused paged-kernel parity
 
 
-def _serve_tokens(cfg, params, prompts, *, use_paged_kernels, tiered=False,
+def _serve_tokens(cfg, params, prompts, *, paged_kernels, tiered=False,
                   max_new=8):
     kw = dict(num_slots=3, page_size=4, num_pages=65, max_blocks_per_slot=8,
-              prefill_bucket=4, use_paged_kernels=use_paged_kernels)
+              prefill_bucket=4)
     if tiered:
         kw.update(num_pages=13, escalated_pages=33, enable_escalation=True,
                   low_watermark=0.5, critical_watermark=0.25)
-    eng = ContinuousServeEngine(cfg, params, serving=ServingCfg(**kw))
+    rt = dataclasses.replace(cfg.attention, paged_kernels=paged_kernels)
+    eng = ContinuousServeEngine(cfg, params, rt=rt, serving=ServingCfg(**kw))
     res, stats = eng.serve(
         [Request(rid=i, prompt=p, max_new_tokens=max_new)
          for i, p in enumerate(prompts)],
@@ -313,8 +320,6 @@ def test_paged_kernel_engine_parity(arch, mode, tiered):
     continuous engine. Run at f32 so both paths agree to reduction-order
     epsilon; the jnp gather oracle's bf16 rounding points are an XLA-fusion
     artifact no kernel can reproduce bit-for-bit at bf16."""
-    import dataclasses
-
     cfg = smoke_config(ARCHS[arch])
     cfg = dataclasses.replace(cfg, dtype="float32")
     if mode:
@@ -322,9 +327,9 @@ def test_paged_kernel_engine_parity(arch, mode, tiered):
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     prompts = _prompts(cfg, seed=3) + _prompts(cfg, seed=4)
     fused, fstats = _serve_tokens(cfg, params, prompts,
-                                  use_paged_kernels=True, tiered=tiered)
+                                  paged_kernels=True, tiered=tiered)
     gather, _ = _serve_tokens(cfg, params, prompts,
-                              use_paged_kernels=False, tiered=tiered)
+                              paged_kernels=False, tiered=tiered)
     assert set(fused) == set(gather) == set(range(len(prompts)))
     for i in fused:
         np.testing.assert_array_equal(fused[i], gather[i])
@@ -339,7 +344,7 @@ def test_paged_kernel_bf16_decode_is_valid():
     in-vocab, and leak-free across all slots and steps."""
     cfg, params = _mk()  # bf16 smoke model, fused kernels on by default
     prompts = _prompts(cfg, seed=5)
-    toks, stats = _serve_tokens(cfg, params, prompts, use_paged_kernels=True)
+    toks, stats = _serve_tokens(cfg, params, prompts, paged_kernels=True)
     assert set(toks) == set(range(len(prompts)))
     for i in toks:
         assert len(toks[i]) == 8

@@ -60,9 +60,11 @@ def _shared_prompts(cfg, tails=(5, 9, 3, 14, 7), prefix=24, seed=0):
 def _serve(cfg, params, prompts, *, share, fused=False, max_new=6, **kw):
     base = dict(num_slots=3, page_size=4, num_pages=65,
                 max_blocks_per_slot=12, prefill_bucket=4, prefill_chunk=4,
-                share_prefix=share, use_paged_kernels=fused)
+                share_prefix=share)
     base.update(kw)
-    eng = ContinuousServeEngine(cfg, params, serving=ServingCfg(**base))
+    rt = dataclasses.replace(cfg.attention, paged_kernels=fused)
+    eng = ContinuousServeEngine(cfg, params, rt=rt,
+                                serving=ServingCfg(**base))
     res, stats = eng.serve(
         [Request(rid=i, prompt=p, max_new_tokens=max_new)
          for i, p in enumerate(prompts)],
@@ -336,7 +338,8 @@ def test_refcount_invariant_any_interleaving(seed, tiered, num_pages, share):
 def test_sharing_greedy_parity(arch, mode, fused):
     """ACCEPTANCE: greedy output with prefix sharing ON is bit-identical to
     OFF across the tier modes on both paged-attention paths — while sharing
-    actually fires (hits > 0) and strictly reduces prefill arena writes."""
+    actually fires (hits > 0) and strictly reduces the prompt tokens that
+    prefill writes into the arena."""
     cfg, params = _mk(arch, mode)
     prompts = _shared_prompts(cfg)
     on_t, on_s, eng = _serve(cfg, params, prompts, share=True, fused=fused)
@@ -346,7 +349,7 @@ def test_sharing_greedy_parity(arch, mode, fused):
         np.testing.assert_array_equal(on_t[i], off_t[i])
     assert on_s["prefix_hits"] > 0
     assert on_s["shared_prefix_tokens"] > 0
-    assert on_s["prefill_write_bytes"] < off_s["prefill_write_bytes"]
+    assert on_s["prefill_tokens"] < off_s["prefill_tokens"]
     assert on_s["dense_pages_leaked"] == 0
     assert off_s["prefix_hits"] == 0 and not off_s["prefix_sharing"]
 
@@ -363,9 +366,10 @@ def test_sharing_seeded_sampling_parity():
     def run(share):
         sv = ServingCfg(num_slots=3, page_size=4, num_pages=65,
                         max_blocks_per_slot=12, prefill_bucket=4,
-                        prefill_chunk=4, share_prefix=share,
-                        use_paged_kernels=False)
-        eng = ContinuousServeEngine(cfg, params, serving=sv)
+                        prefill_chunk=4, share_prefix=share)
+        eng = ContinuousServeEngine(
+            cfg, params, serving=sv,
+            rt=dataclasses.replace(cfg.attention, paged_kernels=False))
         res, stats = eng.serve(
             [ServeRequest(prompt=p, rid=i, sampling=sps[i])
              for i, p in enumerate(prompts)],
@@ -396,9 +400,10 @@ def test_cow_at_mid_page_divergence_is_exact():
     def run(share):
         sv = ServingCfg(num_slots=2, page_size=4, num_pages=65,
                         max_blocks_per_slot=12, prefill_bucket=4,
-                        prefill_chunk=4, share_prefix=share,
-                        use_paged_kernels=False)
-        eng = ContinuousServeEngine(cfg, params, serving=sv)
+                        prefill_chunk=4, share_prefix=share)
+        eng = ContinuousServeEngine(
+            cfg, params, serving=sv,
+            rt=dataclasses.replace(cfg.attention, paged_kernels=False))
         eng.reset(GenerationConfig(max_new_tokens=16))
         eng.add_request(Request(rid=0, prompt=pa, max_new_tokens=16))
         for _ in range(12):     # A's 8 prompt pages land and register

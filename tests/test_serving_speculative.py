@@ -66,9 +66,11 @@ def _loopy_prompts(cfg, n=3, motif=6, reps=3, seed=0):
 def _serve(cfg, params, prompts, *, spec, fused=False, max_new=12, **kw):
     base = dict(num_slots=3, page_size=4, num_pages=65,
                 max_blocks_per_slot=12, prefill_bucket=4, prefill_chunk=4,
-                spec_len=spec, use_paged_kernels=fused)
+                spec_len=spec)
     base.update(kw)
-    eng = ContinuousServeEngine(cfg, params, serving=ServingCfg(**base))
+    rt = dataclasses.replace(cfg.attention, paged_kernels=fused)
+    eng = ContinuousServeEngine(cfg, params, rt=rt,
+                                serving=ServingCfg(**base))
     res, stats = eng.serve(
         [Request(rid=i, prompt=p, max_new_tokens=max_new)
          for i, p in enumerate(prompts)],
@@ -425,9 +427,10 @@ def test_speculative_seeded_sampling_parity():
         def run(spec):
             sv = ServingCfg(num_slots=3, page_size=4, num_pages=65,
                             max_blocks_per_slot=12, prefill_bucket=4,
-                            prefill_chunk=4, spec_len=spec,
-                            use_paged_kernels=False)
-            eng = ContinuousServeEngine(cfg, params, serving=sv)
+                            prefill_chunk=4, spec_len=spec)
+            eng = ContinuousServeEngine(
+                cfg, params, serving=sv,
+                rt=dataclasses.replace(cfg.attention, paged_kernels=False))
             res, stats = eng.serve(
                 [ServeRequest(prompt=p, rid=i, sampling=sps[i])
                  for i, p in enumerate(prompts)],
@@ -510,12 +513,13 @@ def test_migration_replay_with_spec_is_exact():
     prompt = _loopy_prompts(cfg, n=1, motif=4, reps=4, seed=11)[0]
     sp = SamplingParams(temperature=0.3, seed=21, max_tokens=16)
     sv = ServingCfg(num_slots=2, page_size=4, num_pages=33,
-                    max_blocks_per_slot=12, prefill_bucket=4, prefill_chunk=4,
-                    use_paged_kernels=False)
+                    max_blocks_per_slot=12, prefill_bucket=4, prefill_chunk=4)
+    gather = dataclasses.replace(cfg.attention, paged_kernels=False)
 
     def engine(spec):
-        return ContinuousServeEngine(cfg, params, serving=dataclasses.replace(
-            sv, spec_len=spec))
+        return ContinuousServeEngine(cfg, params, rt=gather,
+                                     serving=dataclasses.replace(
+                                         sv, spec_len=spec))
 
     ref = engine(0)
     res, _ = ref.serve([ServeRequest(prompt=prompt, rid=0, sampling=sp)],
@@ -554,8 +558,8 @@ from repro.launch.mesh import make_serve_mesh
 cfg = dataclasses.replace(smoke_config(ARCHS["qwen1.5-0.5b"]), dtype="float32")
 params = M.init_params(cfg, jax.random.PRNGKey(0))
 serving = ServingCfg(num_slots=2, page_size=4, num_pages=33,
-                     max_blocks_per_slot=8, prefill_bucket=4, prefill_chunk=4,
-                     use_paged_kernels=False)
+                     max_blocks_per_slot=8, prefill_bucket=4, prefill_chunk=4)
+gather = dataclasses.replace(cfg.attention, paged_kernels=False)
 rng = np.random.default_rng(0)
 m = rng.integers(1, cfg.vocab_size, size=6).astype(np.int32)
 prompts = [np.concatenate([np.tile(m, 3),
@@ -565,7 +569,7 @@ gen = GenerationConfig(max_new_tokens=10)
 
 def serve(mesh, spec):
     sv = dataclasses.replace(serving, spec_len=spec)
-    eng = ContinuousServeEngine(cfg, params, serving=sv, mesh=mesh)
+    eng = ContinuousServeEngine(cfg, params, rt=gather, serving=sv, mesh=mesh)
     reqs = [Request(rid=i, prompt=p, max_new_tokens=10)
             for i, p in enumerate(prompts)]
     return eng.serve(reqs, gen)

@@ -2,6 +2,7 @@
 a real profiler trace, stable names for its jitted steps, and token streams
 that no span changes."""
 import contextlib
+import dataclasses
 import glob
 import logging
 import re
@@ -187,8 +188,9 @@ def oracle(engine):
             for r in _requests(cfg.vocab_size)]
     serving = ServingCfg(num_slots=2, page_size=8, num_pages=17,
                          max_blocks_per_slot=8, prefill_bucket=8,
-                         prefill_chunk=0, use_paged_kernels=False)
-    return ContinuousServeEngine(cfg, params, serving=serving), refs
+                         prefill_chunk=0)
+    rt = dataclasses.replace(cfg.attention, paged_kernels=False)
+    return ContinuousServeEngine(cfg, params, rt=rt, serving=serving), refs
 
 
 @pytest.mark.parametrize("traced", [False, True])
